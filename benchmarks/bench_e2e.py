@@ -195,12 +195,6 @@ def run_compile_gate(arch: str = "granite-3-8b") -> dict:
     from repro.models.model import Model
     from repro.utils.compile_counter import CompileCounter
 
-    counter = CompileCounter()
-    if not counter.available:
-        emit("e2e/compile_count/unavailable", 0.0, "compiles=-1")
-        note("[compile_gate] jax monitoring hooks unavailable — skipped")
-        return {}
-
     cfg = get_smoke_config(arch)
     model = Model(cfg, attn_chunk=32, remat=False)
     params = model.init(jax.random.PRNGKey(0))
@@ -230,8 +224,8 @@ def run_compile_gate(arch: str = "granite-3-8b") -> dict:
             prefill_pack=True, warmup_compile=True, **bkw),
             predictor=OraclePredictor())
         warm_s = time.perf_counter() - t0
-        counter.reset()
-        eng.serve(mk_reqs())
+        with CompileCounter() as counter:
+            eng.serve(mk_reqs())
         n = counter.count
         results[bname] = n
         emit(f"e2e/compile_count/{bname}", warm_s * 1e6,

@@ -264,7 +264,6 @@ def run_cluster_tier(model, params, cfg) -> dict:
     n_sessions = pick(6, 3)
     prefix_len = pick(64, 32)          # turn-1 prompt: unique per session
     user_len, out_len = 5, 6
-    counter = CompileCounter()
 
     rng = np.random.default_rng(42)
     prompts1 = [rng.integers(2, cfg.vocab_size,
@@ -320,17 +319,15 @@ def run_cluster_tier(model, params, cfg) -> dict:
         reset_request_counter()
         (e0, e1), tier = mk_engines(tier_on)
         session(e0, e1, warm1, warm2)      # jit + tier-import shape warmup
-        if counter.available:
-            counter.reset()
         ttfts, outputs = [], []
-        for p1, fl in zip(prompts1, follows):
-            ttft, toks = session(e0, e1, p1, fl)
-            ttfts.append(ttft)
-            outputs.append(toks)
-        if counter.available:
-            assert counter.count == 0, (
-                f"{counter.count} serve-time recompiles during measured "
-                f"cluster_tier ({mode}) sessions: {counter.events}")
+        with CompileCounter() as counter:
+            for p1, fl in zip(prompts1, follows):
+                ttft, toks = session(e0, e1, p1, fl)
+                ttfts.append(ttft)
+                outputs.append(toks)
+        assert counter.count == 0, (
+            f"{counter.count} serve-time recompiles during measured "
+            f"cluster_tier ({mode}) sessions: {counter.events}")
         outs[mode] = outputs
         tiers[mode] = tier
         engines[mode] = (e0, e1)

@@ -392,7 +392,6 @@ def run_spec_decode(arch: str = "granite-3-8b") -> dict:
     out_len = pick(128, 16)
     max_seq = pick(224, 64)
     passes = pick(4, 1)
-    counter = CompileCounter()
 
     def mk_reqs(seed, out):
         reset_request_counter()
@@ -439,21 +438,19 @@ def run_spec_decode(arch: str = "granite-3-8b") -> dict:
             [list(r.output_tokens) for r in reqs], stats
 
     eng_off, eng_on = mk_engine(False), mk_engine(True)
-    if counter.available:
-        counter.reset()
     tok_s = {"off": 0.0, "on": 0.0}
     tokens_of: dict = {}
     stats_of: dict = {}
-    for _ in range(passes):          # alternate: noise hits both sides
-        for sname, eng in (("off", eng_off), ("on", eng_on)):
-            tps, toks, stats = decode_pass(eng)
-            tok_s[sname] = max(tok_s[sname], tps)
-            tokens_of[sname] = toks
-            stats_of[sname] = stats
-    if counter.available:
-        assert counter.count == 0, (
-            f"{counter.count} serve-time recompiles during measured "
-            f"spec-decode passes: {counter.events}")
+    with CompileCounter() as counter:
+        for _ in range(passes):      # alternate: noise hits both sides
+            for sname, eng in (("off", eng_off), ("on", eng_on)):
+                tps, toks, stats = decode_pass(eng)
+                tok_s[sname] = max(tok_s[sname], tps)
+                tokens_of[sname] = toks
+                stats_of[sname] = stats
+    assert counter.count == 0, (
+        f"{counter.count} serve-time recompiles during measured "
+        f"spec-decode passes: {counter.events}")
     assert tokens_of["on"] == tokens_of["off"], \
         "speculative decoding changed greedy outputs"
     results: dict = {}

@@ -102,6 +102,8 @@ def main() -> int:
                     help="PR identifier recorded in the perf artifact "
                          "(default: $PR_NUMBER or 'local')")
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     if args.smoke:
         common.set_smoke(True)
 

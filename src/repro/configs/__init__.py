@@ -45,6 +45,17 @@ def get_smoke_config(name: str) -> ArchConfig:
                                    num_kv_heads=2, d_ff=128, vocab_size=256)
 
 
+def get_chip_config(name: str) -> ArchConfig:
+    """The published widths, cut in depth to what one chip holds with a
+    real KV working set (the module's ``chip_config()``)."""
+    mod = (importlib.import_module(f"repro.configs.{_ASSIGNED[name]}")
+           if name in _ASSIGNED else None)
+    if not hasattr(mod, "chip_config"):
+        raise KeyError(f"{name!r} defines no one-chip config "
+                       "(configs/<arch>.py: chip_config())")
+    return mod.chip_config()
+
+
 def all_cells():
     """Yield every (arch_name, shape_name, supported, reason) dry-run cell."""
     for arch in ASSIGNED_ARCHS:
@@ -55,4 +66,5 @@ def all_cells():
 
 
 __all__ = ["ArchConfig", "ShapeSpec", "SHAPES", "ASSIGNED_ARCHS",
-           "get_config", "get_smoke_config", "all_cells", "cell_is_supported"]
+           "get_config", "get_smoke_config", "get_chip_config", "all_cells",
+           "cell_is_supported"]

@@ -55,7 +55,7 @@ from repro.core.quantization import kv_bytes_per_token
 from repro.core.request import KVLocation, Request, RequestState
 from repro.core.scheduler import (DecodeLane, PrefillChunk, PrefillPack,
                                   Scheduler, SchedulerConfig)
-from repro.distributed.placement import default_device_label
+from repro.distributed.placement import device_label
 from repro.models.model import Model
 from repro.serving.kv_cache import (DenseKVBackend, KVBackendConfig,
                                     PagedKVBackend)
@@ -164,11 +164,10 @@ class EngineConfig:
     respect_true_len: bool = True          # stop at trace's true_out_len
     device: Optional[str] = None           # placement label ("cpu:1") this
                                            # replica reports in gauges and
-                                           # router attribution; params/KV
-                                           # placement itself happens at
-                                           # construction (launch/serve.py
-                                           # builds each engine under
-                                           # placement.device_scope)
+                                           # router attribution (default:
+                                           # its params' device); the
+                                           # replica runs where its params
+                                           # live (placement.place_params)
     seed: int = 0
 
 
@@ -179,6 +178,12 @@ class ServingEngine:
         self.model = model
         self.params = params
         self.cfg = cfg
+        # the replica runs where its parameters live: its KV storage is
+        # allocated there, every dispatch runs with it as JAX's default
+        # device (see _on_device), and the KV backend takes its Pallas mode
+        # from its platform (refusing one it has no kernels for)
+        device = next(iter(jax.tree.leaves(params)[0].devices()))
+        self.jax_device = device
         acfg = model.cfg
         bpt = kv_bytes_per_token(acfg.num_layers, acfg.num_kv_heads, acfg.hd)
         # the dense backend's prefix cache owns a private page store — a
@@ -248,16 +253,17 @@ class ServingEngine:
             prefill_pack_width=cfg.prefill_pack_width,
             spec_k=(cfg.spec_k
                     if cfg.spec_decode and cfg.fused_decode else 0))
-        if cfg.kv_backend == "paged":
-            if not cfg.fused_decode:
-                raise ValueError("the paged backend only implements the "
-                                 "fused in-JIT decode step")
-            num_pages = max(1, int(hbm // (cfg.page_size * bpt)))
-            self.kv = PagedKVBackend(model, bcfg, num_pages)
-        elif cfg.kv_backend == "dense":
-            self.kv = DenseKVBackend(model, bcfg)
-        else:
-            raise ValueError(f"unknown kv_backend: {cfg.kv_backend!r}")
+        with self._on_device():
+            if cfg.kv_backend == "paged":
+                if not cfg.fused_decode:
+                    raise ValueError("the paged backend only implements the "
+                                     "fused in-JIT decode step")
+                num_pages = max(1, int(hbm // (cfg.page_size * bpt)))
+                self.kv = PagedKVBackend(model, bcfg, num_pages, device)
+            elif cfg.kv_backend == "dense":
+                self.kv = DenseKVBackend(model, bcfg, device)
+            else:
+                raise ValueError(f"unknown kv_backend: {cfg.kv_backend!r}")
         # shared-prefix cache active?  (needs chunked-prefill support: a hit
         # resumes mid-prompt through the PR-4 resumable-chunk machinery)
         self._prefix_ok = self.kv.prefix is not None
@@ -327,13 +333,18 @@ class ServingEngine:
         # the launcher/bench via attach_tier(); None keeps the tier paths
         # to one attribute-load + branch
         self.tier = None
-        # placement label for attribution (gauges, router WARN rows); the
-        # actual params/KV placement happened at construction time via
-        # distributed.placement.device_scope
-        self.device = cfg.device or default_device_label()
+        # placement label for attribution (gauges, router WARN rows)
+        self.device = cfg.device or device_label(device)
         self._step_wall0 = 0.0                     # perf_counter at step start
         if cfg.warmup_compile:
             self.warmup()
+
+    def _on_device(self):
+        """Scope for this replica's device work: arrays created inside land
+        on its device, and warmup and serving trace under the same default
+        device — which is part of jit's cache key, so a program warmed
+        under one scope would compile again under another."""
+        return jax.default_device(self.jax_device)
 
     # -------------------------------------------------------- observability
     def attach_bus(self, bus, name: str = "") -> None:
@@ -355,7 +366,13 @@ class ServingEngine:
         """Join this replica to a cluster-wide host-RAM KV tier
         (serving/kv_tier.py): local prefix publishes export their pages to
         the tier, and fresh prefills import a peer replica's pages at
-        admit time instead of re-prefilling."""
+        admit time instead of re-prefilling.  The tier runs its INT8
+        kernels in this replica's Pallas mode; the replicas of one tier
+        must agree on it."""
+        if tier.interpret not in (None, self.kv.interpret):
+            raise ValueError("replicas that interpret Pallas kernels and "
+                             "replicas that compile them cannot share a tier")
+        tier.interpret = self.kv.interpret
         self.tier = tier
         if self._prefix_ok:
             self.kv.prefix.tier = tier
@@ -667,6 +684,10 @@ class ServingEngine:
         warm draws never perturb a real request's stream).
         """
         assert not self.sched.live, "warmup() requires an idle engine"
+        with self._on_device():
+            return self._warmup()
+
+    def _warmup(self) -> Dict[int, float]:
         costs: Dict[int, float] = {}
         menu = self._buckets
         if menu is None and self._chunked_ok and self.cfg.prefill_chunk:
@@ -909,7 +930,7 @@ class ServingEngine:
         frees its lane/pages, host-pool KV, and memory accounting.  The
         returned request can be re-submitted to any engine and will continue
         deterministically from its current ``output_tokens``."""
-        with self.step_lock:
+        with self.step_lock, self._on_device():
             req = self.sched.live.get(req_id)
             if req is None:
                 return None
@@ -1074,7 +1095,7 @@ class ServingEngine:
         def now() -> float:
             return t
 
-        with self.step_lock:
+        with self.step_lock, self._on_device():
             self._step_wall0 = time.perf_counter()
             self._drain_submit_box()
             plan = self.sched.plan(now())

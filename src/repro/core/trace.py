@@ -110,15 +110,22 @@ def generate_trace(cfg: TraceConfig) -> SyntheticTrace:
 
 def clamp_requests(requests: List[Request], vocab: Optional[int] = None,
                    max_prompt: Optional[int] = None,
-                   max_new: Optional[int] = None) -> List[Request]:
-    """Adapt trace requests to a (small) real engine in place: trim prompts,
-    cap output lengths, and remap tokens into [2, vocab) (0 = pad, 1 = eos).
-    Keeps the arrival process and relative length mix intact."""
+                   max_new: Optional[int] = None,
+                   min_prompt: Optional[int] = None) -> List[Request]:
+    """Adapt trace requests to a real engine in place: trim prompts to
+    ``max_prompt``, lengthen shorter ones to ``min_prompt`` (their tokens
+    repeated), cap output lengths, and remap tokens into [2, vocab)
+    (0 = pad, 1 = eos).  Keeps the arrival process intact."""
     for r in requests:
         if max_prompt is not None and r.prompt_len > max_prompt:
             r.prompt_len = max_prompt
             if r.prompt_tokens is not None:
                 r.prompt_tokens = r.prompt_tokens[:max_prompt]
+        if min_prompt is not None and r.prompt_len < min_prompt:
+            r.prompt_len = min_prompt
+            if r.prompt_tokens:
+                reps = -(-min_prompt // len(r.prompt_tokens))
+                r.prompt_tokens = (r.prompt_tokens * reps)[:min_prompt]
         if max_new is not None:
             r.true_out_len = max(min(r.true_out_len, max_new), 1)
         if vocab is not None and r.prompt_tokens is not None:

@@ -9,14 +9,13 @@ the host into N real XLA devices: the multi-device code paths are
 exercised, not mocked.
 
 Placement is intentionally thin: commit the replica's parameters with
-``device_put`` and build the engine (KV pool, prefix cache, warmup
-compilations) under :func:`device_scope` — every jitted program then
-follows its committed operands onto the replica's device, and no serve-
-time code needs to know about placement at all.
+``device_put`` (:func:`place_params`).  The engine runs where its
+parameters live — it allocates its KV pool and prefix store there and
+runs warmup and every step with that device as JAX's default — so no
+serve-time code needs to know about placement at all.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import List, Sequence, Union
 
 import jax
@@ -28,10 +27,6 @@ def device_label(dev) -> str:
     """Stable ``platform:id`` label (``cpu:0``) used for replica
     attribution in gauges, bench WARN rows, and ``--devices`` specs."""
     return f"{dev.platform}:{dev.id}"
-
-
-def default_device_label() -> str:
-    return device_label(jax.devices()[0])
 
 
 def available_devices(spec: DeviceSpec = None) -> List:
@@ -86,13 +81,3 @@ def place_params(params, device):
     if device is None:
         return params
     return jax.device_put(params, device)
-
-
-def device_scope(device):
-    """Context manager: arrays created inside default to ``device``.
-    Engine construction and warmup run under this scope so the KV pool /
-    prefix store live with the replica's params (a pool on the wrong
-    device would silently bounce every page write across devices)."""
-    if device is None:
-        return contextlib.nullcontext()
-    return jax.default_device(device)

@@ -17,7 +17,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.paged_attention.paged_attention import (attend_head, finish,
+                                                           head_state,
+                                                           init_state)
 
 
 # ------------------------------------------------------------ quant/dequant
@@ -82,42 +84,35 @@ def _paged_q8_kernel(tables_ref, lengths_ref,
                      q_ref, kq_ref, klam_ref, kz_ref,
                      vq_ref, vlam_ref, vz_ref, o_ref,
                      m_ref, l_ref, acc_ref, *,
-                     page: int, n_pages: int, scale: float):
+                     page: int, kvh: int, n_pages: int, scale: float):
+    """Same page-per-step layout and per-head loop as ``paged_attention``:
+    a whole INT8 page (all KV heads) per grid step; each head's rows get
+    their scale/zero applied before its two matmuls."""
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_state(m_ref, l_ref, acc_ref)
 
     length = lengths_ref[b]
 
+    def rows(q_ref, lam_ref, z_ref, h):
+        q = q_ref[0, :, h, :].astype(jnp.float32)                 # (page, d)
+        return lam_ref[0, :, h, :] * (q + 128.0 - z_ref[0, :, h, :])
+
     @pl.when((pi * page) < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                        # (G, d)
-        kq = kq_ref[0, :, 0, :].astype(jnp.float32) + 128.0        # (page, d)
-        k = klam_ref[0, :, 0, :] * (kq - kz_ref[0, :, 0, :])       # dequant
-        vq = vq_ref[0, :, 0, :].astype(jnp.float32) + 128.0
-        v = vlam_ref[0, :, 0, :] * (vq - vz_ref[0, :, 0, :])
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h in range(kvh):
+            attend_head(h, q_ref[0, h].astype(jnp.float32),
+                        rows(kq_ref, klam_ref, kz_ref, h),
+                        rows(vq_ref, vlam_ref, vz_ref, h),
+                        m_ref, l_ref, acc_ref, pi=pi, page=page,
+                        length=length, scale=scale)
 
     @pl.when(pi == n_pages - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        finish(o_ref, l_ref, acc_ref)
 
 
 def paged_attention_q8(q, kq, k_lam, k_z, vq, v_lam, v_z, block_tables,
@@ -129,33 +124,31 @@ def paged_attention_q8(q, kq, k_lam, k_z, vq, v_lam, v_z, block_tables,
     G = H // KVH
     max_pages = block_tables.shape[1]
     scale = 1.0 / (d ** 0.5)
-    qg = q.reshape(B, KVH, G, d)
 
-    kernel = functools.partial(_paged_q8_kernel, page=page,
+    kernel = functools.partial(_paged_q8_kernel, page=page, kvh=KVH,
                                n_pages=max_pages, scale=scale)
 
     def page_spec(width):
-        return pl.BlockSpec((1, page, 1, width),
-                            lambda b, h, pi, tables, lens: (tables[b, pi], 0, h, 0))
+        return pl.BlockSpec((1, page, KVH, width),
+                            lambda b, pi, tables, lens: (tables[b, pi], 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, max_pages),
+        grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, G, d), lambda b, h, pi, t, l: (b, h, 0, 0)),
+            pl.BlockSpec((1, KVH, G, d), lambda b, pi, t, l: (b, 0, 0, 0)),
             page_spec(d), page_spec(1), page_spec(1),
             page_spec(d), page_spec(1), page_spec(1),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, d),
-                               lambda b, h, pi, t, l: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((G,), jnp.float32),
-                        pltpu.VMEM((G,), jnp.float32),
-                        pltpu.VMEM((G, d), jnp.float32)],
+        out_specs=pl.BlockSpec((1, KVH, G, d),
+                               lambda b, pi, t, l: (b, 0, 0, 0)),
+        scratch_shapes=head_state(KVH, G, d),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, d), q.dtype),
         interpret=interpret,
-    )(block_tables, lengths, qg, kq, k_lam, k_z, vq, v_lam, v_z)
+    )(block_tables, lengths, q.reshape(B, KVH, G, d), kq, k_lam, k_z,
+      vq, v_lam, v_z)
     return out.reshape(B, H, d)

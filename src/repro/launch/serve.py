@@ -1,4 +1,4 @@
-"""Serving launcher: end-to-end ALISE serving of a real (small) JAX model.
+"""Serving launcher: end-to-end ALISE serving of a real JAX model.
 
 Batch mode (pre-built request list, closed loop):
 
@@ -10,27 +10,91 @@ admission + multi-replica routing, streaming delivery):
 
     PYTHONPATH=src python -m repro.launch.serve --gateway --dataset alpaca \
         --rate 8 --n-requests 32 --n-engines 2
+
+``--size smoke`` (the default) builds the architecture's tiny CPU config;
+``--size chip`` builds its published widths cut in depth to one chip
+(``configs/<arch>.py: chip_config()``) with 2048-token lanes.
 """
 from __future__ import annotations
 
 import argparse
 import asyncio
-from typing import Optional
+import time
+from dataclasses import dataclass
+from typing import List, Optional
 
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_chip_config, get_smoke_config
 from repro.core.engine import EngineConfig, ServingEngine
 from repro.core.predictor import OraclePredictor, RetrievalPredictor
 from repro.core.request import Request, SLOClass, reset_request_counter
 from repro.core.trace import TraceConfig, clamp_requests, generate_trace
 from repro.distributed.placement import (assign_devices, device_label,
-                                         device_scope, place_params)
+                                         place_params)
 from repro.models.model import Model
 from repro.serving.gateway import AdmissionConfig, Gateway, GatewayConfig
 from repro.serving.kv_tier import HostKVTier
 from repro.serving.prediction import OnlineQuantilePredictor
+from repro.utils import use_compile_cache
+from repro.utils.compile_counter import CompileCounter
+
+
+@dataclass(frozen=True)
+class ServeSize:
+    """What one launcher size serves: the per-request limits the engine
+    and the traffic are cut to (the model config comes with the size)."""
+    max_seq_len: int            # KV positions per decode lane
+    min_prompt: int             # shorter prompts are lengthened to this
+    max_prompt: int             # prompt tokens kept per request
+    max_new_tokens: int         # output tokens per request
+
+
+SIZES = {
+    # toy widths that run on a laptop CPU (tests, CI)
+    "smoke": ServeSize(max_seq_len=96, min_prompt=4, max_prompt=24,
+                       max_new_tokens=48),
+    # published widths on one chip: prompt and output share a 2048 lane,
+    # whose last position is never decoded (the engine's context stop)
+    "chip": ServeSize(max_seq_len=2048, min_prompt=128, max_prompt=1024,
+                      max_new_tokens=1023),
+}
+
+
+@dataclass
+class ServeRun:
+    """What one launcher run served, for callers that check it."""
+    requests: List[Request]
+    server: object                  # ServingEngine (batch) or Gateway
+    streams: Optional[list]         # gateway: one RequestStream per request
+    setup_s: float                  # weights, engines and their warmup
+    serve_s: float                  # wall seconds serving the requests
+    compiles: int                   # backend compiles while serving
+    setup_compile_s: float          # backend compile/cache-load seconds
+    setup_cache_hits: int           # persistent-cache loads during set-up
+
+
+def _report_phases(tag: str, run: ServeRun) -> None:
+    print(f"[{tag}] set-up {run.setup_s:.1f}s (compiles "
+          f"{run.setup_compile_s:.1f}s summed over programs, "
+          f"{run.setup_cache_hits} compile-cache hits); serving "
+          f"{run.serve_s:.2f}s with {run.compiles} compiles")
+
+
+def size_config(arch: str, size: str):
+    """(ArchConfig, ServeSize) for ``arch`` at launcher size ``size``."""
+    if size not in SIZES:
+        raise ValueError(f"unknown size {size!r}; known: {sorted(SIZES)}")
+    cfg = get_smoke_config(arch) if size == "smoke" else get_chip_config(arch)
+    return cfg, SIZES[size]
+
+
+def init_params(model: Model, seed: int):
+    """Random weights from ``seed``, built by one compiled program: each
+    weight is drawn and cast to its dtype inside the program, so no
+    float32 copy of the whole layer stack is ever materialized."""
+    return jax.jit(model.init)(jax.random.PRNGKey(seed))
 
 
 def _mk_predictor(kind: str, seed: int = 0):
@@ -41,12 +105,15 @@ def _mk_predictor(kind: str, seed: int = 0):
     return RetrievalPredictor(seed=seed)
 
 
-def build_requests(cfg, n: int, seed: int = 0):
+def build_requests(cfg, n: int, seed: int = 0,
+                   size: ServeSize = SIZES["smoke"]):
+    """``n`` closed-loop requests with prompts of ``min_prompt`` up to
+    ``max_prompt`` tokens and short outputs."""
     rng = np.random.default_rng(seed)
     reset_request_counter()
     reqs = []
     for _ in range(n):
-        plen = int(rng.integers(4, 24))
+        plen = int(rng.integers(size.min_prompt, size.max_prompt))
         out = int(rng.choice([3, 5, 8, 30, 40], p=[0.3, 0.25, 0.2, 0.15, 0.1]))
         reqs.append(Request(
             prompt_len=plen, arrival_time=0.0, true_out_len=out,
@@ -87,48 +154,59 @@ def serve(arch: str = "granite-3-8b", strategy: str = "alise",
           prefill_buckets=None, prefill_pack: bool = False,
           prefill_pack_width: int = 4,
           warmup: bool = False, chunk_attn: str = "masked",
-          spec_decode: bool = False, spec_k: int = 3):
-    cfg = get_smoke_config(arch)
-    model = Model(cfg, attn_chunk=32, remat=False,
-                  chunk_attn_impl=chunk_attn)
-    params = model.init(jax.random.PRNGKey(seed))
-    predictor = _mk_predictor(predictor_kind, seed)
-    autotune = iter_token_budget == "auto"
-    eng = ServingEngine(model, params, EngineConfig(
-        max_slots=max_slots, max_seq_len=96, max_new_tokens=48,
-        strategy=strategy, quantize_offload=quantize,
-        kv_backend=kv_backend, prefill_chunk=prefill_chunk,
-        iter_token_budget=None if autotune else iter_token_budget,
-        prefix_cache=prefix_cache,
-        prefill_buckets=prefill_buckets, prefill_pack=prefill_pack,
-        prefill_pack_width=prefill_pack_width,
-        spec_decode=spec_decode, spec_k=spec_k,
-        warmup_compile=warmup), predictor=predictor)
-    if trace_out:
-        from repro.serving.observability import EventBus
-        eng.attach_bus(EventBus(clock="wall"), "engine0")
-    if autotune:
-        # profile a small warmup batch, then pick the budget whose
-        # predicted mixed-iteration time matches the target TPOT
-        eng.serve(build_requests(cfg, max(4, max_slots), seed + 1))
-        budget = eng.autotune_token_budget(target_tpot)
-        print(f"[serve] auto-tuned iter_token_budget={budget} "
-              f"(target TPOT {target_tpot*1e3:.1f}ms)")
-    reqs = build_requests(cfg, n_requests, seed)
-    eng.serve(reqs)
+          spec_decode: bool = False, spec_k: int = 3,
+          size: str = "smoke") -> ServeRun:
+    with CompileCounter() as counter:
+        t0 = time.perf_counter()
+        cfg, sz = size_config(arch, size)
+        model = Model(cfg, attn_chunk=32, remat=False,
+                      chunk_attn_impl=chunk_attn)
+        params = init_params(model, seed)
+        predictor = _mk_predictor(predictor_kind, seed)
+        autotune = iter_token_budget == "auto"
+        eng = ServingEngine(model, params, EngineConfig(
+            max_slots=max_slots, max_seq_len=sz.max_seq_len,
+            max_new_tokens=sz.max_new_tokens,
+            strategy=strategy, quantize_offload=quantize,
+            kv_backend=kv_backend, prefill_chunk=prefill_chunk,
+            iter_token_budget=None if autotune else iter_token_budget,
+            prefix_cache=prefix_cache,
+            prefill_buckets=prefill_buckets, prefill_pack=prefill_pack,
+            prefill_pack_width=prefill_pack_width,
+            spec_decode=spec_decode, spec_k=spec_k,
+            warmup_compile=warmup), predictor=predictor)
+        if trace_out:
+            from repro.serving.observability import EventBus
+            eng.attach_bus(EventBus(clock="wall"), "engine0")
+        if autotune:
+            # profile a small warmup batch, then pick the budget whose
+            # predicted mixed-iteration time matches the target TPOT
+            eng.serve(build_requests(cfg, max(4, max_slots), seed + 1, sz))
+            budget = eng.autotune_token_budget(target_tpot)
+            print(f"[serve] auto-tuned iter_token_budget={budget} "
+                  f"(target TPOT {target_tpot*1e3:.1f}ms)")
+        reqs = build_requests(cfg, n_requests, seed, sz)
+        setup_s = time.perf_counter() - t0
+        compile_s, hits = counter.seconds, counter.cache_hits
+        counter.reset()
+        t0 = time.perf_counter()
+        eng.serve(reqs)
+        run = ServeRun(reqs, eng, None, setup_s, time.perf_counter() - t0,
+                       counter.count, compile_s, hits)
     lat = [r.e2e_latency for r in reqs if r.e2e_latency is not None]
     norm = [r.normalized_latency for r in reqs if r.normalized_latency]
     print(f"[serve] {strategy}: {len(lat)}/{len(reqs)} finished; "
           f"mean latency {np.mean(lat):.3f}s; "
           f"normalized {np.mean(norm)*1e3:.1f} ms/token; "
           f"preemptions {sum(r.preempt_count for r in reqs)}")
+    _report_phases("serve", run)
     lm = eng.fit_latency_model()
     print(f"[serve] fitted latency model: t0={lm.t0:.2e}s/tok "
           f"alpha={lm.alpha:.2e} beta={lm.beta:.2e}")
     if trace_out:
         from repro.serving.observability import analyze_quality
         _export_trace(eng.bus, analyze_quality(eng.bus), trace_out)
-    return reqs, eng
+    return run
 
 
 def serve_gateway(arch: str = "granite-3-8b", strategy: str = "alise",
@@ -155,7 +233,8 @@ def serve_gateway(arch: str = "granite-3-8b", strategy: str = "alise",
                   spec_decode: bool = False, spec_k: int = 3,
                   kv_tier: bool = False, tier_bytes: float = 256e6,
                   tier_quantize: bool = False,
-                  devices: Optional[str] = None):
+                  devices: Optional[str] = None,
+                  size: str = "smoke") -> ServeRun:
     """Replay a synthetic Poisson trace through the online Gateway and print
     per-class TTFT/E2E percentiles (and SLO attainment when targets are
     set).  ``virtual_dt=None`` serves in wall clock; ``pump`` selects the
@@ -167,67 +246,81 @@ def serve_gateway(arch: str = "granite-3-8b", strategy: str = "alise",
     ``kv_tier`` joins every replica to one shared host-RAM prefix pool
     (serving/kv_tier.py): re-routed sessions import a peer's prefix pages
     at DMA cost instead of re-prefilling."""
-    cfg = get_smoke_config(arch)
-    model = Model(cfg, attn_chunk=32, remat=False,
-                  chunk_attn_impl=chunk_attn)
-    params = model.init(jax.random.PRNGKey(seed))
+    with CompileCounter() as counter:
+        t0 = time.perf_counter()
+        cfg, sz = size_config(arch, size)
+        model = Model(cfg, attn_chunk=32, remat=False,
+                      chunk_attn_impl=chunk_attn)
+        params = init_params(model, seed)
 
-    dev_list = assign_devices(n_engines, devices)
-    # only commit params per replica when placement is explicit or there
-    # is real device diversity — the single-device default stays the
-    # uncommitted layout (bit-identical to prior releases)
-    place = devices is not None or len({(d.platform, d.id)
-                                        for d in dev_list}) > 1
-    tier = None
-    if kv_tier:
-        tier = HostKVTier(tier_bytes, EngineConfig().page_size,
-                          quantize=tier_quantize)
+        dev_list = assign_devices(n_engines, devices)
+        # only commit params per replica when placement is explicit or
+        # there is real device diversity — the single-device default
+        # stays the uncommitted layout (bit-identical to prior releases)
+        place = devices is not None or len({(d.platform, d.id)
+                                            for d in dev_list}) > 1
+        tier = None
+        if kv_tier:
+            tier = HostKVTier(tier_bytes, EngineConfig().page_size,
+                              quantize=tier_quantize)
 
-    def mk_engine(i: int):
-        predictor = _mk_predictor(predictor_kind, seed)
-        dev = dev_list[i % len(dev_list)] if place else None
-        with device_scope(dev):
+        def mk_engine(i: int):
+            predictor = _mk_predictor(predictor_kind, seed)
+            dev = dev_list[i % len(dev_list)] if place else None
+            # the engine runs (and allocates its KV) where its params live
             eng = ServingEngine(model, place_params(params, dev),
                                 EngineConfig(
-                max_slots=max_slots, max_seq_len=96, max_new_tokens=48,
+                max_slots=max_slots, max_seq_len=sz.max_seq_len,
+                max_new_tokens=sz.max_new_tokens,
                 strategy=strategy, quantize_offload=False,
                 kv_backend=kv_backend, prefill_chunk=prefill_chunk,
                 iter_token_budget=iter_token_budget,
                 prefix_cache=prefix_cache,
-                prefill_buckets=prefill_buckets, prefill_pack=prefill_pack,
+                prefill_buckets=prefill_buckets,
+                prefill_pack=prefill_pack,
                 prefill_pack_width=prefill_pack_width,
                 spec_decode=spec_decode, spec_k=spec_k,
-                device=device_label(dev) if dev is not None else None,
                 warmup_compile=warmup), predictor=predictor)
-        if tier is not None:
-            eng.attach_tier(tier)
-        return eng
+            if tier is not None:
+                eng.attach_tier(tier)
+            return eng
 
-    reset_request_counter()
-    trace = generate_trace(TraceConfig(dataset=dataset, rate=rate,
-                                       duration=1e9,
-                                       max_requests=n_requests, seed=seed))
-    reqs = clamp_requests(trace.requests, vocab=cfg.vocab_size,
-                          max_prompt=24, max_new=48)
-    rng = np.random.default_rng(seed)
-    for r in reqs:
-        if rng.random() < interactive_frac:
-            r.slo_class = SLOClass.INTERACTIVE
+        reset_request_counter()
+        trace = generate_trace(TraceConfig(dataset=dataset, rate=rate,
+                                           duration=1e9,
+                                           max_requests=n_requests,
+                                           seed=seed))
+        reqs = clamp_requests(trace.requests, vocab=cfg.vocab_size,
+                              min_prompt=sz.min_prompt,
+                              max_prompt=sz.max_prompt,
+                              max_new=sz.max_new_tokens)
+        rng = np.random.default_rng(seed)
+        for r in reqs:
+            if rng.random() < interactive_frac:
+                r.slo_class = SLOClass.INTERACTIVE
 
-    gw = Gateway([mk_engine(i) for i in range(n_engines)],
-                 GatewayConfig(virtual_dt=virtual_dt, router_policy=router,
-                               concurrent_pump=(pump == "concurrent"),
-                               trace=bool(trace_out),
-                               metrics_interval_s=metrics_interval,
-                               heartbeat=metrics_interval is not None),
-                 admission=AdmissionConfig(
-                     max_queue_depth=max(8 * n_engines * max_slots, 32),
-                     defer_high_watermark=4 * n_engines * max_slots,
-                     ttft_target_interactive=ttft_target_interactive,
-                     ttft_target_batch=ttft_target_batch,
-                     ttft_miss_policy=ttft_miss_policy,
-                     ttft_quantile=ttft_quantile))
-    streams = asyncio.run(gw.replay(reqs))
+        engines = [mk_engine(i) for i in range(n_engines)]
+        gw = Gateway(engines,
+                     GatewayConfig(virtual_dt=virtual_dt,
+                                   router_policy=router,
+                                   concurrent_pump=(pump == "concurrent"),
+                                   trace=bool(trace_out),
+                                   metrics_interval_s=metrics_interval,
+                                   heartbeat=metrics_interval is not None),
+                     admission=AdmissionConfig(
+                         max_queue_depth=max(8 * n_engines * max_slots, 32),
+                         defer_high_watermark=4 * n_engines * max_slots,
+                         ttft_target_interactive=ttft_target_interactive,
+                         ttft_target_batch=ttft_target_batch,
+                         ttft_miss_policy=ttft_miss_policy,
+                         ttft_quantile=ttft_quantile))
+        setup_s = time.perf_counter() - t0
+        compile_s, hits = counter.seconds, counter.cache_hits
+        counter.reset()
+        t0 = time.perf_counter()
+        streams = asyncio.run(gw.replay(reqs))
+        run = ServeRun(reqs, gw, streams, setup_s, time.perf_counter() - t0,
+                       counter.count, compile_s, hits)
     done = sum(1 for s in streams if s.finished)
     clock = "virtual" if virtual_dt is not None else f"wall/{pump}"
     placement = (" [" + ", ".join(
@@ -237,6 +330,7 @@ def serve_gateway(arch: str = "granite-3-8b", strategy: str = "alise",
           f"({clock}), {dataset}@{rate}/s: {done}/{len(reqs)} streams "
           f"finished")
     print(gw.metrics.format())
+    _report_phases("gateway", run)
     if tier is not None:
         s = tier.stats
         print(f"[kv-tier] {tier.bytes / 1e6:.1f}/{tier.capacity_bytes / 1e6:.1f} MB, "
@@ -245,12 +339,16 @@ def serve_gateway(arch: str = "granite-3-8b", strategy: str = "alise",
               f"{s.evicted_pages} evicted")
     if trace_out:
         _export_trace(gw.bus, gw.quality(), trace_out)
-    return streams, gw
+    return run
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
+    ap.add_argument("--size", default="smoke", choices=sorted(SIZES),
+                    help="'smoke': the architecture's tiny CPU config; "
+                         "'chip': its published widths, depth cut to one "
+                         "chip, with 2048-token lanes")
     ap.add_argument("--strategy", default="alise",
                     choices=["alise", "orca", "vllm", "alise-recompute",
                              "alise-defer"])
@@ -380,6 +478,7 @@ def main():
                          "heartbeat every SECONDS (gauges are sampled "
                          "at the same cadence when tracing)")
     args = ap.parse_args()
+    use_compile_cache()
     buckets = None
     if args.prefill_buckets:
         buckets = tuple(sorted({int(x) for x in
@@ -422,7 +521,8 @@ def main():
                       tier_quantize=args.tier_quantize,
                       devices=args.devices,
                       trace_out=args.trace_out,
-                      metrics_interval=args.metrics_interval)
+                      metrics_interval=args.metrics_interval,
+                      size=args.size)
     else:
         if args.metrics_interval is not None:
             print("[serve] --metrics-interval is gateway-mode only "
@@ -435,7 +535,8 @@ def main():
               prefill_pack_width=args.prefill_pack_width,
               warmup=args.warmup, chunk_attn=args.chunk_attn,
               spec_decode=args.spec_decode, spec_k=args.spec_k,
-              target_tpot=args.target_tpot, trace_out=args.trace_out)
+              target_tpot=args.target_tpot, trace_out=args.trace_out,
+              size=args.size)
 
 
 if __name__ == "__main__":

@@ -510,7 +510,8 @@ class Model:
         against cached KV is exactly a (tiny) prefill chunk."""
         return self.supports_chunked_prefill()
 
-    def decode_verify(self, params, cache, tokens):
+    def decode_verify(self, params, cache, tokens, *,
+                      interpret: bool = False):
         """Score K1 = k+1 decode positions per lane in one dispatch.
 
         ``tokens``: (B, K1) int32 — column 0 is the lane's previous sampled
@@ -544,7 +545,8 @@ class Model:
             q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
             k_l = k_l.at[rows, q_pos].set(k.astype(k_l.dtype))
             v_l = v_l.at[rows, q_pos].set(v.astype(v_l.dtype))
-            attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, lengths)
+            attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, lengths,
+                                    interpret)
             h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, (k_l, v_l)
@@ -557,7 +559,8 @@ class Model:
 
     def decode_verify_sampled(self, params, cache, tokens, n_drafts, active,
                               base_gen, base_ctx, true_len, rids, base_key,
-                              *, greedy_sampling=True, temp: float = 1.0,
+                              *, interpret: bool = False,
+                              greedy_sampling=True, temp: float = 1.0,
                               top_k: int = 0, eos_token: int = 1,
                               max_new_tokens: int = 128,
                               max_seq_len: int = 256):
@@ -574,7 +577,8 @@ class Model:
         ``(samples (B, K1), n_emit (B,), reason (B,), new_cache)``.
         """
         from repro.serving.sampler import token_keys, verify_and_reason
-        logits, cache = self.decode_verify(params, cache, tokens)
+        logits, cache = self.decode_verify(params, cache, tokens,
+                                           interpret=interpret)
         B, K1 = tokens.shape
         keys = None
         if not greedy_sampling:
@@ -602,7 +606,8 @@ class Model:
         return (cfg.family not in ("ssm", "hybrid")
                 and not cfg.is_encoder_decoder)
 
-    def _chunk_attn(self, q, k_all, v_all, q_pos, kv_pos, start):
+    def _chunk_attn(self, q, k_all, v_all, q_pos, kv_pos, start,
+                    interpret: bool):
         """Attention for one (possibly packed) prefill chunk.
 
         ``q``: (B, C, H, hd); ``k_all``/``v_all``: (B, Smax, KVH, hd);
@@ -611,13 +616,14 @@ class Model:
         position mask (the bit-identity reference); ``flash`` routes
         through the Pallas ``flash_prefill_prefix`` kernel, which reads
         the stripe blockwise with online softmax — same math, no dense
-        score matrix, so it survives long contexts.
+        score matrix, so it survives long contexts.  ``interpret`` runs
+        the kernel in Pallas interpret mode (the caller decides it from
+        its device's platform).
         """
         cfg = self.cfg
         if self.chunk_attn_impl == "flash":
             from repro.kernels.flash_prefill.flash_prefill import (
                 flash_prefill_prefix)
-            interpret = jax.default_backend() == "cpu"
             out = flash_prefill_prefix(
                 q.transpose(0, 2, 1, 3),            # (B, H, C, hd)
                 k_all.astype(q.dtype).transpose(0, 2, 1, 3),
@@ -628,7 +634,7 @@ class Model:
                                 q_positions=q_pos, kv_positions=kv_pos)
 
     def prefill_chunk(self, params, k_stripe, v_stripe, tokens, start,
-                      chunk_len):
+                      chunk_len, *, interpret: bool = False):
         """One resumable prefill chunk over a dense per-request KV stripe.
 
         ``k_stripe``/``v_stripe``: (L, Smax, KVH, hd) — the request's slot
@@ -666,7 +672,7 @@ class Model:
             k_l = k_l.at[write_idx].set(k[0].astype(k_l.dtype))
             v_l = v_l.at[write_idx].set(v[0].astype(v_l.dtype))
             attn = self._chunk_attn(q, k_l[None], v_l[None], q_pos, kv_pos,
-                                    start_vec)
+                                    start_vec, interpret)
             h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, (k_l, v_l)
@@ -680,8 +686,26 @@ class Model:
         logits = self._logits(params, x_last)
         return logits.astype(jnp.float32), k_new, v_new
 
+    def _scan_paged(self, params, kv, x, layer_fn):
+        """Layer scan over a paged pool carried in the scan *carry*:
+        ``layer_fn(h, p_l, k_all, v_all, i) -> (h, k_all, v_all)`` scatters
+        layer ``i``'s new KV into the whole (L, pages, ...) pool and reads
+        its pages back by gather.  XLA then updates the one (donated) pool
+        buffer in place; a pool passed as scan xs/ys would need a second
+        whole-pool buffer for the stacked outputs."""
+        def step(carry, inp):
+            h, k_all, v_all = carry
+            p_l, i = inp
+            return layer_fn(h, p_l, k_all, v_all, i), None
+
+        (x, k_new, v_new), _ = lax.scan(
+            step, (x, kv["k"], kv["v"]),
+            (params["layers"], jnp.arange(self.cfg.num_layers)))
+        return x, {"k": k_new, "v": v_new}
+
     def paged_prefill_chunk(self, params, kv, tokens, block_tables,
-                            write_page, write_off, start, chunk_len):
+                            write_page, write_off, start, chunk_len, *,
+                            interpret: bool = False):
         """Paged twin of :meth:`prefill_chunk`: the chunk's KV lands
         directly in the page pool (device-side, mid-page chunk boundaries
         included) and attention gathers the request's pages in logical
@@ -708,29 +732,28 @@ class Model:
         start_vec = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
         ffn_kind = cfg.ffn_kind(0)
 
-        def body(h, inp):
-            p_l, k_pool, v_pool = inp
+        def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
             q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_pool = k_pool.at[write_page, write_off].set(
-                k[0].astype(k_pool.dtype))
-            v_pool = v_pool.at[write_page, write_off].set(
-                v[0].astype(v_pool.dtype))
-            kg = k_pool[block_tables[0]].reshape(1, Smax, *k_pool.shape[2:])
-            vg = v_pool[block_tables[0]].reshape(1, Smax, *v_pool.shape[2:])
-            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start_vec)
+            k_all = k_all.at[i, write_page, write_off].set(
+                k[0].astype(k_all.dtype))
+            v_all = v_all.at[i, write_page, write_off].set(
+                v[0].astype(v_all.dtype))
+            kg = k_all[i, block_tables[0]].reshape(1, Smax, *k_all.shape[3:])
+            vg = v_all[i, block_tables[0]].reshape(1, Smax, *v_all.shape[3:])
+            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start_vec,
+                                    interpret)
             h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, (k_pool, v_pool)
+            return h, k_all, v_all
 
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["layers"], kv["k"], kv["v"]))
+        x, kv = self._scan_paged(params, kv, x, body)
         x = L.apply_norm(cfg, params["final_norm"], x)
         last = jnp.clip(chunk_len - 1, 0, C - 1)
         x_last = jax.lax.dynamic_index_in_dim(x, last, axis=1,
                                               keepdims=False)
         logits = self._logits(params, x_last)
-        return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+        return logits.astype(jnp.float32), kv
 
     # ------------------------------------------------------- packed prefill
     def supports_prefill_pack(self) -> bool:
@@ -745,7 +768,7 @@ class Model:
         return self.supports_chunked_prefill() and self.cfg.ffn_kind(0) != "moe"
 
     def prefill_pack(self, params, k_stripes, v_stripes, tokens, start,
-                     chunk_len):
+                     chunk_len, *, interpret: bool = False):
         """N prefill chunks from distinct requests in one dispatch.
 
         Batched twin of :meth:`prefill_chunk` — segment ``i`` occupies
@@ -781,7 +804,8 @@ class Model:
             q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
             k_l = k_l.at[rows, write_idx].set(k.astype(k_l.dtype))
             v_l = v_l.at[rows, write_idx].set(v.astype(v_l.dtype))
-            attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, start)
+            attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, start,
+                                    interpret)
             h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, (k_l, v_l)
@@ -795,7 +819,8 @@ class Model:
         return logits.astype(jnp.float32), k_new, v_new
 
     def paged_prefill_pack(self, params, kv, tokens, block_tables,
-                           write_page, write_off, start, chunk_len):
+                           write_page, write_off, start, chunk_len, *,
+                           interpret: bool = False):
         """Paged twin of :meth:`prefill_pack`: N chunks' KV lands in the
         shared page pool in one dispatch.
 
@@ -821,28 +846,27 @@ class Model:
         kv_pos = jnp.broadcast_to(jnp.arange(Smax)[None, :], (N, Smax))
         ffn_kind = cfg.ffn_kind(0)
 
-        def body(h, inp):
-            p_l, k_pool, v_pool = inp
+        def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
             q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_pool = k_pool.at[write_page, write_off].set(
-                k.astype(k_pool.dtype))
-            v_pool = v_pool.at[write_page, write_off].set(
-                v.astype(v_pool.dtype))
-            kg = k_pool[block_tables].reshape(N, Smax, *k_pool.shape[2:])
-            vg = v_pool[block_tables].reshape(N, Smax, *v_pool.shape[2:])
-            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start)
+            k_all = k_all.at[i, write_page, write_off].set(
+                k.astype(k_all.dtype))
+            v_all = v_all.at[i, write_page, write_off].set(
+                v.astype(v_all.dtype))
+            kg = k_all[i, block_tables].reshape(N, Smax, *k_all.shape[3:])
+            vg = v_all[i, block_tables].reshape(N, Smax, *v_all.shape[3:])
+            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start,
+                                    interpret)
             h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, (k_pool, v_pool)
+            return h, k_all, v_all
 
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["layers"], kv["k"], kv["v"]))
+        x, kv = self._scan_paged(params, kv, x, body)
         x = L.apply_norm(cfg, params["final_norm"], x)
         last = jnp.clip(chunk_len - 1, 0, C - 1)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         logits = self._logits(params, x_last)
-        return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+        return logits.astype(jnp.float32), kv
 
     # ------------------------------------------------------- paged decode
     def supports_paged(self) -> bool:
@@ -855,7 +879,7 @@ class Model:
 
     def paged_decode_step(self, params, kv, tokens, block_tables, lengths,
                           write_page, write_off, *, attn_impl: str = "gather",
-                          interpret: bool = True):
+                          interpret: bool = False):
         """One decode iteration over a paged KV pool (vLLM-style block KV).
 
         ``kv``: {"k","v"} of shape (L, num_pages, page, KVH, hd);
@@ -885,41 +909,40 @@ class Model:
         positions = lengths[:, None]
         ffn_kind = cfg.ffn_kind(0)
 
-        def body(h, inp):
-            p_l, k_pool, v_pool = inp
+        def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
             q, k, v = L._project_qkv(cfg, p_l["attn"], h1, positions)
-            k_pool = k_pool.at[write_page, write_off].set(
-                k[:, 0].astype(k_pool.dtype))
-            v_pool = v_pool.at[write_page, write_off].set(
-                v[:, 0].astype(v_pool.dtype))
+            k_all = k_all.at[i, write_page, write_off].set(
+                k[:, 0].astype(k_all.dtype))
+            v_all = v_all.at[i, write_page, write_off].set(
+                v[:, 0].astype(v_all.dtype))
             if attn_impl == "kernel":
                 from repro.kernels.paged_attention.paged_attention import \
                     paged_attention
-                attn = paged_attention(q[:, 0], k_pool, v_pool, block_tables,
-                                       lengths + 1, interpret=interpret)
+                attn = paged_attention(q[:, 0], k_all, v_all, block_tables,
+                                       lengths + 1, layer=i,
+                                       interpret=interpret)
             else:
                 n_pages = block_tables.shape[1]
-                kg = k_pool[block_tables].reshape(
-                    B, n_pages * page, *k_pool.shape[2:])
-                vg = v_pool[block_tables].reshape(
-                    B, n_pages * page, *v_pool.shape[2:])
+                kg = k_all[i, block_tables].reshape(
+                    B, n_pages * page, *k_all.shape[3:])
+                vg = v_all[i, block_tables].reshape(
+                    B, n_pages * page, *v_all.shape[3:])
                 attn = L.decode_attention(cfg, q[:, 0], kg, vg, lengths + 1)
             h = h + (attn.reshape(B, -1) @ p_l["attn"]["wo"])[:, None, :]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind)
-            return h, (k_pool, v_pool)
+            return h, k_all, v_all
 
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["layers"], kv["k"], kv["v"]))
+        x, kv = self._scan_paged(params, kv, x, body)
         x = L.apply_norm(cfg, params["final_norm"], x)
         logits = self._logits(params, x[:, -1, :])
-        return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+        return logits.astype(jnp.float32), kv
 
     def paged_decode_step_sampled(self, params, kv, tokens, block_tables,
                                   lengths, write_page, write_off, active,
                                   new_gen, new_ctx, true_len, rids, base_key,
                                   *, attn_impl: str = "gather",
-                                  interpret: bool = True,
+                                  interpret: bool = False,
                                   greedy_sampling=True, temp: float = 1.0,
                                   top_k: int = 0, eos_token: int = 1,
                                   max_new_tokens: int = 128,
@@ -942,7 +965,8 @@ class Model:
         return tok, reason, kv
 
     def paged_decode_verify(self, params, kv, tokens, block_tables, lengths,
-                            write_page, write_off):
+                            write_page, write_off, *,
+                            interpret: bool = False):
         """Paged twin of :meth:`decode_verify`: K1 positions per lane land
         in the page pool at host-computed ``(write_page, write_off)``
         destinations (real tail pages in place; positions past the last
@@ -971,31 +995,32 @@ class Model:
         kv_pos = jnp.broadcast_to(jnp.arange(Smax)[None, :], (B, Smax))
         ffn_kind = cfg.ffn_kind(0)
 
-        def body(h, inp):
-            p_l, k_pool, v_pool = inp
+        def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
             q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_pool = k_pool.at[write_page, write_off].set(
-                k.astype(k_pool.dtype))
-            v_pool = v_pool.at[write_page, write_off].set(
-                v.astype(v_pool.dtype))
-            kg = k_pool[block_tables].reshape(B, Smax, *k_pool.shape[2:])
-            vg = v_pool[block_tables].reshape(B, Smax, *v_pool.shape[2:])
-            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, lengths)
+            k_all = k_all.at[i, write_page, write_off].set(
+                k.astype(k_all.dtype))
+            v_all = v_all.at[i, write_page, write_off].set(
+                v.astype(v_all.dtype))
+            kg = k_all[i, block_tables].reshape(B, Smax, *k_all.shape[3:])
+            vg = v_all[i, block_tables].reshape(B, Smax, *v_all.shape[3:])
+            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, lengths,
+                                    interpret)
             h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, (k_pool, v_pool)
+            return h, k_all, v_all
 
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["layers"], kv["k"], kv["v"]))
+        x, kv = self._scan_paged(params, kv, x, body)
         x = L.apply_norm(cfg, params["final_norm"], x)
         logits = self._logits(params, x)
-        return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+        return logits.astype(jnp.float32), kv
 
     def paged_decode_verify_sampled(self, params, kv, tokens, block_tables,
                                     lengths, write_page, write_off, n_drafts,
                                     active, base_gen, base_ctx, true_len,
-                                    rids, base_key, *, greedy_sampling=True,
+                                    rids, base_key, *,
+                                    interpret: bool = False,
+                                    greedy_sampling=True,
                                     temp: float = 1.0, top_k: int = 0,
                                     eos_token: int = 1,
                                     max_new_tokens: int = 128,
@@ -1007,7 +1032,8 @@ class Model:
         (rollback = simply not advancing the pool length)."""
         from repro.serving.sampler import token_keys, verify_and_reason
         logits, kv = self.paged_decode_verify(
-            params, kv, tokens, block_tables, lengths, write_page, write_off)
+            params, kv, tokens, block_tables, lengths, write_page, write_off,
+            interpret=interpret)
         B, K1 = tokens.shape
         keys = None
         if not greedy_sampling:

@@ -31,21 +31,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.kv_quant import kv_dequantize_op, kv_quantize_op
 
-_INTERPRET = jax.default_backend() == "cpu"   # Pallas interpret off-TPU
 _QBLK = 128                                   # kv_quant row-tile
 
-# buffer donation on the jitted cache-update dispatches: the decode/chunk
-# step rewrites the whole cache/pool functionally, so donating the input
-# buffer lets XLA update in place instead of copying the full cache every
-# iteration.  CPU XLA ignores donation (with a warning per compile), so
-# gate it off there — a no-op on CPU, the full-cache copy disappears on TPU.
-_DONATE_OK = jax.default_backend() != "cpu"
-
-
-def _donate(*argnums):
-    return dict(donate_argnums=argnums) if _DONATE_OK else {}
+# Every jitted cache-update dispatch donates the cache/pool it rewrites, so
+# XLA updates it in place instead of holding a second whole-cache buffer.
+# Donation deletes the caller's input buffer on every backend (CPU
+# included): callers rebind the returned arrays and never touch the
+# donated ones again.
 
 
 # --------------------------------------------------------------- page pool
@@ -81,12 +76,16 @@ class PagedKVPool:
         self.page_table: Dict[int, List[int]] = {}       # req -> pages
         self.lengths: Dict[int, int] = {}
         self.refs: Dict[int, int] = {}                   # page -> refcount
-        # CoW page duplication as one jitted, donated dispatch: without
-        # donation each eager at[].set would materialize a whole new pool
+        # CoW page duplication and page-granular writes as jitted, donated
+        # dispatches: an eager at[].set would materialize a whole new pool
         self._cow_copy = jax.jit(
             lambda k, v, s, d: (k.at[:, d].set(k[:, s][:, None]),
                                 v.at[:, d].set(v[:, s][:, None])),
-            **_donate(0, 1))
+            donate_argnums=(0, 1))
+        self._scatter = jax.jit(
+            lambda k, v, idx, ks, vs: (k.at[:, idx].set(ks.astype(k.dtype)),
+                                       v.at[:, idx].set(vs.astype(v.dtype))),
+            donate_argnums=(0, 1))
 
     # ----------------------------------------------------------- refcounts
     def take_page(self) -> int:
@@ -117,6 +116,12 @@ class PagedKVPool:
         self.k, self.v = self._cow_copy(self.k, self.v, jnp.asarray(src),
                                         jnp.asarray([dst]))
         return dst
+
+    def scatter_pages(self, idx, k, v) -> None:
+        """Write whole pages in place: ``k``/``v`` (L, len(idx), page,
+        KVH, d) land at physical pages ``idx`` of every layer."""
+        self.k, self.v = self._scatter(self.k, self.v, jnp.asarray(idx),
+                                       k, v)
 
     # ------------------------------------------------------------ allocator
     def pages_needed(self, tokens: int) -> int:
@@ -193,11 +198,9 @@ class PagedKVPool:
         if pad:
             k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        idx = jnp.asarray(pages)
-        kp = k.reshape(k.shape[0], len(pages), pg, *k.shape[2:])
-        vp = v.reshape(v.shape[0], len(pages), pg, *v.shape[2:])
-        self.k = self.k.at[:, idx].set(kp.astype(self.k.dtype))
-        self.v = self.v.at[:, idx].set(vp.astype(self.v.dtype))
+        self.scatter_pages(
+            pages, k.reshape(k.shape[0], len(pages), pg, *k.shape[2:]),
+            v.reshape(v.shape[0], len(pages), pg, *v.shape[2:]))
         return pages
 
     def block_table_array(self, req_ids: List[int]) -> tuple:
@@ -222,19 +225,19 @@ class PagedKVPool:
 
     def restore(self, req_id: int, snap: dict) -> None:
         pages = self.allocate(req_id, snap["tokens"])
-        idx = jnp.asarray(pages)
-        self.k = self.k.at[:, idx].set(jnp.asarray(snap["k"]))
-        self.v = self.v.at[:, idx].set(jnp.asarray(snap["v"]))
+        self.scatter_pages(pages, jnp.asarray(snap["k"]),
+                           jnp.asarray(snap["v"]))
 
 
 # ------------------------------------------------- device-side quant blobs
 
-def quantize_kv_device(x) -> tuple:
+def quantize_kv_device(x, *, interpret: bool) -> tuple:
     """INT8-quantize an arbitrary-rank KV tensor on device via the Pallas
     ``kv_quantize`` kernel (per (token, head) row over the last axis) and
     pull the *INT8* payload to host — the host link carries half the bytes
     of the fp tensor (Eq. 8), unlike the old host-numpy ``quantize_np``
-    path which shipped fp32 first.  Returns ``(q, lam, z, shape)``."""
+    path which shipped fp32 first.  ``interpret`` is the Pallas mode of
+    the device ``x`` lives on.  Returns ``(q, lam, z, shape)``."""
     shape = tuple(x.shape)
     d = shape[-1]
     rows = int(np.prod(shape[:-1]))
@@ -243,12 +246,13 @@ def quantize_kv_device(x) -> tuple:
     if pad:
         flat = jnp.pad(flat, ((0, pad), (0, 0)))
     q, lam, z = kv_quantize_op(flat, blk=min(_QBLK, flat.shape[0]),
-                               interpret=_INTERPRET)
+                               interpret=interpret)
     q, lam, z = jax.device_get((q[:rows], lam[:rows], z[:rows]))
     return np.asarray(q), np.asarray(lam), np.asarray(z), shape
 
 
-def dequantize_kv_device(blob: tuple, dtype=jnp.float32):
+def dequantize_kv_device(blob: tuple, dtype=jnp.float32, *,
+                         interpret: bool):
     """Inverse of :func:`quantize_kv_device`: upload INT8 + scales, run the
     Pallas ``kv_dequantize`` kernel on device, reshape to the saved shape."""
     q, lam, z, shape = blob
@@ -260,7 +264,7 @@ def dequantize_kv_device(blob: tuple, dtype=jnp.float32):
         lj = jnp.pad(lj, ((0, pad), (0, 0)))
         zj = jnp.pad(zj, ((0, pad), (0, 0)))
     x = kv_dequantize_op(qj, lj, zj, dtype=dtype,
-                         blk=min(_QBLK, qj.shape[0]), interpret=_INTERPRET)
+                         blk=min(_QBLK, qj.shape[0]), interpret=interpret)
     return x[:rows].reshape(shape)
 
 
@@ -303,9 +307,13 @@ class KVBackend:
     ``(tokens, reasons)`` pair per iteration.
     """
 
-    def __init__(self, model, cfg: KVBackendConfig):
+    def __init__(self, model, cfg: KVBackendConfig, device):
         self.model = model
         self.cfg = cfg
+        # the one place the Pallas mode is decided: from the platform of
+        # the device this backend's storage and dispatches live on
+        self.device = device
+        self.interpret = interpret_mode(device.platform)
         self.slot_req: List[Optional[int]] = [None] * cfg.max_slots
         self.prefix = None                 # shared-prefix cache (optional)
         # sampling keys are derived per (request id, token index) inside the
@@ -480,21 +488,21 @@ class DenseKVBackend(KVBackend):
     SSM, hybrid, enc-dec) — per-key batch axes come from the cache spec.
     """
 
-    def __init__(self, model, cfg: KVBackendConfig):
-        super().__init__(model, cfg)
+    def __init__(self, model, cfg: KVBackendConfig, device):
+        super().__init__(model, cfg, device)
         self.cache = model.init_cache(cfg.max_slots, cfg.max_seq_len)
         self._axes = self._cache_batch_axes()
         # the cache pytree (arg 1) is consumed and fully re-emitted by the
-        # fused step: donate it so TPU updates in place (no-op on CPU)
+        # fused step: donate it so it updates in place
         self._fused = jax.jit(functools.partial(
             model.decode_step_sampled, **self._sample_kwargs()),
-            **_donate(1))
+            donate_argnums=(1,))
         self._fused_verify = None
         if cfg.spec_k > 0 and model.supports_spec_decode():
             self._fused_verify = jax.jit(functools.partial(
-                model.decode_verify_sampled, **self._sample_kwargs()),
-                **_donate(1))
-        self._decode = jax.jit(model.decode_step, **_donate(1))
+                model.decode_verify_sampled, interpret=self.interpret,
+                **self._sample_kwargs()), donate_argnums=(1,))
+        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
         self._chunk = None
         if model.supports_chunked_prefill():
             # one jitted dispatch per chunk over the *full* cache: the slot
@@ -505,11 +513,11 @@ class DenseKVBackend(KVBackend):
                             chunk_len):
                 logits, k_new, v_new = model.prefill_chunk(
                     params, k_cache[:, slot], v_cache[:, slot], toks,
-                    start, chunk_len)
+                    start, chunk_len, interpret=self.interpret)
                 return (logits,
                         k_cache.at[:, slot].set(k_new.astype(k_cache.dtype)),
                         v_cache.at[:, slot].set(v_new.astype(v_cache.dtype)))
-            self._chunk = jax.jit(chunk_cache, **_donate(1, 2))
+            self._chunk = jax.jit(chunk_cache, donate_argnums=(1, 2))
         self._pack = None
         if model.supports_prefill_pack():
             # packed twin: N segment rows gather N slot stripes, run the
@@ -521,11 +529,11 @@ class DenseKVBackend(KVBackend):
                            chunk_len):
                 logits, k_new, v_new = model.prefill_pack(
                     params, k_cache[:, slots], v_cache[:, slots], toks,
-                    start, chunk_len)
+                    start, chunk_len, interpret=self.interpret)
                 return (logits,
                         k_cache.at[:, slots].set(k_new.astype(k_cache.dtype)),
                         v_cache.at[:, slots].set(v_new.astype(v_cache.dtype)))
-            self._pack = jax.jit(pack_cache, **_donate(1, 2))
+            self._pack = jax.jit(pack_cache, donate_argnums=(1, 2))
         if cfg.prefix_cache and model.supports_chunked_prefill():
             from repro.serving.prefix_cache import DensePrefixCache
             acfg = model.cfg
@@ -542,7 +550,7 @@ class DenseKVBackend(KVBackend):
                 kc = kc.at[:, slot, :span].set(k.astype(kc.dtype))
                 vc = vc.at[:, slot, :span].set(v.astype(vc.dtype))
                 return kc, vc, lengths.at[slot].set(hit)
-            self._place = jax.jit(place, **_donate(0, 1, 2))
+            self._place = jax.jit(place, donate_argnums=(0, 1, 2))
 
     def _cache_batch_axes(self) -> Dict[str, int]:
         fam = self.model.cfg.family
@@ -746,7 +754,8 @@ class DenseKVBackend(KVBackend):
             else:
                 trimmed = arr
             if self.cfg.quantize_offload and key in ("k", "v"):
-                stored[key] = ("q8", quantize_kv_device(trimmed))
+                stored[key] = ("q8", quantize_kv_device(
+                    trimmed, interpret=self.interpret))
             else:
                 stored[key] = ("raw", np.asarray(jax.device_get(trimmed)))
         self.clear(rid)
@@ -762,7 +771,8 @@ class DenseKVBackend(KVBackend):
                 continue
             item = blob[key]
             if item[0] == "q8":
-                src = dequantize_kv_device(item[1], dtype=jnp.float32)
+                src = dequantize_kv_device(item[1], dtype=jnp.float32,
+                                           interpret=self.interpret)
             else:
                 src = jnp.asarray(item[1])
             if key in ("k", "v"):
@@ -825,8 +835,9 @@ class PagedKVBackend(KVBackend):
     inactive lanes write to a reserved scratch page.
     """
 
-    def __init__(self, model, cfg: KVBackendConfig, num_pages: int):
-        super().__init__(model, cfg)
+    def __init__(self, model, cfg: KVBackendConfig, num_pages: int,
+                 device):
+        super().__init__(model, cfg, device)
         if not model.supports_paged():
             raise ValueError(
                 "paged KV backend requires an attention-family decoder-only "
@@ -857,21 +868,26 @@ class PagedKVBackend(KVBackend):
             [self.pool.reserve_scratch() for _ in range(cfg.max_slots)]
             if spec_on else [])
         # kv (arg 1) is the whole page pool, consumed and re-emitted: donate
-        # so TPU writes pages in place (no-op on CPU)
+        # so pages are written in place
         self._fused = jax.jit(functools.partial(
             model.paged_decode_step_sampled, attn_impl=cfg.attn_impl,
-            interpret=_INTERPRET, **self._sample_kwargs()), **_donate(1))
+            interpret=self.interpret, **self._sample_kwargs()),
+            donate_argnums=(1,))
         self._fused_verify = None
         if spec_on:
             self._fused_verify = jax.jit(functools.partial(
-                model.paged_decode_verify_sampled, **self._sample_kwargs()),
-                **_donate(1))
+                model.paged_decode_verify_sampled, interpret=self.interpret,
+                **self._sample_kwargs()), donate_argnums=(1,))
         # chunked prefill always attends via the logical-order page gather
         # (bit-exact vs the dense stripe path); attn_impl only selects the
         # decode-step kernel
-        self._chunk = jax.jit(model.paged_prefill_chunk, **_donate(1))
-        self._pack = (jax.jit(model.paged_prefill_pack, **_donate(1))
-                      if model.supports_prefill_pack() else None)
+        self._chunk = jax.jit(functools.partial(
+            model.paged_prefill_chunk, interpret=self.interpret),
+            donate_argnums=(1,))
+        self._pack = (jax.jit(functools.partial(
+            model.paged_prefill_pack, interpret=self.interpret),
+            donate_argnums=(1,))
+            if model.supports_prefill_pack() else None)
         if cfg.prefix_cache:
             from repro.serving.prefix_cache import PagedPrefixCache
             self.prefix = PagedPrefixCache(self.pool, cfg.page_size)
@@ -1019,7 +1035,8 @@ class PagedKVBackend(KVBackend):
         stored: dict = {"lengths": length}
         for key, arr in (("k", k), ("v", v)):
             if self.cfg.quantize_offload:
-                stored[key] = ("q8", quantize_kv_device(arr))
+                stored[key] = ("q8", quantize_kv_device(
+                    arr, interpret=self.interpret))
             else:
                 stored[key] = ("raw", np.asarray(jax.device_get(arr)))
         self.clear(rid)
@@ -1050,22 +1067,21 @@ class PagedKVBackend(KVBackend):
         fresh = [self.pool.take_page() for _ in range(n_need - len(shared))]
         self.pool.page_table[rid] = shared + fresh
         self.pool.lengths[rid] = length
+        src = {}
         for key in ("k", "v"):
             item = blob[key]
             if item[0] == "q8":
-                src = dequantize_kv_device(item[1], dtype=jnp.float32)
+                src[key] = dequantize_kv_device(item[1], dtype=jnp.float32,
+                                                interpret=self.interpret)
             else:
-                src = jnp.asarray(item[1])
-            # the blob carries the pow2-padded page bucket; surplus rows
-            # scatter into the scratch page (shape-stable, harmless), and
-            # so do rows covering re-linked shared pages — their device
-            # KV is already exact and may be serving other requests
-            idx = jnp.asarray([self.scratch_page] * len(shared) + fresh
-                              + [self.scratch_page]
-                              * (src.shape[1] - n_need))
-            arr = getattr(self.pool, key)
-            setattr(self.pool, key,
-                    arr.at[:, idx].set(src.astype(arr.dtype)))
+                src[key] = jnp.asarray(item[1])
+        # the blob carries the pow2-padded page bucket; surplus rows
+        # scatter into the scratch page (shape-stable, harmless), and so
+        # do rows covering re-linked shared pages — their device KV is
+        # already exact and may be serving other requests
+        idx = ([self.scratch_page] * len(shared) + fresh
+               + [self.scratch_page] * (src["k"].shape[1] - n_need))
+        self.pool.scatter_pages(idx, src["k"], src["v"])
         self.slot_req[slot] = rid
 
     def tier_fill(self, tokens, handle) -> int:
@@ -1099,15 +1115,15 @@ class PagedKVBackend(KVBackend):
         fresh = [self.pool.take_page() for _ in range(n - have)]
         nb = 1 << (len(fresh) - 1).bit_length()
         nb = min(max(nb, len(fresh)), self.max_pages_per_seq)
-        idx = jnp.asarray(fresh + [self.scratch_page] * (nb - len(fresh)))
-        for j, key in enumerate(("k", "v")):
+        src = []
+        for j in range(2):
             parts = [np.asarray(m[j]) for m in mats]
-            src = np.zeros((parts[0].shape[0], nb) + parts[0].shape[1:],
+            buf = np.zeros((parts[0].shape[0], nb) + parts[0].shape[1:],
                            dtype=parts[0].dtype)
-            src[:, :len(parts)] = np.stack(parts, axis=1)
-            arr = getattr(self.pool, key)
-            setattr(self.pool, key,
-                    arr.at[:, idx].set(jnp.asarray(src).astype(arr.dtype)))
+            buf[:, :len(parts)] = np.stack(parts, axis=1)
+            src.append(jnp.asarray(buf))
+        self.pool.scatter_pages(
+            fresh + [self.scratch_page] * (nb - len(fresh)), *src)
         created = self.prefix.index.insert(toks, n * pg,
                                            lambda i: fresh[i - have])
         used = {node.page for node in created}

@@ -34,6 +34,7 @@ import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.prefix_cache import RadixPageIndex, SimPrefixIndex
@@ -84,13 +85,16 @@ class TierHandle:
         """Decode every payload to ``(k, v)`` page arrays of ``dtype``
         (host numpy for raw entries; dequantized device arrays for q8)."""
         out = []
+        mode = self._tier.interpret
         for payload in self.payloads:
             if payload[0] == "raw":
                 out.append((payload[1], payload[2]))
             else:
                 from repro.serving.kv_cache import dequantize_kv_device
-                out.append((dequantize_kv_device(payload[1], dtype),
-                            dequantize_kv_device(payload[2], dtype)))
+                out.append((dequantize_kv_device(payload[1], dtype,
+                                                 interpret=mode),
+                            dequantize_kv_device(payload[2], dtype,
+                                                 interpret=mode)))
         return out
 
     def release(self) -> None:
@@ -108,10 +112,14 @@ class HostKVTier:
     """
 
     def __init__(self, capacity_bytes: float, page_size: int,
-                 quantize: bool = False):
+                 quantize: bool = False, interpret: Optional[bool] = None):
         self.capacity_bytes = float(capacity_bytes)
         self.page_size = int(page_size)
         self.quantize = bool(quantize)
+        # Pallas mode of the kv_quant kernels that (de)quantize INT8
+        # payloads: the replicas' own, handed over by
+        # ServingEngine.attach_tier
+        self.interpret = interpret
         self.index = RadixPageIndex(self.page_size)
         self.entries: Dict[int, _Entry] = {}
         self.bytes = 0
@@ -213,11 +221,15 @@ class HostKVTier:
             k = np.asarray(k)
             v = np.asarray(v)
             return ("raw", k, v), k.nbytes + v.nbytes
-        import jax
         import jax.numpy as jnp
         from repro.serving.kv_cache import quantize_kv_device
-        kb = jax.device_get(quantize_kv_device(jnp.asarray(k)))
-        vb = jax.device_get(quantize_kv_device(jnp.asarray(v)))
+        if self.interpret is None:
+            raise RuntimeError("a quantizing tier needs its replicas' "
+                               "kernel mode: attach it to an engine first")
+        kb = jax.device_get(quantize_kv_device(jnp.asarray(k),
+                                               interpret=self.interpret))
+        vb = jax.device_get(quantize_kv_device(jnp.asarray(v),
+                                               interpret=self.interpret))
         nbytes = sum(getattr(x, "nbytes", 0) for x in (*kb, *vb))
         return ("q8", kb, vb), nbytes
 

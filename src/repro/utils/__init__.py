@@ -1,6 +1,9 @@
 """Small shared utilities."""
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,3 +43,22 @@ def assert_no_nans(tree, where: str = ""):
         arr = np.asarray(leaf)
         if np.issubdtype(arr.dtype, np.floating) and not np.isfinite(arr).all():
             raise AssertionError(f"non-finite values at {jax.tree_util.keystr(path)} {where}")
+
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache for this process; returns
+    its directory.  Called from entry points, never at import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here.  Otherwise the cache is the fixed ``.jax_cache/``
+    at the repository root: a fixed path, because a directory that moves
+    between runs never hits."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

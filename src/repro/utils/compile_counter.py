@@ -4,24 +4,30 @@ The serve path is only fast (and its iteration cost only predictable —
 the property ALISE's EWT estimates lean on) if every dispatch shape the
 engine can emit was compiled during warmup.  ``CompileCounter`` listens
 for the ``/jax/core/compile/backend_compile_duration`` monitoring event,
-which fires exactly once per real backend (XLA) compilation — cache
-hits and pure retraces don't count.  CI uses it two ways:
+which fires once per program the backend has to produce: an XLA compile,
+or a load from JAX's persistent compilation cache (also counted apart, in
+``cache_hits``).  Reuse of an executable already in the process and pure
+retraces don't count.  It is used three ways:
 
 * ``tests/test_prefill_buckets.py`` warms an engine, replays a
   mixed-length trace, and asserts the serve-time count is zero;
 * ``bench_e2e`` emits a ``compile_count`` row and raises on any
-  serve-time recompile, which fails the ``--smoke`` gate.
+  serve-time recompile, which fails the ``--smoke`` gate;
+* ``chip_smoke.py`` asserts zero compiles after warmup on the chip.
 
-The hook is a jax-internal API (``jax._src.monitoring``); construction
-degrades gracefully (``available = False``) if it disappears, so the
-library never hard-fails on a jax upgrade — only the CI gate test does,
-loudly, via ``require()``.
+The hook is a jax-internal API (``jax._src.monitoring``).  If it moves,
+construction fails at once: a gate that silently counted nothing would
+pass every recompile.
 """
 from __future__ import annotations
 
+import threading
 from typing import List, Optional
 
+from jax._src import monitoring
+
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class CompileCounter:
@@ -29,71 +35,63 @@ class CompileCounter:
 
     Usage::
 
-        cc = CompileCounter()            # attaches immediately
-        with cc.expect_no_compiles():    # raises if any compile fires
-            engine.step(t)
-        cc.detach()
+        with CompileCounter() as cc:          # attaches immediately
+            with cc.expect_no_compiles():     # raises if any compile fires
+                engine.step(t)
+        # detached on exit
 
-    or sample ``cc.count`` manually around a region.
+    or sample ``cc.count`` manually around a region and call
+    :meth:`detach` when done.
     """
 
     def __init__(self) -> None:
         self.count = 0
+        self.seconds = 0.0              # backend time of those programs
+        self.cache_hits = 0
         self.events: List[str] = []
-        self._attached = False
-        self._monitoring = None
-        try:
-            from jax._src import monitoring as _m
-            self._monitoring = _m
-            _m.register_event_duration_secs_listener(self._on_event)
-            self._attached = True
-        except Exception:   # jax internals moved; degrade to unavailable
-            self._monitoring = None
+        # compiles fire on whichever thread traced (the gateway's pump
+        # threads): the read-modify-writes below take a lock
+        self._lock = threading.Lock()
+        monitoring.register_event_duration_secs_listener(self._on_event)
+        monitoring.register_event_listener(self._on_hit)
+        self._attached = True
 
-    @property
-    def available(self) -> bool:
-        return self._attached
-
-    def require(self) -> "CompileCounter":
-        """Raise if the monitoring hook could not be attached."""
-        if not self._attached:
-            raise RuntimeError(
-                "jax._src.monitoring duration listener unavailable; the "
-                "compile-count gate cannot run on this jax version")
-        return self
-
-    # -- listener -----------------------------------------------------
+    # -- listeners ----------------------------------------------------
     def _on_event(self, name: str, secs: float, **kw) -> None:
         if name == _COMPILE_EVENT:
-            self.count += 1
-            self.events.append(f"{name}:{secs * 1e3:.1f}ms")
+            with self._lock:
+                self.count += 1
+                self.seconds += secs
+                self.events.append(f"{name}:{secs * 1e3:.1f}ms")
+
+    def _on_hit(self, name: str, **kw) -> None:
+        if name == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.cache_hits += 1
 
     # -- API ----------------------------------------------------------
     def reset(self) -> int:
-        """Zero the counter, returning the count so far."""
-        n = self.count
-        self.count = 0
-        self.events.clear()
+        """Zero the counters, returning the compile count so far."""
+        with self._lock:
+            n = self.count
+            self.count = 0
+            self.seconds = 0.0
+            self.cache_hits = 0
+            self.events.clear()
         return n
 
     def detach(self) -> None:
-        if not self._attached or self._monitoring is None:
-            return
-        m = self._monitoring
-        for name in ("_unregister_event_duration_listener_by_callback",
-                     "unregister_event_duration_listener_by_callback"):
-            fn = getattr(m, name, None)
-            if fn is not None:
-                try:
-                    fn(self._on_event)
-                    self._attached = False
-                    return
-                except Exception:
-                    pass
-        # no unregister API: neuter the callback instead of leaking counts
-        self.count = 0
-        self._on_event = lambda *a, **k: None  # type: ignore[assignment]
-        self._attached = False
+        """Unregister the listeners (idempotent)."""
+        if self._attached:
+            monitoring.unregister_event_duration_listener(self._on_event)
+            monitoring.unregister_event_listener(self._on_hit)
+            self._attached = False
+
+    def __enter__(self) -> "CompileCounter":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.detach()
 
     def expect_no_compiles(self, label: str = "") -> "_NoCompileGuard":
         return _NoCompileGuard(self, label)

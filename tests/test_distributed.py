@@ -1,6 +1,7 @@
 """Distributed correctness on an 8-device CPU mesh (run in a subprocess so
 the main pytest process keeps 1 device)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -82,8 +83,8 @@ _SCRIPT = textwrap.dedent("""
 def test_sharded_train_matches_single_device():
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        cwd=REPO, env={"PYTHONPATH": str(REPO / "src"),
-                       "PATH": "/usr/bin:/bin:/usr/local/bin"},
+        cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                       "PYTHONPATH": str(REPO / "src")},
         timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])

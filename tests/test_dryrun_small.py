@@ -1,5 +1,6 @@
 """Dry-run machinery on a small 8-device mesh (subprocess; fast)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -23,7 +24,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.models.config import ShapeSpec
     from repro.models.model import Model
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh(2, 4)
     cfg = get_smoke_config("granite-3-8b")
     model = Model(cfg, attn_chunk=16, remat=False)
     shape = ShapeSpec("d", 64, 8, "decode")
@@ -39,8 +41,6 @@ _SCRIPT = textwrap.dedent("""
                           ).lower(params_shape, ins["cache"], ins["tokens"])
         compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     coll = parse_collectives(compiled.as_text(), {"body": cfg.num_layers})
     print(json.dumps({
@@ -56,8 +56,8 @@ _SCRIPT = textwrap.dedent("""
 def test_dryrun_small_mesh_compiles_and_analyzes():
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        cwd=REPO, env={"PYTHONPATH": str(REPO / "src"),
-                       "PATH": "/usr/bin:/bin:/usr/local/bin"},
+        cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                       "PYTHONPATH": str(REPO / "src")},
         timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])

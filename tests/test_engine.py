@@ -178,3 +178,34 @@ def test_mamba_engine_state_swap():
         eng.step(t); t += 0.1
     for r in reqs2:
         assert ref[r.req_id] == list(r.output_tokens)
+
+
+def test_engine_rejects_unknown_platform(model_and_params):
+    """The Pallas mode comes from the platform the replica's parameters
+    live on: 'cpu' interprets, 'tpu' compiles, and anything else is
+    refused at construction instead of silently picking a mode."""
+    from repro.kernels import interpret_mode
+
+    assert interpret_mode("cpu") is True
+    assert interpret_mode("tpu") is False
+
+    class _Gpu:
+        platform, id = "gpu", 0
+
+    class _Leaf:                       # a parameter that lives on a GPU
+        def devices(self):
+            return {_Gpu()}
+
+    _, model, _ = model_and_params
+    for backend in ("dense", "paged"):
+        with pytest.raises(ValueError, match="'gpu'"):
+            ServingEngine(model, {"w": _Leaf()}, EngineConfig(
+                max_slots=2, max_seq_len=32, kv_backend=backend))
+
+
+def test_engine_interpret_mode_follows_params_device(model_and_params):
+    _, model, params = model_and_params
+    eng = ServingEngine(model, params, EngineConfig(
+        max_slots=2, max_seq_len=32, kv_backend="paged"))
+    assert eng.kv.device.platform == "cpu" and eng.kv.interpret is True
+    assert eng.device == f"cpu:{eng.kv.device.id}"

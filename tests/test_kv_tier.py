@@ -29,8 +29,7 @@ from repro.core.engine import EngineConfig, ServingEngine
 from repro.core.predictor import OraclePredictor
 from repro.core.request import Request, reset_request_counter
 from repro.distributed.placement import (assign_devices, available_devices,
-                                         default_device_label, device_label,
-                                         device_scope, place_params)
+                                         device_label, place_params)
 from repro.models.model import Model
 from repro.serving.kv_tier import HostKVTier, SimKVTier
 from repro.serving.observability import (EventBus, render_prometheus,
@@ -121,7 +120,7 @@ def test_tier_quantized_payloads_are_lossy_and_smaller():
                 rng.standard_normal(shape).astype(np.float32))
 
     raw = HostKVTier(1e6, page_size=4)
-    q8 = HostKVTier(1e6, page_size=4, quantize=True)
+    q8 = HostKVTier(1e6, page_size=4, quantize=True, interpret=True)
     toks = list(range(200, 208))
     for t in (raw, q8):
         t.publish(toks, 8, big)
@@ -156,7 +155,6 @@ def test_placement_spec_resolution():
     assert lbl == f"{devs[0].platform}:{devs[0].id}"
     assert available_devices(lbl) == [devs[0]]
     assert available_devices("0") == [devs[0]]
-    assert default_device_label() == device_label(devs[0])
     with pytest.raises(ValueError):
         available_devices("nonsense")
     with pytest.raises(ValueError):
@@ -173,9 +171,7 @@ def test_place_params_commits_to_device():
     assert place_params(tree, None) is tree
     placed = place_params(tree, dev)
     assert placed["w"].devices() == {dev}
-    with device_scope(dev):
-        x = jnp.arange(3)
-    assert x.devices() == {dev}
+    assert placed["w"].committed and not tree["w"].committed
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2,
@@ -191,13 +187,11 @@ def test_multi_device_replica_placement(model_and_params):
     toks = rng.integers(2, cfg.vocab_size, 12).tolist()
     outs = []
     for dev in devs:
-        with device_scope(dev):
-            eng = ServingEngine(model, place_params(params, dev),
-                                EngineConfig(
-                max_slots=2, max_seq_len=64, max_new_tokens=8,
-                strategy="alise", quantize_offload=False,
-                device=device_label(dev)), predictor=OraclePredictor())
+        eng = ServingEngine(model, place_params(params, dev), EngineConfig(
+            max_slots=2, max_seq_len=64, max_new_tokens=8, strategy="alise",
+            quantize_offload=False), predictor=OraclePredictor())
         assert eng.device == device_label(dev)
+        assert eng.kv.cache["k"].devices() == {dev}   # KV follows params
         assert eng.gauges()["device_index"] == float(dev.id)
         reset_request_counter()
         r = Request(prompt_len=12, arrival_time=0.0, true_out_len=4,
@@ -220,6 +214,19 @@ def _mk_engine(model, params, tier=None, **kw):
     if tier is not None:
         eng.attach_tier(tier)
     return eng
+
+
+def test_tier_takes_kernel_mode_from_its_replicas(model_and_params):
+    """The INT8 tier runs its kernels in the Pallas mode of the replicas
+    attached to it, and refuses a replica whose mode differs."""
+    _, model, params = model_and_params
+    tier = HostKVTier(1e6, page_size=8, quantize=True)
+    eng = _mk_engine(model, params, tier)
+    assert tier.interpret is eng.kv.interpret is True
+    with pytest.raises(ValueError, match="cannot share a tier"):
+        _mk_engine(model, params,
+                   HostKVTier(1e6, page_size=8, quantize=True,
+                              interpret=False))
 
 
 def _session(e0, e1, p1, follow, out_len=6):

@@ -197,9 +197,6 @@ def test_zero_compiles_after_warmup(model_and_params, backend, quant):
     """The CI compile gate: after explicit warmup, a mixed-length serve
     replay (chunked + packed + swaps + decode) must trigger ZERO backend
     compiles — every serve-time shape came from the warmed menu."""
-    counter = CompileCounter()
-    if not counter.available:
-        pytest.skip("jax monitoring hooks unavailable")
     cfg, model, params = model_and_params
     kw = dict(kv_backend=backend, quantize_offload=quant,
               prefill_chunk=16, iter_token_budget=48,
@@ -210,9 +207,32 @@ def test_zero_compiles_after_warmup(model_and_params, backend, quant):
     eng = ServingEngine(model, params, EngineConfig(
         max_slots=4, max_seq_len=64, max_new_tokens=16,
         strategy="alise", **kw), predictor=OraclePredictor())
-    counter.reset()
-    eng.serve(reqs)
-    counter.expect_no_compiles(f"serve[{backend},quant={quant}]")
+    with CompileCounter() as counter:
+        with counter.expect_no_compiles(f"serve[{backend},quant={quant}]"):
+            eng.serve(reqs)
+    assert all(r.output_tokens for r in reqs)
+
+
+def test_zero_compiles_after_warmup_committed_replica(model_and_params):
+    """A replica whose params are committed to a device (explicit
+    placement) and which was built under a caller's default-device scope
+    still serves with zero compiles: warmup and serving both run under the
+    engine's own device scope, and the default device is part of jit's
+    cache key."""
+    from repro.distributed.placement import place_params
+    cfg, model, params = model_and_params
+    dev = jax.devices()[-1]
+    with jax.default_device(dev):
+        eng = ServingEngine(model, place_params(params, dev), EngineConfig(
+            max_slots=4, max_seq_len=64, max_new_tokens=16, strategy="alise",
+            kv_backend="paged", page_size=8, quantize_offload=False,
+            prefill_chunk=16, warmup_compile=True),
+            predictor=OraclePredictor())
+    assert eng.kv.pool.k.devices() == {dev}
+    reqs = _mk_requests(cfg)
+    with CompileCounter() as counter:
+        with counter.expect_no_compiles("serve[committed replica]"):
+            eng.serve(reqs)
     assert all(r.output_tokens for r in reqs)
 
 
@@ -232,3 +252,43 @@ def test_scheduler_rounds_chunks_to_buckets(model_and_params):
         assert b in menu
         assert e.data["tokens"] <= b
         assert e.data["pack_size"] <= 4
+
+
+def test_compile_counter_detach_unregisters():
+    """A detached counter leaves jax's listener list and counts nothing
+    more, while a counter still attached sees the same compile."""
+    from jax._src import monitoring
+
+    first = CompileCounter()
+    first.detach()
+    first.detach()                      # idempotent
+    assert first._on_event not in monitoring.get_event_duration_listeners()
+    assert first._on_hit not in monitoring.get_event_listeners()
+    with CompileCounter() as second:
+        jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+    assert second.count >= 1 and second.seconds > 0.0
+    assert first.count == 0
+    assert second._on_event not in monitoring.get_event_duration_listeners()
+    assert second._on_hit not in monitoring.get_event_listeners()
+
+
+@pytest.mark.parametrize("env_dir", [None, "outside"])
+def test_use_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (and the code then sets nothing), else to the fixed .jax_cache/
+    at the repo root."""
+    from repro.utils import REPO_ROOT, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = use_compile_cache()
+        assert path == str(REPO_ROOT / ".jax_cache")
+        assert (REPO_ROOT / "src" / "repro" / "utils").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -239,9 +239,6 @@ def test_no_serve_time_recompiles_with_spec(model_and_params):
     a mixed-length serve with speculation on triggers zero backend
     compiles on either KV backend."""
     from repro.utils.compile_counter import CompileCounter
-    counter = CompileCounter()
-    if not counter.available:
-        pytest.skip("jax monitoring hooks unavailable")
     cfg, model, params = model_and_params
     for bkw in (dict(), dict(kv_backend="paged", page_size=8)):
         reqs = _requests(cfg, n=6, seed=3)
@@ -251,8 +248,8 @@ def test_no_serve_time_recompiles_with_spec(model_and_params):
             spec_decode=True, spec_k=3, warmup_compile=True, **bkw),
             predictor=OraclePredictor())
         assert eng._spec_ok and eng.latency.verify_cost is not None
-        counter.reset()
-        eng.serve(reqs)
+        with CompileCounter() as counter:
+            eng.serve(reqs)
         assert counter.count == 0, (
             f"{bkw or 'dense'}: {counter.count} serve-time recompiles with "
             f"spec decode on: {counter.events}")
