@@ -29,7 +29,13 @@ token-budgeted list of typed work items — over a pluggable
     kernels per the paper's Eq. 8 — the host link carries the INT8 payload;
   * recompute strategy re-runs prefill over prompt+generated tokens;
   * per-iteration wall-time profiling (bounded ring buffers) used to fit
-    the Eq. 3-5 latency model.
+    the Eq. 3-5 latency model;
+  * host spans on the profiler's clock (``serving/observability/span.py``):
+    ``alise.engine.step`` holds ``alise.sched.plan``, the swaps
+    (``alise.kv.offload`` / ``alise.kv.upload``), ``alise.engine.prefill``
+    (or ``alise.engine.prefill_pack``), ``alise.engine.decode`` and
+    ``alise.predictor.feedback``; each swap, prefill and decode span also
+    times the bus event and the ring entry of its site.
 
 Correctness invariant (tested): with greedy sampling and quantization off,
 generated tokens are bit-identical no matter how jobs are preempted/swapped,
@@ -58,7 +64,8 @@ from repro.core.scheduler import (DecodeLane, PrefillChunk, PrefillPack,
 from repro.distributed.placement import device_label
 from repro.models.model import Model
 from repro.serving.kv_cache import (DenseKVBackend, KVBackendConfig,
-                                    PagedKVBackend)
+                                    PagedKVBackend, named_program)
+from repro.serving.observability.span import span, trace_gc
 from repro.serving.sampler import REASONS, sample_and_reason, token_keys
 
 
@@ -290,7 +297,7 @@ class ServingEngine:
         # where cache-off recompute is exact, breaking on/off bit-identity.
         # Cleared on drop (recompute rebuilds exact KV).
         self._lossy_kv: set = set()
-        self._prefill = jax.jit(model.prefill)
+        self._prefill = jax.jit(named_program("alise_prefill", model.prefill))
         # bounded profiling rings: week-long gateway serves must not leak.
         # Entries lead with a time.perf_counter() timestamp so fits and
         # exported traces can be aligned post-hoc:
@@ -336,6 +343,7 @@ class ServingEngine:
         # placement label for attribution (gauges, router WARN rows)
         self.device = cfg.device or device_label(device)
         self._step_wall0 = 0.0                     # perf_counter at step start
+        trace_gc()                                 # alise.gc spans
         if cfg.warmup_compile:
             self.warmup()
 
@@ -379,13 +387,14 @@ class ServingEngine:
         if self.bus is not None and tier.bus is None:
             tier.bus = self.bus
 
-    def _span_t(self, t: float, t0: float) -> float:
-        """Trace placement of an in-step span that started at wall clock
-        ``t0``: offset from the iteration's gateway-domain timestamp ``t``
-        by the wall time elapsed since step entry.  Exact in wall mode; in
-        virtual mode it yields monotone within-iteration placement (the
-        span's ``dur`` stays informational wall seconds)."""
-        return t + (t0 - self._step_wall0)
+    def _offset(self, t: float) -> float:
+        """The step's clock (``t``, read when the step was asked for, in
+        the gateway's or the bus's domain) minus ``time.perf_counter``, as
+        of step entry: an in-step instant ``p`` on perf_counter lies at
+        ``_offset(t) + p`` on that clock.  Exact in wall mode; in virtual
+        mode it yields monotone within-iteration placement (a span's
+        ``dur`` stays informational wall seconds)."""
+        return t - self._step_wall0
 
     def gauges(self) -> Dict[str, float]:
         """Replica-level occupancy snapshot for periodic gauge sampling."""
@@ -543,22 +552,22 @@ class ServingEngine:
             return "covered", start, target_toks
         return "ready", start, target_toks
 
-    def _chunk_postwork(self, chunk: PrefillChunk, start: int, n_toks: int,
-                        logits_row, target_toks, generated_of, t: float,
-                        t0: float, dt: float, pack_size: int = 1) -> None:
-        """Everything after a chunk's dispatch: the observability event,
-        prefix publication on the final chunk, and first-token sampling
-        when a fresh prefill just completed.  Shared between the single-
-        chunk path (``pack_size=1``) and each member of a packed
-        dispatch."""
+    def _chunk_event(self, sp: span, chunk: PrefillChunk, start: int,
+                     n_toks: int, pack_size: int = 1) -> None:
+        """Queue a chunk's ``prefill_chunk`` bus event on the span of its
+        dispatch (one per member of a packed dispatch)."""
+        sp.emit("prefill_chunk", req_id=chunk.req.req_id, replica=self.name,
+                start=start, end=chunk.end, tokens=n_toks, last=chunk.last,
+                fresh=chunk.fresh, bucket=chunk.bucket, pack_size=pack_size)
+
+    def _chunk_postwork(self, chunk: PrefillChunk, logits_row, target_toks,
+                        generated_of, t: float) -> None:
+        """Everything after a chunk's dispatch: prefix publication on the
+        final chunk, and first-token sampling when a fresh prefill just
+        completed.  Shared between the single-chunk path and each member
+        of a packed dispatch."""
         r = chunk.req
         rid = r.req_id
-        if self.bus is not None:
-            self.bus.emit("prefill_chunk", t=self._span_t(t, t0), dur=dt,
-                          req_id=rid, replica=self.name, start=start,
-                          end=chunk.end, tokens=n_toks,
-                          last=chunk.last, fresh=chunk.fresh,
-                          bucket=chunk.bucket, pack_size=pack_size)
         if chunk.last and self._prefix_ok and rid not in self._lossy_kv:
             # prefill complete: publish the full pages covering the target
             # back to the index so the *next* request sharing this prefix
@@ -584,22 +593,25 @@ class ServingEngine:
         status, start, target_toks = self._chunk_prework(chunk, t)
         if status != "ready":
             return status == "covered"
-        t0 = time.perf_counter()
         if self._chunked_ok:
-            logits = self.kv.prefill_chunk(
-                self.params, r.req_id, target_toks[start:chunk.end], start)
-            r.prefilled = chunk.end
             n_chunk_toks = chunk.end - start
         else:
             assert chunk.start == 0 and chunk.last, \
                 "monolithic fallback cannot resume a partial chunk"
-            logits = self._run_prefill(r, target_toks)
-            r.prefilled = len(target_toks)
             n_chunk_toks = len(target_toks)
-        dt = time.perf_counter() - t0
-        self.prefill_times.append((t0, n_chunk_toks, dt))
-        self._chunk_postwork(chunk, start, n_chunk_toks, logits[0],
-                             target_toks, generated_of, t, t0, dt)
+        with span("engine.prefill", self.bus, self._offset(t),
+                  tokens=n_chunk_toks, start=start) as sp:
+            if self._chunked_ok:
+                logits = self.kv.prefill_chunk(
+                    self.params, r.req_id, target_toks[start:chunk.end],
+                    start)
+                r.prefilled = chunk.end
+            else:
+                logits = self._run_prefill(r, target_toks)
+                r.prefilled = len(target_toks)
+            self._chunk_event(sp, chunk, start, n_chunk_toks)
+        self.prefill_times.append((sp.t0, n_chunk_toks, sp.dur))
+        self._chunk_postwork(chunk, logits[0], target_toks, generated_of, t)
         return True
 
     def _exec_prefill_pack(self, pack: PrefillPack, generated_of,
@@ -649,16 +661,19 @@ class ServingEngine:
                 ran_any |= self._exec_prefill_chunk(chunk, generated_of, t)
             return ran_any
         items = [(c.req.req_id, toks[s:c.end], s) for c, s, toks in ready]
-        t0 = time.perf_counter()
-        logits = self.kv.prefill_pack(self.params, items, bucket=pack.bucket)
-        dt = time.perf_counter() - t0
         total = sum(c.end - s for c, s, _ in ready)
-        self.prefill_times.append((t0, total, dt))
+        with span("engine.prefill_pack", self.bus, self._offset(t),
+                  tokens=total, members=len(ready)) as sp:
+            logits = self.kv.prefill_pack(self.params, items,
+                                          bucket=pack.bucket)
+            for chunk, start, _ in ready:
+                self._chunk_event(sp, chunk, start, chunk.end - start,
+                                  len(ready))
+        self.prefill_times.append((sp.t0, total, sp.dur))
         for i, (chunk, start, target_toks) in enumerate(ready):
             chunk.req.prefilled = chunk.end
-            self._chunk_postwork(chunk, start, chunk.end - start, logits[i],
-                                 target_toks, generated_of, t, t0, dt,
-                                 pack_size=len(ready))
+            self._chunk_postwork(chunk, logits[i], target_toks, generated_of,
+                                 t)
         return True
 
     # -------------------------------------------------------------- warmup
@@ -859,19 +874,16 @@ class ServingEngine:
         path shared by the planned swap-out, page-shortfall, and
         mid-iteration-grow sites (engine KV move + memory accounting +
         request state + observability events)."""
-        t0 = time.perf_counter()
-        self._offload(victim)
-        op = self.mem.offload(victim, t)
-        victim.state = RequestState.PREEMPTED
-        victim.preempt_count += 1
         if self.bus is not None:
             self.bus.emit("preempt", t=t, req_id=victim.req_id,
                           replica=self.name, reason=reason)
-            self.bus.emit("swap_out", t=self._span_t(t, t0),
-                          dur=max(op.done_time - op.issue_time, 0.0),
-                          req_id=victim.req_id, replica=self.name,
-                          bytes=op.bytes,
-                          quantized=self.cfg.quantize_offload)
+        with span("kv.offload", self.bus, self._offset(t)) as sp:
+            self._offload(victim)
+            op = self.mem.offload(victim, t)
+            sp.emit("swap_out", req_id=victim.req_id, replica=self.name,
+                    bytes=op.bytes, quantized=self.cfg.quantize_offload)
+        victim.state = RequestState.PREEMPTED
+        victim.preempt_count += 1
 
     # ------------------------------------------------------------ main loop
     def submit(self, req: Request, now: float = 0.0) -> None:
@@ -1090,67 +1102,100 @@ class ServingEngine:
 
     def step(self, t: float) -> bool:
         """One scheduling + execution iteration; returns whether work ran."""
+        with span("engine.step"):
+            with self.step_lock, self._on_device():
+                ran_any = self._step(t)
+                stall, self._stall_debt = self._stall_debt, 0.0
+            # learning happens here — outside step_lock, after the
+            # iteration's dispatch work is done — so a slow (or
+            # pathological) predictor update can never stall token emission
+            # or a concurrent submit
+            with span("predictor.feedback"):
+                self.predictor.drain_feedback()
+            if stall > 0:
+                time.sleep(stall)          # modeled swap DMA, lock released
+        return ran_any
+
+    def _step(self, t: float) -> bool:
+        """The iteration under ``step_lock``: plan, drops and swaps, the
+        prefill items in priority order, then one fused decode batch."""
         generated_of = self._generated_of
+        self._step_wall0 = time.perf_counter()
+        self._drain_submit_box()
+        with span("sched.plan"):
+            plan = self.sched.plan(t)
 
-        def now() -> float:
-            return t
-
-        with self.step_lock, self._on_device():
-            self._step_wall0 = time.perf_counter()
-            self._drain_submit_box()
-            plan = self.sched.plan(now())
-
-            for r in plan.drop:            # recompute-strategy eviction
-                # under very tight HBM the planned victim's KV may already
-                # live in the host pool (offloaded earlier) rather than a slot
-                dropped_ctx = r.context_len
-                self._drop_kv(r.req_id)
-                self.mem.drop(r)
-                r.state = RequestState.QUEUED
-                r.preempt_count += 1
-                if self.bus is not None:
-                    self.bus.emit("drop", t=now(), req_id=r.req_id,
-                                  replica=self.name, tokens=dropped_ctx)
-            for r in plan.swap_out:
-                if not self.kv.has(r.req_id):
-                    continue               # already off-slot; nothing to move
-                self._spill(r, now(), "planned")
-            for r in plan.swap_in:
-                if self.kv.free_slot() is None:
-                    continue               # retry next iteration
-                t0 = time.perf_counter()
+        for r in plan.drop:                # recompute-strategy eviction
+            # under very tight HBM the planned victim's KV may already
+            # live in the host pool (offloaded earlier) rather than a slot
+            dropped_ctx = r.context_len
+            self._drop_kv(r.req_id)
+            self.mem.drop(r)
+            r.state = RequestState.QUEUED
+            r.preempt_count += 1
+            if self.bus is not None:
+                self.bus.emit("drop", t=t, req_id=r.req_id,
+                              replica=self.name, tokens=dropped_ctx)
+        for r in plan.swap_out:
+            if not self.kv.has(r.req_id):
+                continue                   # already off-slot; nothing to move
+            self._spill(r, t, "planned")
+        for r in plan.swap_in:
+            if self.kv.free_slot() is None:
+                continue                   # retry next iteration
+            with span("kv.upload", self.bus, self._offset(t)) as sp:
                 self._upload(r)
-                op = self.mem.upload(r, now())
-                r.state = RequestState.PREEMPTED
-                self.sched._swap_ready_at[r.req_id] = 0.0
-                if self.bus is not None:
-                    self.bus.emit("swap_in", t=self._span_t(now(), t0),
-                                  dur=max(op.done_time - op.issue_time, 0.0),
-                                  req_id=r.req_id, replica=self.name,
-                                  bytes=op.bytes)
+                op = self.mem.upload(r, t)
+                sp.emit("swap_in", req_id=r.req_id, replica=self.name,
+                        bytes=op.bytes)
+            r.state = RequestState.PREEMPTED
+            self.sched._swap_ready_at[r.req_id] = 0.0
 
-            ran_any = False
-            # compute items in priority order: prefill chunks execute as
-            # encountered; decode lanes collect into one fused batch
-            decode_lanes: List[Request] = []
-            for item in plan.items:
-                if isinstance(item, DecodeLane):
-                    decode_lanes.append(item.req)
-                elif isinstance(item, PrefillPack):
-                    ran_any |= self._exec_prefill_pack(item, generated_of,
-                                                       now())
-                else:
-                    ran_any |= self._exec_prefill_chunk(item, generated_of,
-                                                        now())
+        ran_any = False
+        # compute items in priority order: prefill chunks execute as
+        # encountered; decode lanes collect into one fused batch
+        decode_lanes: List[Request] = []
+        for item in plan.items:
+            if isinstance(item, DecodeLane):
+                decode_lanes.append(item.req)
+            elif isinstance(item, PrefillPack):
+                ran_any |= self._exec_prefill_pack(item, generated_of, t)
+            else:
+                ran_any |= self._exec_prefill_chunk(item, generated_of, t)
 
-            # decode batch
-            runnable = [r for r in decode_lanes if self.kv.has(r.req_id)]
-            if runnable and self.cfg.kv_backend == "paged":
-                runnable = self._reserve_pages(runnable, now())
-            if runnable:
-                t0 = time.perf_counter()
-                B = self.cfg.max_slots
-                k1 = (self.cfg.spec_k + 1) if self._spec_ok else 1
+        runnable = [r for r in decode_lanes if self.kv.has(r.req_id)]
+        if runnable and self.cfg.kv_backend == "paged":
+            runnable = self._reserve_pages(runnable, t)
+        if runnable:
+            self._exec_decode(runnable, generated_of, t)
+            ran_any = True
+
+        if self.bus is not None and plan.hol_blocked:
+            # charge each blocked higher-priority request the wall time of
+            # the iteration that ran lower-priority work ahead of it (the
+            # direct HoL-blocking measurement)
+            iter_dt = time.perf_counter() - self._step_wall0
+            for r in plan.hol_blocked:
+                self.bus.emit("hol_blocked", t=t, dur=iter_dt,
+                              req_id=r.req_id, replica=self.name,
+                              level=r.priority_level)
+        self._backlog_cache, self._backlog_q90 = \
+            self.sched.backlog_quantiles()
+        return ran_any
+
+    def _exec_decode(self, runnable: List[Request], generated_of,
+                     t: float) -> None:
+        """One fused decode dispatch over ``runnable``'s lanes, then each
+        lane's token(s) accepted, in the span ``alise.engine.decode``,
+        which also times the ``decode_iter`` event.  The iteration ring
+        times the lane arrays, the dispatch and its sync: from the span's
+        start to the sync's end."""
+        ctx_tokens = int(sum(r.context_len for r in runnable))
+        with span("engine.decode", self.bus, self._offset(t),
+                  lanes=len(runnable), ctx=ctx_tokens) as sp:
+            B = self.cfg.max_slots
+            k1 = (self.cfg.spec_k + 1) if self._spec_ok else 1
+            with span("decode.stage"):
                 tokens = np.zeros((B, k1), np.int32)
                 n_drafts = np.zeros((B,), np.int32)
                 active = np.zeros((B,), bool)
@@ -1179,45 +1224,41 @@ class ServingEngine:
                     if self.cfg.respect_true_len:
                         true_len[slot] = r.true_out_len
                     r.state = RequestState.RUNNING
+            if self._spec_ok:
+                # one verify-k dispatch: score the fed token plus all
+                # drafts, accept the longest exact-match run, sample the
+                # bonus token, terminate — one host sync for up to
+                # spec_k+1 emitted tokens per lane
+                s, n_emit, reasons = self.kv.decode_verify(
+                    self.params, tokens, n_drafts, active, base_gen,
+                    base_ctx, true_len, rids)
+            elif self.cfg.fused_decode:
+                # one dispatch: decode + sample + terminate on device
+                toks, reasons = self.kv.decode(
+                    self.params, tokens, active, base_gen + 1,
+                    base_ctx + 1, true_len, rids)
+            else:
+                logits = self.kv.decode_logits(self.params, tokens, active)
+            self.iter_times.append((sp.t0, ctx_tokens, len(runnable),
+                                    time.perf_counter() - sp.t0))
+            if self.bus is not None:
+                extra = {}
                 if self._spec_ok:
-                    # one verify-k dispatch: score the fed token plus all
-                    # drafts, accept the longest exact-match run, sample the
-                    # bonus token, terminate — one host sync for up to
-                    # spec_k+1 emitted tokens per lane
-                    s, n_emit, reasons = self.kv.decode_verify(
-                        self.params, tokens, n_drafts, active, base_gen,
-                        base_ctx, true_len, rids)
-                elif self.cfg.fused_decode:
-                    # one dispatch: decode + sample + terminate on device
-                    toks, reasons = self.kv.decode(
-                        self.params, tokens, active, base_gen + 1,
-                        base_ctx + 1, true_len, rids)
-                else:
-                    logits = self.kv.decode_logits(self.params, tokens,
-                                                   active)
-                ctx_tokens = int(sum(r.context_len for r in runnable))
-                dt = time.perf_counter() - t0
-                self.iter_times.append((t0, ctx_tokens, len(runnable), dt))
-                if self.bus is not None:
-                    extra = {}
-                    if self._spec_ok:
-                        extra = dict(
-                            drafted=int(n_drafts.sum()),
-                            accepted=int(sum(
-                                max(int(n_emit[sl]) - 1, 0)
-                                for sl in slot_of.values())))
-                    self.bus.emit("decode_iter", t=self._span_t(now(), t0),
-                                  dur=dt, replica=self.name,
-                                  batch=len(runnable),
-                                  ctx_tokens=ctx_tokens, **extra)
+                    extra = dict(
+                        drafted=int(n_drafts.sum()),
+                        accepted=int(sum(max(int(n_emit[sl]) - 1, 0)
+                                         for sl in slot_of.values())))
+                sp.emit("decode_iter", replica=self.name,
+                        batch=len(runnable), ctx_tokens=ctx_tokens, **extra)
+            with span("decode.accept"):
                 for r in runnable:
                     # the token must be accepted even if a neighbor's
                     # mem.grow() spill offloaded r mid-loop: this decode
-                    # already wrote r's fed token's KV (and advanced any SSM
-                    # state), so skipping would re-feed the same token after
-                    # swap-in and duplicate its KV row — accepting keeps the
-                    # "last sampled token's KV not yet written" invariant
-                    # intact for the host-pool copy
+                    # already wrote r's fed token's KV (and advanced any
+                    # SSM state), so skipping would re-feed the same token
+                    # after swap-in and duplicate its KV row — accepting
+                    # keeps the "last sampled token's KV not yet written"
+                    # invariant intact for the host-pool copy
                     slot = slot_of[r.req_id]
                     if self._spec_ok:
                         m = int(n_emit[slot])
@@ -1227,40 +1268,19 @@ class ServingEngine:
                         for i in range(m):
                             last = i == m - 1
                             self._accept_token(
-                                r, int(s[slot, i]), generated_of, now(),
+                                r, int(s[slot, i]), generated_of, t,
                                 reason=(REASONS[int(reasons[slot])]
                                         if last else ""))
                     elif self.cfg.fused_decode:
-                        self._accept_token(r, int(toks[slot]), generated_of,
-                                           now(),
-                                           reason=REASONS[int(reasons[slot])])
+                        self._accept_token(
+                            r, int(toks[slot]), generated_of, t,
+                            reason=REASONS[int(reasons[slot])])
                     else:
                         tok, reason = self._sample_host(
                             logits[slot], r.req_id, r.generated + 1,
                             r.context_len + 1, self._true_len_of(r))
-                        self._accept_token(r, tok, generated_of, now(),
+                        self._accept_token(r, tok, generated_of, t,
                                            reason=reason)
-                ran_any = True
-
-            if self.bus is not None and plan.hol_blocked:
-                # charge each blocked higher-priority request the wall
-                # time of the iteration that ran lower-priority work ahead
-                # of it (the direct HoL-blocking measurement)
-                iter_dt = time.perf_counter() - self._step_wall0
-                for r in plan.hol_blocked:
-                    self.bus.emit("hol_blocked", t=now(), dur=iter_dt,
-                                  req_id=r.req_id, replica=self.name,
-                                  level=r.priority_level)
-            self._backlog_cache, self._backlog_q90 = \
-                self.sched.backlog_quantiles()
-            stall, self._stall_debt = self._stall_debt, 0.0
-        # learning happens here — outside step_lock, after the iteration's
-        # dispatch work is done — so a slow (or pathological) predictor
-        # update can never stall token emission or a concurrent submit
-        self.predictor.drain_feedback()
-        if stall > 0:
-            time.sleep(stall)              # modeled swap DMA, lock released
-        return ran_any
 
     def step_and_poll(self, t: float) -> Tuple[bool, List[EngineEvent]]:
         """One iteration plus its events, as a single executor-friendly call
@@ -1288,6 +1308,7 @@ class ServingEngine:
                 index=len(req.output_tokens) - 1))
         if req.first_token_time is None:
             req.first_token_time = t
+            req.first_token_emit_time = self._offset(t) + time.perf_counter()
         # a request spilled mid-iteration by an earlier neighbor's grow()
         # lives in DRAM now; its byte growth is settled at upload time
         if self.mem.resident_hbm(req) and not self.mem.grow(req):

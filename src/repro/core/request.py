@@ -80,7 +80,11 @@ class Request:
 
     # --- metrics ---
     first_scheduled_time: Optional[float] = None
-    first_token_time: Optional[float] = None
+    first_token_time: Optional[float] = None   # start of the iteration
+                                           # that sampled the first token
+    first_token_emit_time: Optional[float] = None   # when the engine handed
+                                           # it to the stream buffer (the
+                                           # clock of first_scheduled_time)
     finish_time: Optional[float] = None
     preempt_count: int = 0
     swap_in_bytes: float = 0.0
@@ -171,6 +175,7 @@ def reset_runtime_state(req: Request) -> None:
     req.output_tokens = []
     req.first_scheduled_time = None
     req.first_token_time = None
+    req.first_token_emit_time = None
     req.finish_time = None
     req.preempt_count = 0
     req.swap_in_bytes = 0.0

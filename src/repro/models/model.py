@@ -1,6 +1,11 @@
 """Model assembly: decoder-only / MoE / SSM / hybrid / encoder-decoder stacks
 with scan-over-layers, remat, and train / prefill / decode entry points.
 
+The serving steps name their parts with ``jax.named_scope`` (``attention``:
+projections, KV writes and reads, attention and output projection; ``ffn``;
+``lm_head``), so that a profiler trace can tell the parts of a step apart.
+Scopes change op metadata only, not the computation.
+
 All assigned architectures run through this one implementation, selected by
 :class:`ArchConfig`.  Params are explicit pytrees; layers are stacked along a
 leading axis and iterated with ``lax.scan`` so the lowered HLO stays small for
@@ -50,12 +55,13 @@ def _apply_ffn_part(cfg: ArchConfig, p, x, ffn_kind: str, moe_groups: int = 1):
     aux = jnp.zeros((), jnp.float32)
     if ffn_kind == "none":
         return x, aux
-    h = L.apply_norm(cfg, p["ln2"], x)
-    if ffn_kind == "moe":
-        out, aux = L.apply_moe(cfg, p["ffn"], h, groups=moe_groups)
-    else:
-        out = L.apply_ffn(cfg, p["ffn"], h)
-    return x + out, aux
+    with jax.named_scope("ffn"):
+        h = L.apply_norm(cfg, p["ln2"], x)
+        if ffn_kind == "moe":
+            out, aux = L.apply_moe(cfg, p["ffn"], h, groups=moe_groups)
+        else:
+            out = L.apply_ffn(cfg, p["ffn"], h)
+        return x + out, aux
 
 
 def _apply_sublayer_full(cfg: ArchConfig, p, x, positions, kind: str,
@@ -66,14 +72,16 @@ def _apply_sublayer_full(cfg: ArchConfig, p, x, positions, kind: str,
     h = L.apply_norm(cfg, p["ln1"], x)
     cache = None
     if kind == "attn":
-        q, k, v = L._project_qkv(cfg, p["attn"], h, positions)
-        if attn_impl == "chunked":
-            attn = L.chunked_attention(cfg, q, k, v, causal=causal,
-                                       q_chunk=attn_chunk, kv_chunk=attn_chunk)
-        else:
-            attn = L.full_attention(cfg, q, k, v, causal=causal)
-        B, S = x.shape[:2]
-        out = attn.reshape(B, S, -1) @ p["attn"]["wo"]
+        with jax.named_scope("attention"):
+            q, k, v = L._project_qkv(cfg, p["attn"], h, positions)
+            if attn_impl == "chunked":
+                attn = L.chunked_attention(cfg, q, k, v, causal=causal,
+                                           q_chunk=attn_chunk,
+                                           kv_chunk=attn_chunk)
+            else:
+                attn = L.full_attention(cfg, q, k, v, causal=causal)
+            B, S = x.shape[:2]
+            out = attn.reshape(B, S, -1) @ p["attn"]["wo"]
         if want_cache:
             cache = {"k": k, "v": v}
     else:
@@ -92,12 +100,13 @@ def _apply_sublayer_full(cfg: ArchConfig, p, x, positions, kind: str,
 def _attn_decode(cfg: ArchConfig, p_attn, h, k_cache, v_cache, lengths):
     """h: (B,1,D).  Writes new kv at index `lengths`, attends to lengths+1."""
     B = h.shape[0]
-    q, k, v = L._project_qkv(cfg, p_attn, h, lengths[:, None])
-    rows = jnp.arange(B)
-    k_cache = k_cache.at[rows, lengths].set(k[:, 0].astype(k_cache.dtype))
-    v_cache = v_cache.at[rows, lengths].set(v[:, 0].astype(v_cache.dtype))
-    attn = L.decode_attention(cfg, q[:, 0], k_cache, v_cache, lengths + 1)
-    out = attn.reshape(B, -1) @ p_attn["wo"]
+    with jax.named_scope("attention"):
+        q, k, v = L._project_qkv(cfg, p_attn, h, lengths[:, None])
+        rows = jnp.arange(B)
+        k_cache = k_cache.at[rows, lengths].set(k[:, 0].astype(k_cache.dtype))
+        v_cache = v_cache.at[rows, lengths].set(v[:, 0].astype(v_cache.dtype))
+        attn = L.decode_attention(cfg, q[:, 0], k_cache, v_cache, lengths + 1)
+        out = attn.reshape(B, -1) @ p_attn["wo"]
     return out[:, None, :], k_cache, v_cache
 
 
@@ -218,7 +227,8 @@ class Model:
     def _logits(self, params, h):
         head = (params["embed"].T if self.cfg.tie_embeddings
                 else params["lm_head"])
-        return h @ head
+        with jax.named_scope("lm_head"):
+            return h @ head
 
     # ------------------------------------------------------------- encoder
     def _encode(self, params, enc_embeds):
@@ -542,12 +552,13 @@ class Model:
         def body(h, inp):
             p_l, k_l, v_l = inp                   # (B, Smax, KVH, hd)
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_l = k_l.at[rows, q_pos].set(k.astype(k_l.dtype))
-            v_l = v_l.at[rows, q_pos].set(v.astype(v_l.dtype))
-            attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, lengths,
-                                    interpret)
-            h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
+                k_l = k_l.at[rows, q_pos].set(k.astype(k_l.dtype))
+                v_l = v_l.at[rows, q_pos].set(v.astype(v_l.dtype))
+                attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, lengths,
+                                        interpret)
+                h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, (k_l, v_l)
 
@@ -668,12 +679,13 @@ class Model:
         def body(h, inp):
             p_l, k_l, v_l = inp                               # (Smax, KVH, hd)
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_l = k_l.at[write_idx].set(k[0].astype(k_l.dtype))
-            v_l = v_l.at[write_idx].set(v[0].astype(v_l.dtype))
-            attn = self._chunk_attn(q, k_l[None], v_l[None], q_pos, kv_pos,
-                                    start_vec, interpret)
-            h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
+                k_l = k_l.at[write_idx].set(k[0].astype(k_l.dtype))
+                v_l = v_l.at[write_idx].set(v[0].astype(v_l.dtype))
+                attn = self._chunk_attn(q, k_l[None], v_l[None], q_pos,
+                                        kv_pos, start_vec, interpret)
+                h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, (k_l, v_l)
 
@@ -734,16 +746,19 @@ class Model:
 
         def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_all = k_all.at[i, write_page, write_off].set(
-                k[0].astype(k_all.dtype))
-            v_all = v_all.at[i, write_page, write_off].set(
-                v[0].astype(v_all.dtype))
-            kg = k_all[i, block_tables[0]].reshape(1, Smax, *k_all.shape[3:])
-            vg = v_all[i, block_tables[0]].reshape(1, Smax, *v_all.shape[3:])
-            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start_vec,
-                                    interpret)
-            h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
+                k_all = k_all.at[i, write_page, write_off].set(
+                    k[0].astype(k_all.dtype))
+                v_all = v_all.at[i, write_page, write_off].set(
+                    v[0].astype(v_all.dtype))
+                kg = k_all[i, block_tables[0]].reshape(1, Smax,
+                                                       *k_all.shape[3:])
+                vg = v_all[i, block_tables[0]].reshape(1, Smax,
+                                                       *v_all.shape[3:])
+                attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start_vec,
+                                        interpret)
+                h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, k_all, v_all
 
@@ -801,12 +816,13 @@ class Model:
         def body(h, inp):
             p_l, k_l, v_l = inp                               # (N, Smax, KVH, hd)
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_l = k_l.at[rows, write_idx].set(k.astype(k_l.dtype))
-            v_l = v_l.at[rows, write_idx].set(v.astype(v_l.dtype))
-            attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, start,
-                                    interpret)
-            h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
+                k_l = k_l.at[rows, write_idx].set(k.astype(k_l.dtype))
+                v_l = v_l.at[rows, write_idx].set(v.astype(v_l.dtype))
+                attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, start,
+                                        interpret)
+                h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, (k_l, v_l)
 
@@ -848,16 +864,17 @@ class Model:
 
         def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_all = k_all.at[i, write_page, write_off].set(
-                k.astype(k_all.dtype))
-            v_all = v_all.at[i, write_page, write_off].set(
-                v.astype(v_all.dtype))
-            kg = k_all[i, block_tables].reshape(N, Smax, *k_all.shape[3:])
-            vg = v_all[i, block_tables].reshape(N, Smax, *v_all.shape[3:])
-            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start,
-                                    interpret)
-            h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
+                k_all = k_all.at[i, write_page, write_off].set(
+                    k.astype(k_all.dtype))
+                v_all = v_all.at[i, write_page, write_off].set(
+                    v.astype(v_all.dtype))
+                kg = k_all[i, block_tables].reshape(N, Smax, *k_all.shape[3:])
+                vg = v_all[i, block_tables].reshape(N, Smax, *v_all.shape[3:])
+                attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start,
+                                        interpret)
+                h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, k_all, v_all
 
@@ -911,25 +928,27 @@ class Model:
 
         def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, positions)
-            k_all = k_all.at[i, write_page, write_off].set(
-                k[:, 0].astype(k_all.dtype))
-            v_all = v_all.at[i, write_page, write_off].set(
-                v[:, 0].astype(v_all.dtype))
-            if attn_impl == "kernel":
-                from repro.kernels.paged_attention.paged_attention import \
-                    paged_attention
-                attn = paged_attention(q[:, 0], k_all, v_all, block_tables,
-                                       lengths + 1, layer=i,
-                                       interpret=interpret)
-            else:
-                n_pages = block_tables.shape[1]
-                kg = k_all[i, block_tables].reshape(
-                    B, n_pages * page, *k_all.shape[3:])
-                vg = v_all[i, block_tables].reshape(
-                    B, n_pages * page, *v_all.shape[3:])
-                attn = L.decode_attention(cfg, q[:, 0], kg, vg, lengths + 1)
-            h = h + (attn.reshape(B, -1) @ p_l["attn"]["wo"])[:, None, :]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, positions)
+                k_all = k_all.at[i, write_page, write_off].set(
+                    k[:, 0].astype(k_all.dtype))
+                v_all = v_all.at[i, write_page, write_off].set(
+                    v[:, 0].astype(v_all.dtype))
+                if attn_impl == "kernel":
+                    from repro.kernels.paged_attention.paged_attention \
+                        import paged_attention
+                    attn = paged_attention(q[:, 0], k_all, v_all,
+                                           block_tables, lengths + 1,
+                                           layer=i, interpret=interpret)
+                else:
+                    n_pages = block_tables.shape[1]
+                    kg = k_all[i, block_tables].reshape(
+                        B, n_pages * page, *k_all.shape[3:])
+                    vg = v_all[i, block_tables].reshape(
+                        B, n_pages * page, *v_all.shape[3:])
+                    attn = L.decode_attention(cfg, q[:, 0], kg, vg,
+                                              lengths + 1)
+                h = h + (attn.reshape(B, -1) @ p_l["attn"]["wo"])[:, None, :]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind)
             return h, k_all, v_all
 
@@ -997,16 +1016,17 @@ class Model:
 
         def body(h, p_l, k_all, v_all, i):
             h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-            k_all = k_all.at[i, write_page, write_off].set(
-                k.astype(k_all.dtype))
-            v_all = v_all.at[i, write_page, write_off].set(
-                v.astype(v_all.dtype))
-            kg = k_all[i, block_tables].reshape(B, Smax, *k_all.shape[3:])
-            vg = v_all[i, block_tables].reshape(B, Smax, *v_all.shape[3:])
-            attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, lengths,
-                                    interpret)
-            h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
+            with jax.named_scope("attention"):
+                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
+                k_all = k_all.at[i, write_page, write_off].set(
+                    k.astype(k_all.dtype))
+                v_all = v_all.at[i, write_page, write_off].set(
+                    v.astype(v_all.dtype))
+                kg = k_all[i, block_tables].reshape(B, Smax, *k_all.shape[3:])
+                vg = v_all[i, block_tables].reshape(B, Smax, *v_all.shape[3:])
+                attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, lengths,
+                                        interpret)
+                h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, k_all, v_all
 

@@ -44,6 +44,7 @@ from repro.serving.gateway.admission import (AdmissionConfig,
                                              AdmissionController, Verdict)
 from repro.serving.gateway.metrics import GatewayMetrics
 from repro.serving.gateway.router import GatewayRouter
+from repro.serving.observability.span import span
 
 
 class RequestStream:
@@ -200,8 +201,13 @@ class Gateway:
         return wait + intrinsic
 
     def submit(self, req: Request, now: Optional[float] = None) -> RequestStream:
-        """Admission decision + (if admitted) dispatch.  Always returns a
-        stream; a shed request's stream carries a single ``shed`` event."""
+        """Admission decision + (if admitted) dispatch, in the host span
+        ``alise.gateway.submit``.  Always returns a stream; a shed
+        request's stream carries a single ``shed`` event."""
+        with span("gateway.submit"):
+            return self._admit(req, now)
+
+    def _admit(self, req: Request, now: Optional[float]) -> RequestStream:
         t = self.now() if now is None else now
         if now is None:
             req.arrival_time = t
@@ -298,6 +304,14 @@ class Gateway:
             self._spawn_pump(d)
 
     # ------------------------------------------------------------ event pump
+    def _deliver(self, evs: List[EngineEvent]) -> None:
+        """Hand a step's engine events to their streams, in the host span
+        ``alise.gateway.deliver``."""
+        if evs:
+            with span("gateway.deliver"):
+                for ev in evs:
+                    self._dispatch_event(ev)
+
     def _dispatch_event(self, ev: EngineEvent) -> None:
         stream = self.streams.get(ev.req_id)
         if stream is None or stream.closed:
@@ -476,8 +490,7 @@ class Gateway:
         for d in self.router.alive_drivers():
             if d.engine.sched.live:
                 ran |= d.engine.step(t)
-            for ev in d.engine.poll_events():
-                self._dispatch_event(ev)
+            self._deliver(d.engine.poll_events())
         if ran and self.cfg.virtual_dt is not None:
             self._vclock += self.cfg.virtual_dt
         return ran
@@ -496,8 +509,7 @@ class Gateway:
             if d.engine.queue_depth() > 0:
                 ran, evs = await loop.run_in_executor(
                     executor, d.engine.step_and_poll, t)
-                for ev in evs:
-                    self._dispatch_event(ev)
+                self._deliver(evs)
                 if ran or evs:
                     backoff = self.cfg.idle_sleep_s
                     if self._progress is not None:

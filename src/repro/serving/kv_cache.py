@@ -20,10 +20,15 @@ Offload/upload runs through the Pallas ``kv_quant`` kernels when
 ``quantize_offload`` is set: KV is quantized **on device** and the host link
 carries the INT8 payload + per-row scales (paper Eq. 8), instead of moving
 fp tensors and quantizing in host numpy.
+
+Every program is jitted from a named function (:func:`named_program`), so
+the profiler's trace names its executions ``jit_alise_<what>``; a decode
+dispatch runs in the host spans ``alise.decode.stage`` (page tables and
+lane arrays to the device), ``alise.decode.dispatch`` and
+``alise.decode.sync``.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -33,6 +38,7 @@ import numpy as np
 
 from repro.kernels import interpret_mode
 from repro.kernels.kv_quant import kv_dequantize_op, kv_quantize_op
+from repro.serving.observability.span import span
 
 _QBLK = 128                                   # kv_quant row-tile
 
@@ -41,6 +47,20 @@ _QBLK = 128                                   # kv_quant row-tile
 # Donation deletes the caller's input buffer on every backend (CPU
 # included): callers rebind the returned arrays and never touch the
 # donated ones again.
+
+
+def named_program(name: str, fn, **static):
+    """``fn`` with the keywords ``static`` bound, as a function called
+    ``name``: JAX names a jitted program after its function, so its
+    executions read ``jit_<name>`` in a profiler trace."""
+    def program(*args):
+        return fn(*args, **static)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+def _to_device(*arrays) -> list:
+    return [jnp.asarray(a) for a in arrays]
 
 
 # --------------------------------------------------------------- page pool
@@ -78,14 +98,15 @@ class PagedKVPool:
         self.refs: Dict[int, int] = {}                   # page -> refcount
         # CoW page duplication and page-granular writes as jitted, donated
         # dispatches: an eager at[].set would materialize a whole new pool
-        self._cow_copy = jax.jit(
-            lambda k, v, s, d: (k.at[:, d].set(k[:, s][:, None]),
-                                v.at[:, d].set(v[:, s][:, None])),
-            donate_argnums=(0, 1))
-        self._scatter = jax.jit(
-            lambda k, v, idx, ks, vs: (k.at[:, idx].set(ks.astype(k.dtype)),
-                                       v.at[:, idx].set(vs.astype(v.dtype))),
-            donate_argnums=(0, 1))
+        def alise_kv_cow(k, v, s, d):
+            return (k.at[:, d].set(k[:, s][:, None]),
+                    v.at[:, d].set(v[:, s][:, None]))
+
+        def alise_kv_scatter(k, v, idx, ks, vs):
+            return (k.at[:, idx].set(ks.astype(k.dtype)),
+                    v.at[:, idx].set(vs.astype(v.dtype)))
+        self._cow_copy = jax.jit(alise_kv_cow, donate_argnums=(0, 1))
+        self._scatter = jax.jit(alise_kv_scatter, donate_argnums=(0, 1))
 
     # ----------------------------------------------------------- refcounts
     def take_page(self) -> int:
@@ -363,6 +384,15 @@ class KVBackend:
                 f"{menu[-1]}; the scheduler must clamp chunk spans")
         return self._pow2_bucket(n)
 
+    def _dispatch_sync(self, program, params, kv, args):
+        """One decode dispatch and the wait for its sampled outputs: returns
+        (the outputs before the KV, as numpy arrays; the new KV)."""
+        with span("decode.dispatch"):
+            *out, kv = program(params, kv, *args, self._base_key)
+        with span("decode.sync"):
+            out = jax.device_get(out)
+        return [np.asarray(o) for o in out], kv
+
     # ----------------------------------------------------------- interface
     def write_prefill(self, rid: int, pcache, length: int) -> None:
         """Place batch-index-0 of a prefill cache into a free lane."""
@@ -494,30 +524,33 @@ class DenseKVBackend(KVBackend):
         self._axes = self._cache_batch_axes()
         # the cache pytree (arg 1) is consumed and fully re-emitted by the
         # fused step: donate it so it updates in place
-        self._fused = jax.jit(functools.partial(
-            model.decode_step_sampled, **self._sample_kwargs()),
-            donate_argnums=(1,))
+        self._fused = jax.jit(named_program(
+            "alise_decode", model.decode_step_sampled,
+            **self._sample_kwargs()), donate_argnums=(1,))
         self._fused_verify = None
         if cfg.spec_k > 0 and model.supports_spec_decode():
-            self._fused_verify = jax.jit(functools.partial(
-                model.decode_verify_sampled, interpret=self.interpret,
-                **self._sample_kwargs()), donate_argnums=(1,))
-        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+            self._fused_verify = jax.jit(named_program(
+                "alise_decode_verify", model.decode_verify_sampled,
+                interpret=self.interpret, **self._sample_kwargs()),
+                donate_argnums=(1,))
+        self._decode = jax.jit(named_program("alise_decode_logits",
+                                             model.decode_step),
+                               donate_argnums=(1,))
         self._chunk = None
         if model.supports_chunked_prefill():
             # one jitted dispatch per chunk over the *full* cache: the slot
             # gather, chunk compute, and slot scatter all fuse — no eager
             # whole-cache copies on the host side per chunk (the batch axis
             # of k/v is 1 for every chunk-capable family)
-            def chunk_cache(params, k_cache, v_cache, toks, slot, start,
-                            chunk_len):
+            def alise_prefill_chunk(params, k_cache, v_cache, toks, slot,
+                                    start, chunk_len):
                 logits, k_new, v_new = model.prefill_chunk(
                     params, k_cache[:, slot], v_cache[:, slot], toks,
                     start, chunk_len, interpret=self.interpret)
                 return (logits,
                         k_cache.at[:, slot].set(k_new.astype(k_cache.dtype)),
                         v_cache.at[:, slot].set(v_new.astype(v_cache.dtype)))
-            self._chunk = jax.jit(chunk_cache, donate_argnums=(1, 2))
+            self._chunk = jax.jit(alise_prefill_chunk, donate_argnums=(1, 2))
         self._pack = None
         if model.supports_prefill_pack():
             # packed twin: N segment rows gather N slot stripes, run the
@@ -525,15 +558,15 @@ class DenseKVBackend(KVBackend):
             # out-of-range slot index: JAX clamps the gather (harmless
             # read of the last stripe) and DROPS the scatter, so pack
             # padding never touches live cache state.
-            def pack_cache(params, k_cache, v_cache, toks, slots, start,
-                           chunk_len):
+            def alise_prefill_pack(params, k_cache, v_cache, toks, slots,
+                                   start, chunk_len):
                 logits, k_new, v_new = model.prefill_pack(
                     params, k_cache[:, slots], v_cache[:, slots], toks,
                     start, chunk_len, interpret=self.interpret)
                 return (logits,
                         k_cache.at[:, slots].set(k_new.astype(k_cache.dtype)),
                         v_cache.at[:, slots].set(v_new.astype(v_cache.dtype)))
-            self._pack = jax.jit(pack_cache, donate_argnums=(1, 2))
+            self._pack = jax.jit(alise_prefill_pack, donate_argnums=(1, 2))
         if cfg.prefix_cache and model.supports_chunked_prefill():
             from repro.serving.prefix_cache import DensePrefixCache
             acfg = model.cfg
@@ -545,12 +578,12 @@ class DenseKVBackend(KVBackend):
 
             # hit placement as one jitted, cache-donated dispatch (the
             # eager per-tensor at[].set would copy the whole cache twice)
-            def place(kc, vc, lengths, k, v, slot, hit):
+            def alise_kv_place(kc, vc, lengths, k, v, slot, hit):
                 span = k.shape[1]
                 kc = kc.at[:, slot, :span].set(k.astype(kc.dtype))
                 vc = vc.at[:, slot, :span].set(v.astype(vc.dtype))
                 return kc, vc, lengths.at[slot].set(hit)
-            self._place = jax.jit(place, donate_argnums=(0, 1, 2))
+            self._place = jax.jit(alise_kv_place, donate_argnums=(0, 1, 2))
 
     def _cache_batch_axes(self) -> Dict[str, int]:
         fam = self.model.cfg.family
@@ -791,13 +824,12 @@ class DenseKVBackend(KVBackend):
 
     def decode(self, params, tokens, active, new_gen, new_ctx, true_len,
                rids):
-        tok, reason, cache = self._fused(
-            params, self.cache, jnp.asarray(tokens), jnp.asarray(active),
-            jnp.asarray(new_gen), jnp.asarray(new_ctx),
-            jnp.asarray(true_len), jnp.asarray(rids), self._base_key)
-        self.cache = cache
-        tok, reason = jax.device_get((tok, reason))
-        return np.asarray(tok), np.asarray(reason)
+        with span("decode.stage"):
+            args = _to_device(tokens, active, new_gen, new_ctx, true_len,
+                              rids)
+        (tok, reason), self.cache = self._dispatch_sync(
+            self._fused, params, self.cache, args)
+        return tok, reason
 
     def supports_spec_decode(self) -> bool:
         return self._fused_verify is not None
@@ -808,14 +840,12 @@ class DenseKVBackend(KVBackend):
         # watermark: never attended (causal masks + ``lengths``) and
         # overwritten by the next dispatch before they could matter —
         # rollback costs nothing on the dense stripes
-        s, n_emit, reason, cache = self._fused_verify(
-            params, self.cache, jnp.asarray(tokens), jnp.asarray(n_drafts),
-            jnp.asarray(active), jnp.asarray(base_gen),
-            jnp.asarray(base_ctx), jnp.asarray(true_len),
-            jnp.asarray(rids), self._base_key)
-        self.cache = cache
-        s, n_emit, reason = jax.device_get((s, n_emit, reason))
-        return np.asarray(s), np.asarray(n_emit), np.asarray(reason)
+        with span("decode.stage"):
+            args = _to_device(tokens, n_drafts, active, base_gen, base_ctx,
+                              true_len, rids)
+        (s, n_emit, reason), self.cache = self._dispatch_sync(
+            self._fused_verify, params, self.cache, args)
+        return s, n_emit, reason
 
     def decode_logits(self, params, tokens, active):
         logits, cache = self._decode(params, self.cache, jnp.asarray(tokens))
@@ -869,24 +899,25 @@ class PagedKVBackend(KVBackend):
             if spec_on else [])
         # kv (arg 1) is the whole page pool, consumed and re-emitted: donate
         # so pages are written in place
-        self._fused = jax.jit(functools.partial(
-            model.paged_decode_step_sampled, attn_impl=cfg.attn_impl,
-            interpret=self.interpret, **self._sample_kwargs()),
-            donate_argnums=(1,))
+        self._fused = jax.jit(named_program(
+            "alise_decode", model.paged_decode_step_sampled,
+            attn_impl=cfg.attn_impl, interpret=self.interpret,
+            **self._sample_kwargs()), donate_argnums=(1,))
         self._fused_verify = None
         if spec_on:
-            self._fused_verify = jax.jit(functools.partial(
-                model.paged_decode_verify_sampled, interpret=self.interpret,
-                **self._sample_kwargs()), donate_argnums=(1,))
+            self._fused_verify = jax.jit(named_program(
+                "alise_decode_verify", model.paged_decode_verify_sampled,
+                interpret=self.interpret, **self._sample_kwargs()),
+                donate_argnums=(1,))
         # chunked prefill always attends via the logical-order page gather
         # (bit-exact vs the dense stripe path); attn_impl only selects the
         # decode-step kernel
-        self._chunk = jax.jit(functools.partial(
-            model.paged_prefill_chunk, interpret=self.interpret),
-            donate_argnums=(1,))
-        self._pack = (jax.jit(functools.partial(
-            model.paged_prefill_pack, interpret=self.interpret),
-            donate_argnums=(1,))
+        self._chunk = jax.jit(named_program(
+            "alise_prefill_chunk", model.paged_prefill_chunk,
+            interpret=self.interpret), donate_argnums=(1,))
+        self._pack = (jax.jit(named_program(
+            "alise_prefill_pack", model.paged_prefill_pack,
+            interpret=self.interpret), donate_argnums=(1,))
             if model.supports_prefill_pack() else None)
         if cfg.prefix_cache:
             from repro.serving.prefix_cache import PagedPrefixCache
@@ -1153,32 +1184,30 @@ class PagedKVBackend(KVBackend):
                rids):
         B, pg = self.cfg.max_slots, self.cfg.page_size
         maxp = self.max_pages_per_seq
-        tables = np.full((B, maxp), self.scratch_page, np.int32)
-        lens = np.zeros((B,), np.int32)
-        wp = np.full((B,), self.scratch_page, np.int32)
-        wo = np.zeros((B,), np.int32)
-        for slot, rid in enumerate(self.slot_req):
-            if rid is None or not active[slot]:
-                continue
-            # the fed token's KV lands at logical position `pos`: grow the
-            # page table first (caller guarantees a free page via
-            # pages_shortfall), then point the write at its page slot
-            self.pool.extend(rid, 1)
-            pos = self.pool.lengths[rid] - 1
-            pt = self.pool.page_table[rid]
-            tables[slot, :len(pt)] = pt
-            lens[slot] = pos
-            wp[slot] = pt[pos // pg]
-            wo[slot] = pos % pg
-        tok, reason, kv = self._fused(
-            params, {"k": self.pool.k, "v": self.pool.v},
-            jnp.asarray(tokens), jnp.asarray(tables), jnp.asarray(lens),
-            jnp.asarray(wp), jnp.asarray(wo), jnp.asarray(active),
-            jnp.asarray(new_gen), jnp.asarray(new_ctx),
-            jnp.asarray(true_len), jnp.asarray(rids), self._base_key)
+        with span("decode.stage"):
+            tables = np.full((B, maxp), self.scratch_page, np.int32)
+            lens = np.zeros((B,), np.int32)
+            wp = np.full((B,), self.scratch_page, np.int32)
+            wo = np.zeros((B,), np.int32)
+            for slot, rid in enumerate(self.slot_req):
+                if rid is None or not active[slot]:
+                    continue
+                # the fed token's KV lands at logical position `pos`: grow
+                # the page table first (caller guarantees a free page via
+                # pages_shortfall), then point the write at its page slot
+                self.pool.extend(rid, 1)
+                pos = self.pool.lengths[rid] - 1
+                pt = self.pool.page_table[rid]
+                tables[slot, :len(pt)] = pt
+                lens[slot] = pos
+                wp[slot] = pt[pos // pg]
+                wo[slot] = pos % pg
+            args = _to_device(tokens, tables, lens, wp, wo, active, new_gen,
+                              new_ctx, true_len, rids)
+        (tok, reason), kv = self._dispatch_sync(
+            self._fused, params, {"k": self.pool.k, "v": self.pool.v}, args)
         self.pool.k, self.pool.v = kv["k"], kv["v"]
-        tok, reason = jax.device_get((tok, reason))
-        return np.asarray(tok), np.asarray(reason)
+        return tok, reason
 
     def supports_spec_decode(self) -> bool:
         return self._fused_verify is not None
@@ -1188,39 +1217,36 @@ class PagedKVBackend(KVBackend):
         B, pg = self.cfg.max_slots, self.cfg.page_size
         K1 = self.cfg.spec_k + 1
         maxp = self.max_pages_per_seq
-        # one extra table column holds the lane's scratch page right after
-        # its real pages: a scratch-resident write at logical position p
-        # (p // pg == len(table)) gathers back at exactly position p
-        tables = np.full((B, maxp + 1), self.scratch_page, np.int32)
-        lens = np.zeros((B,), np.int32)
-        wp = np.full((B, K1), self.scratch_page, np.int32)
-        wo = np.broadcast_to(np.arange(K1, dtype=np.int32) % pg,
-                             (B, K1)).copy()
-        for slot, rid in enumerate(self.slot_req):
-            if rid is None or not active[slot]:
-                continue
-            # NO pre-extend: speculative positions past the last real page
-            # land on the lane's scratch page, promoted only on accept
-            pos = self.pool.lengths[rid]
-            pt = self.pool.page_table[rid]
-            tables[slot, :len(pt)] = pt
-            tables[slot, len(pt)] = self.lane_scratch[slot]
-            lens[slot] = pos
-            for i in range(K1):
-                p = pos + i
-                wp[slot, i] = (pt[p // pg] if p // pg < len(pt)
-                               else self.lane_scratch[slot])
-                wo[slot, i] = p % pg
-        s, n_emit, reason, kv = self._fused_verify(
-            params, {"k": self.pool.k, "v": self.pool.v},
-            jnp.asarray(tokens), jnp.asarray(tables), jnp.asarray(lens),
-            jnp.asarray(wp), jnp.asarray(wo), jnp.asarray(n_drafts),
-            jnp.asarray(active), jnp.asarray(base_gen),
-            jnp.asarray(base_ctx), jnp.asarray(true_len),
-            jnp.asarray(rids), self._base_key)
+        with span("decode.stage"):
+            # one extra table column holds the lane's scratch page right
+            # after its real pages: a scratch-resident write at logical
+            # position p (p // pg == len(table)) gathers back at exactly p
+            tables = np.full((B, maxp + 1), self.scratch_page, np.int32)
+            lens = np.zeros((B,), np.int32)
+            wp = np.full((B, K1), self.scratch_page, np.int32)
+            wo = np.broadcast_to(np.arange(K1, dtype=np.int32) % pg,
+                                 (B, K1)).copy()
+            for slot, rid in enumerate(self.slot_req):
+                if rid is None or not active[slot]:
+                    continue
+                # NO pre-extend: speculative positions past the last real
+                # page land on the lane's scratch page, promoted on accept
+                pos = self.pool.lengths[rid]
+                pt = self.pool.page_table[rid]
+                tables[slot, :len(pt)] = pt
+                tables[slot, len(pt)] = self.lane_scratch[slot]
+                lens[slot] = pos
+                for i in range(K1):
+                    p = pos + i
+                    wp[slot, i] = (pt[p // pg] if p // pg < len(pt)
+                                   else self.lane_scratch[slot])
+                    wo[slot, i] = p % pg
+            args = _to_device(tokens, tables, lens, wp, wo, n_drafts, active,
+                              base_gen, base_ctx, true_len, rids)
+        (s, n_emit, reason), kv = self._dispatch_sync(
+            self._fused_verify, params, {"k": self.pool.k, "v": self.pool.v},
+            args)
         self.pool.k, self.pool.v = kv["k"], kv["v"]
-        s, n_emit, reason = jax.device_get((s, n_emit, reason))
-        s, n_emit = np.asarray(s), np.asarray(n_emit)
         # commit after the sync: lanes whose accepted span crossed into
         # scratch promote it into the page table (pointer move, no copy)
         # and take a fresh scratch page; rejected speculative writes are
@@ -1235,4 +1261,4 @@ class PagedKVBackend(KVBackend):
                 # caller guaranteed a free page via pages_shortfall
                 self.lane_scratch[slot] = self.pool.take_page()
             self.pool.lengths[rid] = new_len
-        return s, n_emit, np.asarray(reason)
+        return s, n_emit, reason

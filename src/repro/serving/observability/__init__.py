@@ -6,10 +6,13 @@ gateway, scheduler, engine, memory manager, prefix cache, and simulator;
 from the same stream the exporters derive a Chrome-trace/Perfetto
 timeline, scheduler-quality telemetry (estimate error, queueing-delay
 decomposition, head-of-line blocking), and Prometheus-style gauge text.
+:class:`span` puts the serving path's host spans (``alise.*``) on the
+profiler's clock and times the bus's span events.
 """
 from repro.serving.observability.bus import EventBus, TraceEvent
 from repro.serving.observability.prom import render_prometheus
 from repro.serving.observability.quality import analyze_quality
+from repro.serving.observability.span import span
 from repro.serving.observability.trace_export import (to_chrome_trace,
                                                       validate_chrome_trace,
                                                       write_chrome_trace)
@@ -17,5 +20,5 @@ from repro.serving.observability.trace_export import (to_chrome_trace,
 __all__ = [
     "EventBus", "TraceEvent",
     "to_chrome_trace", "validate_chrome_trace", "write_chrome_trace",
-    "analyze_quality", "render_prometheus",
+    "analyze_quality", "render_prometheus", "span",
 ]

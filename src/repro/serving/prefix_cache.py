@@ -415,7 +415,7 @@ class DensePrefixCache:
         # one jitted, store-donated dispatch per publish: gather every new
         # page out of the stripe (vmapped dynamic slice) and scatter them
         # into the store in one go — not one full-store copy per page
-        def store_pages(k_store, v_store, k_src, v_src, rows, starts):
+        def alise_kv_store(k_store, v_store, k_src, v_src, rows, starts):
             def sl(src):
                 return jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
                     src, s, page_size, axis=1))(starts)
@@ -423,7 +423,7 @@ class DensePrefixCache:
             vs = jnp.moveaxis(sl(v_src), 0, 1).astype(v_store.dtype)
             return k_store.at[:, rows].set(ks), v_store.at[:, rows].set(vs)
 
-        self._store_pages = jax.jit(store_pages, donate_argnums=(0, 1))
+        self._store_pages = jax.jit(alise_kv_store, donate_argnums=(0, 1))
 
     def probe(self, tokens) -> int:
         if not tokens:

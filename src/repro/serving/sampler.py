@@ -84,14 +84,16 @@ def sample_and_reason(logits, keys, *, greedy_sampling: bool,
     accepting this token; ``true_len`` is the per-slot trace stop (pass a
     huge value when ``respect_true_len`` is off).  Returns
     ``(tokens (B,) int32, reason (B,) int32)`` with reason codes from
-    REASON_* (0 = keep decoding).
+    REASON_* (0 = keep decoding).  Traced under the ``sample`` scope.
     """
-    tok = sample_tokens(logits, keys, greedy_sampling=greedy_sampling,
-                        temp=temp, top_k=top_k)
-    reason = _reason_of(tok, new_gen, new_ctx, true_len,
-                        eos_token=eos_token, max_new_tokens=max_new_tokens,
-                        max_seq_len=max_seq_len)
-    return tok, reason.astype(jnp.int32)
+    with jax.named_scope("sample"):
+        tok = sample_tokens(logits, keys, greedy_sampling=greedy_sampling,
+                            temp=temp, top_k=top_k)
+        reason = _reason_of(tok, new_gen, new_ctx, true_len,
+                            eos_token=eos_token,
+                            max_new_tokens=max_new_tokens,
+                            max_seq_len=max_seq_len)
+        return tok, reason.astype(jnp.int32)
 
 
 def verify_and_reason(logits, drafts, n_drafts, keys, active, *,
@@ -119,31 +121,33 @@ def verify_and_reason(logits, drafts, n_drafts, keys, active, *,
 
     Returns ``(samples (B, K1), n_emit (B,), reason (B,))`` — the caller
     emits ``samples[b, :n_emit[b]]`` and applies ``reason[b]`` to the last
-    of them; inactive lanes emit nothing.
+    of them; inactive lanes emit nothing.  Traced under the ``sample``
+    scope.
     """
-    B, K1, _ = logits.shape
-    flat = logits.reshape(B * K1, logits.shape[-1])
-    if greedy_sampling:
-        s = greedy(flat).reshape(B, K1)
-    else:
-        kflat = keys.reshape(B * K1, *keys.shape[2:])
-        s = jax.vmap(lambda lg, k: temperature(lg, k, temp=temp,
-                                               top_k=top_k))(
-            flat, kflat).reshape(B, K1)
-    pos = jnp.arange(K1)[None, :]                          # (1, K1)
-    prev = jnp.roll(s, 1, axis=1)                          # prev[:, i] = s[:, i-1]
-    match = (pos == 0) | ((drafts == prev)
-                          & (pos <= n_drafts[:, None]))
-    acc = jnp.cumprod(match.astype(jnp.int32), axis=1)
-    m_cand = acc.sum(axis=1)                               # 1 + accepted drafts
-    new_gen = base_gen[:, None] + 1 + pos
-    new_ctx = base_ctx[:, None] + 1 + pos
-    reason = _reason_of(s, new_gen, new_ctx, true_len[:, None],
-                        eos_token=eos_token, max_new_tokens=max_new_tokens,
-                        max_seq_len=max_seq_len)
-    first_term = jnp.min(jnp.where(reason > 0, pos, K1), axis=1)
-    m = jnp.clip(jnp.minimum(m_cand, first_term + 1), 1, K1)
-    n_emit = jnp.where(active, m, 0).astype(jnp.int32)
-    last = jnp.take_along_axis(reason, (m - 1)[:, None], axis=1)[:, 0]
-    reason_out = jnp.where(active, last, 0).astype(jnp.int32)
-    return s, n_emit, reason_out
+    with jax.named_scope("sample"):
+        B, K1, _ = logits.shape
+        flat = logits.reshape(B * K1, logits.shape[-1])
+        if greedy_sampling:
+            s = greedy(flat).reshape(B, K1)
+        else:
+            kflat = keys.reshape(B * K1, *keys.shape[2:])
+            s = jax.vmap(lambda lg, k: temperature(lg, k, temp=temp,
+                                                   top_k=top_k))(
+                flat, kflat).reshape(B, K1)
+        pos = jnp.arange(K1)[None, :]                          # (1, K1)
+        prev = jnp.roll(s, 1, axis=1)                          # prev[:, i] = s[:, i-1]
+        match = (pos == 0) | ((drafts == prev)
+                              & (pos <= n_drafts[:, None]))
+        acc = jnp.cumprod(match.astype(jnp.int32), axis=1)
+        m_cand = acc.sum(axis=1)                               # 1 + accepted drafts
+        new_gen = base_gen[:, None] + 1 + pos
+        new_ctx = base_ctx[:, None] + 1 + pos
+        reason = _reason_of(s, new_gen, new_ctx, true_len[:, None],
+                            eos_token=eos_token, max_new_tokens=max_new_tokens,
+                            max_seq_len=max_seq_len)
+        first_term = jnp.min(jnp.where(reason > 0, pos, K1), axis=1)
+        m = jnp.clip(jnp.minimum(m_cand, first_term + 1), 1, K1)
+        n_emit = jnp.where(active, m, 0).astype(jnp.int32)
+        last = jnp.take_along_axis(reason, (m - 1)[:, None], axis=1)[:, 0]
+        reason_out = jnp.where(active, last, 0).astype(jnp.int32)
+        return s, n_emit, reason_out
