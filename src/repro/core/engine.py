@@ -404,6 +404,8 @@ class ServingEngine:
         g["live_requests"] = float(len(self.sched.live))
         for i, d in enumerate(self.sched.queue_depths()):
             g[f"mlfq_q{i}_depth"] = float(d)
+        for w, n in self.kv.decode_width_steps.items():
+            g[f"decode_width_{w}_steps"] = float(n)
         pool = getattr(self.kv, "pool", None)
         if pool is not None:
             g["pool_free_pages"] = float(len(pool.free_pages))
@@ -682,7 +684,8 @@ class ServingEngine:
         engine: one chunk dispatch per prefill bucket, one packed dispatch
         per bucket (when packing is on; a single member already compiles
         the full ``(width, bucket)`` shape — dummy rows pad the rest), the
-        fused (or legacy per-slot) decode step, and the host-side
+        fused decode step at every page-table width the paged backend
+        dispatches (or the legacy per-slot step), and the host-side
         first-token sampling chain.  Each bucket runs twice — the first
         rep compiles, the second measures — and the measured seconds land
         in ``self.latency.bucket_costs`` so EWT prices a bucketed chunk at
@@ -754,8 +757,9 @@ class ServingEngine:
         zeros = np.zeros((B,), np.int32)
         tl = np.full((B,), np.iinfo(np.int32).max, np.int32)
         if self.cfg.fused_decode:
-            self.kv.decode(self.params, tokens, active, zeros, zeros, tl,
-                           zeros)
+            # every page-table width of the paged ladder
+            self.kv.warm_decode(self.params, tokens, active, zeros, zeros,
+                                tl, zeros)
         else:
             jax.block_until_ready(
                 self.kv.decode_logits(self.params, tokens, active))
@@ -1191,8 +1195,9 @@ class ServingEngine:
         times the lane arrays, the dispatch and its sync: from the span's
         start to the sync's end."""
         ctx_tokens = int(sum(r.context_len for r in runnable))
+        width = self.kv.decode_width([r.req_id for r in runnable])
         with span("engine.decode", self.bus, self._offset(t),
-                  lanes=len(runnable), ctx=ctx_tokens) as sp:
+                  lanes=len(runnable), ctx=ctx_tokens, width=width) as sp:
             B = self.cfg.max_slots
             k1 = (self.cfg.spec_k + 1) if self._spec_ok else 1
             with span("decode.stage"):

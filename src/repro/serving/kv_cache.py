@@ -26,11 +26,17 @@ the profiler's trace names its executions ``jit_alise_<what>``; a decode
 dispatch runs in the host spans ``alise.decode.stage`` (page tables and
 lane arrays to the device), ``alise.decode.dispatch`` and
 ``alise.decode.sync``.
+
+The paged decode step gathers only as many pages per lane as its page table
+is wide, and that width follows the live context: the smallest rung of
+:func:`decode_width_ladder` covering the longest active lane
+(:func:`decode_table_width`).  Positions past a lane's end are masked before
+the softmax, so a narrower table changes no result, only the copy.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +67,24 @@ def named_program(name: str, fn, **static):
 
 def _to_device(*arrays) -> list:
     return [jnp.asarray(a) for a in arrays]
+
+
+def decode_width_ladder(max_seq_len: int, page_size: int) -> Tuple[int, ...]:
+    """The page-table widths a paged decode step is compiled at: multiples
+    of an eighth of a full table (at least one page), up to the full
+    ``max_seq_len // page_size`` pages."""
+    full = max_seq_len // page_size
+    step = max(1, full // 8)
+    return tuple(range(step, full, step)) + (full,)
+
+
+def decode_table_width(ladder: Sequence[int], pages: Iterable[int]) -> int:
+    """The decode page-table width for a batch whose active lanes hold
+    ``pages`` pages each, counted after the step's extend: the smallest
+    rung of ``ladder`` covering the largest (the smallest rung when no lane
+    is active)."""
+    need = max(pages, default=0)
+    return next(w for w in ladder if w >= need)
 
 
 # --------------------------------------------------------------- page pool
@@ -341,6 +365,9 @@ class KVBackend:
         # jitted dispatch (sampler.token_keys): the stream is independent of
         # batch composition, warmup, preemption, and spec-on/off
         self._base_key = jax.random.PRNGKey(cfg.seed)
+        # decode steps served per page-table width, keyed by the positions
+        # gathered per lane (the paged backend's ladder; empty on dense)
+        self.decode_width_steps: Dict[int, int] = {}
 
     # --------------------------------------------------------------- lanes
     def slot_of(self, rid: int) -> Optional[int]:
@@ -444,6 +471,17 @@ class KVBackend:
                rids):
         """One fused iteration -> (sampled (B,), reason (B,)) numpy."""
         raise NotImplementedError
+
+    def decode_width(self, rids: Sequence[int]) -> int:
+        """Positions per lane the next decode step over lanes ``rids``
+        attends over: the full stripe here."""
+        return self.cfg.max_seq_len
+
+    def warm_decode(self, params, *args) -> None:
+        """Run :meth:`decode` (``args`` as it takes them, every lane
+        inactive) at every shape serving dispatches, so that none compiles
+        later."""
+        self.decode(params, *args)
 
     def supports_spec_decode(self) -> bool:
         """Whether :meth:`decode_verify` is available (``spec_k > 0`` and
@@ -883,6 +921,10 @@ class PagedKVBackend(KVBackend):
         acfg = model.cfg
         spec_on = cfg.spec_k > 0 and model.supports_spec_decode()
         self.max_pages_per_seq = cfg.max_seq_len // cfg.page_size
+        self.decode_widths = decode_width_ladder(cfg.max_seq_len,
+                                                 cfg.page_size)
+        self.decode_width_steps = {w * cfg.page_size: 0
+                                   for w in self.decode_widths}
         self.pool = PagedKVPool(PagedKVConfig(
             # +1 sacrificial scratch page, plus one private scratch page
             # per decode lane when verify-k is on (speculative KV lands
@@ -1180,18 +1222,40 @@ class PagedKVBackend(KVBackend):
                            if self.pool.lengths[rid] % pg == 0)
         return max(0, need_new - len(self.pool.free_pages))
 
+    def _decode_pages(self, rids: Sequence[int]) -> int:
+        """Page-table width of the next decode step over lanes ``rids``:
+        their page counts once the step's extend has grown them."""
+        pool = self.pool
+        return decode_table_width(self.decode_widths, (
+            max(len(pool.page_table[r]),
+                pool.pages_needed(pool.lengths[r] + 1)) for r in rids))
+
+    def decode_width(self, rids: Sequence[int]) -> int:
+        if self._fused_verify is not None:      # verify-k keeps full tables
+            return (self.max_pages_per_seq + 1) * self.cfg.page_size
+        return self._decode_pages(rids) * self.cfg.page_size
+
+    def warm_decode(self, params, *args) -> None:
+        for pages in self.decode_widths:
+            self.decode(params, *args, pages=pages)
+
     def decode(self, params, tokens, active, new_gen, new_ctx, true_len,
-               rids):
+               rids, *, pages: Optional[int] = None):
+        """The fused step over page tables ``pages`` wide: by default the
+        smallest rung covering the active lanes, counted in
+        ``decode_width_steps``; warm-up fixes each rung in turn."""
         B, pg = self.cfg.max_slots, self.cfg.page_size
-        maxp = self.max_pages_per_seq
         with span("decode.stage"):
-            tables = np.full((B, maxp), self.scratch_page, np.int32)
+            lanes = [(slot, rid) for slot, rid in enumerate(self.slot_req)
+                     if rid is not None and active[slot]]
+            if pages is None:
+                pages = self._decode_pages([rid for _, rid in lanes])
+                self.decode_width_steps[pages * pg] += 1
+            tables = np.full((B, pages), self.scratch_page, np.int32)
             lens = np.zeros((B,), np.int32)
             wp = np.full((B,), self.scratch_page, np.int32)
             wo = np.zeros((B,), np.int32)
-            for slot, rid in enumerate(self.slot_req):
-                if rid is None or not active[slot]:
-                    continue
+            for slot, rid in lanes:
                 # the fed token's KV lands at logical position `pos`: grow
                 # the page table first (caller guarantees a free page via
                 # pages_shortfall), then point the write at its page slot

@@ -19,8 +19,9 @@ Spans of the serving path (nesting as shown):
   ``alise.sched.plan``, ``alise.kv.offload`` / ``alise.kv.upload`` (swaps),
   ``alise.engine.prefill`` (``tokens``, ``start``),
   ``alise.engine.prefill_pack`` (``tokens``, ``members``),
-  ``alise.engine.decode`` (``lanes``, ``ctx``: active lanes and the context
-  tokens they held before the step) and ``alise.predictor.feedback``;
+  ``alise.engine.decode`` (``lanes``, ``ctx``, ``width``: active lanes, the
+  context tokens they held before the step, and the positions per lane its
+  attention gathers) and ``alise.predictor.feedback``;
 - under ``alise.engine.decode``: ``alise.decode.stage`` (page tables, lane
   arrays, host to device), ``alise.decode.dispatch``, ``alise.decode.sync``
   (the wait for the sampled tokens) and ``alise.decode.accept`` (token

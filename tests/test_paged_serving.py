@@ -6,7 +6,9 @@ per-token KV writes, block-table construction, attention through the kernel,
 request-level snapshot/restore (the swap unit ALISE moves between tiers) —
 plus the serving-level invariant: a ServingEngine on the paged backend
 produces greedy outputs bit-identical to the dense slotted backend, with and
-without forced preemption/swapping.
+without forced preemption/swapping — and the decode page-table width that
+follows the live context: its ladder, the step at a trimmed width against
+the full one, and warm-up compiling every rung.
 """
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,11 @@ from repro.core.request import Request, reset_request_counter
 from repro.kernels.paged_attention import (paged_attention_ref,
                                            paged_decode_attention)
 from repro.models.model import Model
-from repro.serving.kv_cache import PagedKVConfig, PagedKVPool
+from repro.serving.kv_cache import (KVBackendConfig, PagedKVBackend,
+                                    PagedKVConfig, PagedKVPool,
+                                    decode_table_width, decode_width_ladder)
+from repro.serving.observability import EventBus, render_prometheus
+from repro.utils.compile_counter import CompileCounter
 
 KEY = jax.random.PRNGKey(0)
 
@@ -276,3 +282,181 @@ def test_paged_engine_kernel_impl_matches(model_and_params):
         eng.serve(reqs)
         by_impl[impl] = {r.req_id: list(r.output_tokens) for r in reqs}
     assert by_impl["gather"] == by_impl["kernel"]
+
+
+# ------------------------------------------------- decode page-table width
+
+@pytest.mark.parametrize("max_seq_len,page_size", [
+    (2048, 16), (64, 8), (32, 8), (160, 16), (48, 16), (8, 8)])
+def test_decode_width_ladder(max_seq_len, page_size):
+    """Rising rungs, each a multiple of an eighth of the full table (at
+    least one page), ending at the full table."""
+    ladder = decode_width_ladder(max_seq_len, page_size)
+    full = max_seq_len // page_size
+    step = max(1, full // 8)
+    assert all(a < b for a, b in zip(ladder, ladder[1:]))
+    assert ladder[-1] == full
+    assert all(w % step == 0 for w in ladder[:-1])
+    assert ladder[0] == min(step, full)
+    if (max_seq_len, page_size) == (2048, 16):
+        assert ladder == (16, 32, 48, 64, 80, 96, 112, 128)
+
+
+def test_decode_table_width_covers_active_lanes():
+    """The width is the smallest rung covering every lane's pages."""
+    ladder = decode_width_ladder(2048, 16)
+    rng = np.random.default_rng(0)
+    for _ in range(200):
+        pages = rng.integers(1, 129, int(rng.integers(1, 33))).tolist()
+        w = decode_table_width(ladder, pages)
+        assert w in ladder and w >= max(pages)
+        assert all(r < max(pages) for r in ladder if r < w)
+    assert decode_table_width(ladder, []) == ladder[0]
+
+
+def _paged_backend(model, max_seq_len=128, page_size=8, slots=4):
+    cfg = KVBackendConfig(max_slots=slots, max_seq_len=max_seq_len,
+                          page_size=page_size, quantize_offload=False)
+    return PagedKVBackend(model, cfg, 64, jax.devices()[0])
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 5, 8, 15])
+def test_decode_width_at_page_boundaries(model_and_params, k):
+    """A lane holding k*page - 1, k*page or k*page + 1 tokens feeds one
+    more: it holds k, k + 1 and k + 1 pages after the extend, and the step
+    takes the rung covering that (rungs of 2 pages at 16 pages a table)."""
+    _, model, _ = model_and_params
+    kv = _paged_backend(model)
+    pg = kv.cfg.page_size
+    assert kv.decode_widths == (2, 4, 6, 8, 10, 12, 14, 16)
+    for rid, (tokens, pages) in enumerate(
+            ((k * pg - 1, k), (k * pg, k + 1), (k * pg + 1, k + 1))):
+        kv.pool.allocate(rid, tokens)
+        rung = min(w for w in kv.decode_widths if w >= pages)
+        assert kv.decode_width([rid]) == rung * pg
+        kv.pool.extend(rid, 1)
+        assert len(kv.pool.page_table[rid]) == pages
+        kv.pool.free(rid)
+
+
+def test_all_inactive_decode_takes_smallest_rung(model_and_params):
+    _, model, params = model_and_params
+    kv = _paged_backend(model)
+    assert kv.decode_width([]) == kv.decode_widths[0] * kv.cfg.page_size
+    B = kv.cfg.max_slots
+    zeros = np.zeros((B,), np.int32)
+    kv.decode(params, np.zeros((B, 1), np.int32), np.zeros((B,), bool),
+              zeros, zeros, np.full((B,), 1 << 30, np.int32), zeros)
+    steps = kv.decode_width_steps
+    assert steps[kv.decode_widths[0] * kv.cfg.page_size] == 1
+    assert sum(steps.values()) == 1
+
+
+@pytest.mark.parametrize("attn_impl", ["gather", "kernel"])
+def test_trimmed_decode_step_matches_full_width(model_and_params, attn_impl):
+    """The fused step over page tables cut to the ladder's width returns
+    the tokens, reasons and KV of the full-width step, and logits within
+    float32 round-off, for random lane lengths."""
+    cfg, model, params = model_and_params
+    page, maxp, n_pages = 8, 16, 80
+    ladder = decode_width_ladder(maxp * page, page)
+    rng = np.random.default_rng(7)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    shape = (cfg.num_layers, n_pages, page, cfg.num_kv_heads, cfg.hd)
+    kv = {"k": jax.random.normal(keys[0], shape, model.kv_dtype),
+          "v": jax.random.normal(keys[1], shape, model.kv_dtype)}
+    for cap in (5 * page, 10 * page, maxp * page - 1):
+        B = 5
+        lens = rng.integers(0, cap, B).astype(np.int32)
+        active = rng.random(B) < 0.8
+        active[0] = True
+        lens[~active] = 0
+        pages = -(-(lens + 1) // page)           # after the step's extend
+        free = rng.permutation(np.arange(1, n_pages))
+        full = np.zeros((B, maxp), np.int32)      # page 0: the scratch page
+        wp = np.zeros((B,), np.int32)
+        used = 0
+        for b in range(B):
+            if active[b]:
+                full[b, :pages[b]] = free[used:used + pages[b]]
+                used += pages[b]
+                wp[b] = full[b, lens[b] // page]
+        wo = np.where(active, lens % page, 0).astype(np.int32)
+        width = decode_table_width(ladder, pages[active])
+        assert width < maxp or cap == maxp * page - 1
+        tokens = jnp.asarray(rng.integers(2, cfg.vocab_size, (B, 1)),
+                             jnp.int32)
+        gen = jnp.ones((B,), jnp.int32)
+        args = (jnp.asarray(lens), jnp.asarray(wp), jnp.asarray(wo))
+        lane = (jnp.asarray(active), gen, jnp.asarray(lens + 1),
+                jnp.full((B,), 1 << 30, jnp.int32),
+                jnp.arange(B, dtype=jnp.int32), jax.random.PRNGKey(0))
+        out = {}
+        for name, table in (("full", full), ("trim", full[:, :width])):
+            logits, _ = model.paged_decode_step(
+                params, kv, tokens, jnp.asarray(table), *args,
+                attn_impl=attn_impl, interpret=True)
+            tok, reason, new_kv = model.paged_decode_step_sampled(
+                params, kv, tokens, jnp.asarray(table), *args, *lane,
+                attn_impl=attn_impl, interpret=True,
+                max_seq_len=maxp * page)
+            out[name] = (np.asarray(logits), np.asarray(tok),
+                         np.asarray(reason), np.asarray(new_kv["k"]))
+        np.testing.assert_allclose(out["trim"][0], out["full"][0],
+                                   rtol=1e-5, atol=1e-5)
+        for a, b in zip(out["trim"][1:], out["full"][1:]):
+            np.testing.assert_array_equal(a, b)
+
+
+def _width_run(model, params, cfg, full_width: bool, **kw):
+    reqs = _mk_requests(cfg, (56, 48, 40), (7, 15, 23))
+    eng = ServingEngine(model, params, EngineConfig(
+        max_slots=3, max_seq_len=64, max_new_tokens=56, strategy="vllm",
+        quantize_offload=False, kv_backend="paged", page_size=8, **kw),
+        predictor=OraclePredictor())
+    if full_width:      # every step at the full table, as before the ladder
+        eng.kv.decode_widths = (eng.kv.max_pages_per_seq,)
+        eng.kv.decode_width_steps = {64: 0}
+    eng.serve(reqs)
+    return {r.req_id: list(r.output_tokens) for r in reqs}, eng
+
+
+def test_engine_across_rungs_serves_full_width_tokens(model_and_params):
+    """Lanes that grow from 1 to 8 pages cross every rung and serve the
+    greedy tokens of the full-width step."""
+    cfg, model, params = model_and_params
+    trimmed, eng = _width_run(model, params, cfg, full_width=False)
+    full, ref = _width_run(model, params, cfg, full_width=True)
+    assert trimmed == full
+    assert sum(1 for n in eng.kv.decode_width_steps.values() if n) >= 3
+    assert ref.kv.decode_width_steps[64] == len(ref.iter_times)
+
+
+def test_warmup_compiles_every_decode_width(model_and_params):
+    """After warm-up, a request whose context grows across every rung
+    compiles nothing, and ``decode_width_steps`` (gauges and Prometheus
+    text too) counts a step at each rung it crossed."""
+    cfg, model, params = model_and_params
+    reset_request_counter()
+    req = Request(prompt_len=3, arrival_time=0.0, true_out_len=60,
+                  prompt_tokens=[5, 6, 7])
+    eng = ServingEngine(model, params, EngineConfig(
+        max_slots=2, max_seq_len=64, max_new_tokens=60, strategy="alise",
+        quantize_offload=False, kv_backend="paged", page_size=8,
+        prefill_chunk=16, warmup_compile=True), predictor=OraclePredictor())
+    steps = eng.kv.decode_width_steps
+    assert set(steps) == {8 * w for w in range(1, 9)}
+    assert not any(steps.values())              # warm-up steps not counted
+    with CompileCounter() as counter:
+        with counter.expect_no_compiles("serve across every decode width"):
+            eng.serve([req])
+    assert len(req.output_tokens) == 60
+    assert all(steps[w] > 0 for w in steps), steps
+    assert sum(steps.values()) == len(eng.iter_times)
+    g = eng.gauges()
+    assert all(g[f"decode_width_{w}_steps"] == n for w, n in steps.items())
+    bus = EventBus()
+    bus.gauge(g, replica="e0", t=0.0)
+    text = render_prometheus(bus)
+    assert f'alise_decode_width_64_steps{{replica="e0"}} {float(steps[64])}' \
+        in text

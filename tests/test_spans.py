@@ -8,6 +8,7 @@ import contextlib
 import gc
 import re
 import tempfile
+from collections import Counter
 import warnings
 from pathlib import Path
 
@@ -120,11 +121,14 @@ def test_spans_nest_as_documented(traced, child, parent):
 def test_decode_and_prefill_spans_carry_their_stats(traced):
     tr, _, eng = traced
     dec = tr.named("alise.engine.decode")
-    assert dec and all(set(s[3]) == {"lanes", "ctx"} for s in dec)
+    assert dec and all(set(s[3]) == {"lanes", "ctx", "width"} for s in dec)
     # the iteration ring saw the same lanes and context tokens
     assert [(s[3]["lanes"], s[3]["ctx"]) for s in dec] == \
         [(b, c) for _, c, b, _ in eng.iter_times]
     assert all(s[3]["lanes"] >= 1 and s[3]["ctx"] >= 10 for s in dec)
+    # the backend counted a step at each width the spans gathered
+    widths = Counter(s[3]["width"] for s in dec)
+    assert widths == +Counter(eng.kv.decode_width_steps)
     pre = tr.named("alise.engine.prefill")
     assert [s[3] for s in pre] == [{"tokens": 10, "start": 0}] * 3
     assert tr.named("alise.gc")
