@@ -1,19 +1,26 @@
 """One run of one cell of the on-chip benchmark.
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
-configuration (``configs/<config>.json``: the model's sizes, the engine
-and gateway settings it is served with, its SLO limits and the limit of
-its correctness check) and a traffic mix (``traffic/<traffic>.json``).
-Each metric is a reader of its own (``metrics/<metric>.py``) over the run
-record this module builds.  Everything is found by the names in
-``BENCHMARK.json``: a cell, a configuration, a mix or a metric is added
-by adding files and entries.
+configuration (``configs/<config>.json``: the model's sizes, its
+architecture, the engine and gateway settings it is served with, its SLO
+limits and the limit of its correctness check) and a traffic mix
+(``traffic/<traffic>.json``).  The configuration's ``"architecture"``
+names a module ``archs/<architecture>.py`` that holds all of the model's
+mathematics the benchmark uses: the parameter tree the weights are made
+for, the plain reference that decides ``correct`` and the counts of a
+step's work that the rooflines read.  This module, ``readers.py`` and
+``calibrate.py`` reach the model only through it.  Each metric is a
+reader of its own (``metrics/<metric>.py``) over the run record this
+module builds.  Everything is found by the names in ``BENCHMARK.json``
+and the configuration files: a cell, a configuration, an architecture, a
+mix or a metric is added by adding files and entries.
 
 The system under test is the program's normal serving path: one
 ``ServingEngine`` replica per chip behind the ``Gateway``, in wall clock
 with the concurrent pump.  The benchmark makes the weights from the seed
-(``weights.py``), submits the requests itself and reads each stream as a
-client would, on the gateway's clock:
+(``weights.py``, over the architecture's parameter tree), submits the
+requests itself and reads each stream as a client would, on the gateway's
+clock:
 
 requests go in on the schedule and are timed from when they were due;
 traffic starts ``ramp_s`` before the window, only arrivals inside the
@@ -21,20 +28,23 @@ window are measured, and arrivals go on until every measured request has
 finished or ``drain_cap_s`` has passed (a measured request still open
 then has failed).
 
-After the window the program's state is freed and the reference
-(``reference.py``) decides ``correct`` from a sample of the finished
-requests.  ``--trace 1`` also records a profiler trace of the middle of the
-window, with the program's layer entry points annotated from here, and
-reports the per-layer metrics instead of the end-to-end ones.
+After the window the program's state is freed and the architecture's
+reference (compared by ``reference.py``) decides ``correct`` from a sample
+of the finished requests.  ``--trace 1`` also records a profiler trace of
+the middle of the window, with the program's layer entry points annotated
+from here, and reports the per-layer metrics instead of the end-to-end
+ones.
 """
 from __future__ import annotations
 
 import asyncio
+import functools
 import gc
 import importlib.util
 import json
 import os
 import shutil
+import sys
 import tempfile
 import threading
 import time
@@ -46,6 +56,7 @@ import numpy as np
 
 HERE = Path(__file__).resolve().parent
 REPO = HERE.parents[1]
+ARCHS = HERE / "archs"
 TRACE_S = 10.0                  # longest traced slice of the window
 CLOCK_SLACK_S = 1.0             # see the one-clock check in run_cell
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -70,6 +81,7 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    architecture: object            # the module archs/<architecture>.py
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -82,19 +94,40 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
     if name not in cells:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
-    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    return Cell(name, w["chips"], load_json(root / conf["file"]),
+    path = root / {c["name"]: c for c in bench["configs"]}[w["config"]]["file"]
+    conf = load_json(path)
+    if "architecture" not in conf:
+        raise KeyError(f"{path} names no architecture: give it "
+                       '"architecture": "<name>" for archs/<name>.py')
+    return Cell(name, w["chips"], conf,
                 load_json(HERE / "traffic" / f"{w['traffic']}.json"),
                 [m for m in bench["end_to_end"] if _applies(m, name)],
-                [m for m in bench["per_layer"] if _applies(m, name)])
+                [m for m in bench["per_layer"] if _applies(m, name)],
+                architecture(conf["architecture"]))
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, name: str):
+    """The Python file at ``path`` as a module, loaded once a process."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod         # as an import would; dataclasses need it
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(name: str):
+    """The architecture module ``archs/<name>.py``: ``params(sizes)``,
+    ``summarize``, ``decode_step`` and ``prefill_chunk``."""
+    path = ARCHS / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no architecture {name!r}: there is no "
+                                f"file {path}")
+    return _load(path, f"_arch_{name}")
 
 
 def reader(metric: str):
-    path = HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"_metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(HERE / "metrics" / f"{metric}.py", f"_metric_{metric}").read
 
 
 # ------------------------------------------------------------ the record
@@ -137,6 +170,7 @@ class Record:
     peak: object = None             # peaks.Peak of the chip
     timeline: object = None         # trace_reduce.Timeline (traced runs)
     clock_offset: float = 0.0       # perf_counter - gateway clock
+    architecture: object = None     # the cell's architecture module
 
     @property
     def measured(self) -> List[Req]:
@@ -189,9 +223,10 @@ def arch_config(conf: dict):
     return ArchConfig(name=conf["name"], **conf["arch"])
 
 
-def build(conf: dict, seed: int, device):
-    """(gateway, engine, params): the weights from the seed, one warmed-up
-    replica on ``device``, the gateway in front of it."""
+def build(cell: Cell, seed: int, device):
+    """(gateway, engine, params): the weights from the seed over the
+    cell's architecture, one warmed-up replica on ``device``, the gateway
+    in front of it."""
     import jax
     from repro.core.engine import EngineConfig, ServingEngine
     from repro.core.predictor import OraclePredictor
@@ -199,12 +234,14 @@ def build(conf: dict, seed: int, device):
     from repro.serving.gateway import AdmissionConfig, Gateway, GatewayConfig
     from . import weights
 
+    conf = cell.config
     if conf["predictor"] != "oracle":
         raise ValueError(f"unknown predictor {conf['predictor']!r}")
     model = Model(arch_config(conf), **conf["model"])
     with jax.default_device(device):
         template = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        params = weights.make_params(template, conf["arch"], seed)
+        params = weights.make_params(template, cell.architecture,
+                                     conf["arch"], seed)
     engine = ServingEngine(model, params, EngineConfig(**conf["engine"]),
                            predictor=OraclePredictor())
     places = {d for leaf in jax.tree.leaves(engine.params)
@@ -452,7 +489,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     conf = cell.config
 
     setup = Compiles()
-    gw, engine, params = build(conf, seed, dev)
+    gw, engine, params = build(cell, seed, dev)
     if trace:
         annotate(gw, engine)
     setup_s = time.perf_counter() - t_start
@@ -491,11 +528,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     t_ref = time.perf_counter()
     ref_args = (conf["arch"], seed, samples, conf["engine"]["max_seq_len"],
                 conf["check"]["sample_requests"])
+    summarize = cell.architecture.summarize
     gaps, control_gaps = [], None
     if samples and control:
-        gaps, control_gaps = reference.control_gaps(*ref_args, control)
+        gaps, control_gaps = reference.control_gaps(summarize, *ref_args,
+                                                    control)
     elif samples:
-        gaps = reference.served_gaps(*ref_args)
+        gaps = reference.served_gaps(summarize, *ref_args)
     log(f"[bench] reference over {len(samples)} requests "
         f"({sum(len(s) for _, s in samples)} served tokens) took "
         f"{time.perf_counter() - t_ref:.2f}s; widest gaps "
@@ -505,7 +544,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                   for c in checks.values())
 
     record = Record(conf["arch"], conf["slo"], seconds, window, recs,
-                    iters, pool_tokens, setup_s, peak, timeline, off)
+                    iters, pool_tokens, setup_s, peak, timeline, off,
+                    cell.architecture)
     if record_hook is not None:
         record_hook(record)
     wanted = cell.per_layer if trace else cell.end_to_end

@@ -17,7 +17,6 @@ from typing import List, Optional
 
 import numpy as np
 
-from . import flops
 from . import trace_reduce as TR
 
 
@@ -52,7 +51,8 @@ def met_slo(rec, r) -> bool:
 def decode_work(rec):
     """(work, device seconds) of the decode steps in the traced window:
     the work from each annotated decode dispatch's lanes and context
-    tokens, the time from the decode programs' executions on the chip."""
+    tokens, counted by the run's architecture module, the time from the
+    decode programs' executions on the chip."""
     tl = rec.timeline
     if tl is None:
         return None
@@ -60,8 +60,9 @@ def decode_work(rec):
     dev_s = TR.module_seconds(tl, "decode")
     if not spans or dev_s <= 0:
         return None
-    work = [flops.decode_step(rec.sizes, int(s.stats["lanes"]),
-                              int(s.stats["ctx"])) for s in spans]
+    work = [rec.architecture.decode_step(
+        rec.sizes, int(s.stats["lanes"]), int(s.stats["ctx"]))
+        for s in spans]
     return work, dev_s
 
 
@@ -69,9 +70,9 @@ def prefill_work(rec):
     tl = rec.timeline
     if tl is None:
         return []
-    return [flops.prefill_chunk(rec.sizes, int(s.stats["start"]),
-                                int(s.stats["tokens"]))
-            for s in TR.host_spans(tl, "prefill")]
+    return [rec.architecture.prefill_chunk(
+        rec.sizes, int(s.stats["start"]), int(s.stats["tokens"]))
+        for s in TR.host_spans(tl, "prefill")]
 
 
 def decode_roofline_pct(rec) -> Optional[float]:

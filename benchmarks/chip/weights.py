@@ -1,32 +1,52 @@
-"""Seeded random weights of a dense GQA decoder, made by the benchmark.
+"""Seeded random weights, made by the benchmark.
 
 The system under test is handed these weights; the reference makes the
 same ones again, one layer at a time, from the same seed.  Neither takes
 anything from the other.
+
+Which leaves a model has, their shapes and the layer each stacked slice
+is made for are the architecture's: its module under ``archs/`` gives
+them as a ``Tree``.  This module only makes the leaves.
 
 Every matrix entry is a uniform 8-bit integer times a power of two, so it
 is exactly representable in bfloat16 (the served type) and in float32 (the
 reference's type): the two start from identical values, and the bits come
 from integer counters, so a layer made alone equals the same layer made
 inside the stacked set.  The power of two is the one nearest to giving
-the entries the usual ``1/sqrt(fan_in)`` spread (0.02 for the embedding).
-Norm scales are ones.
-
-Names follow the parameter tree of a dense decoder: ``embed`` (V, D),
-``lm_head`` (D, V) when the head is not tied, ``final_norm/scale``, and per
-layer ``ln1/scale``, ``attn/{wq,wk,wv,wo}``, ``ln2/scale``,
-``ffn/{wi,wg,wo}`` (``wi`` the up projection, ``wg`` the gate).
+the entries the usual ``1/sqrt(fan_in)`` spread (0.02 for the embedding),
+the fan-in being the second-to-last axis.  Leaves of one axis (norm
+scales, biases) are ones.
 """
 from __future__ import annotations
 
 import math
 import zlib
+from dataclasses import dataclass
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 INT8_STD = math.sqrt((256 ** 2 - 1) / 12)      # std of uniform ints -128..127
+
+
+@dataclass(frozen=True)
+class Stack:
+    """Leaves stacked over layers.  ``layers`` holds, at each index of the
+    stacked axes, the layer that slice is made for; ``leaves`` maps each
+    leaf's name inside one layer to its shape in one layer."""
+    layers: Tuple
+    leaves: Dict[str, tuple]
+
+
+@dataclass(frozen=True)
+class Tree:
+    """A model's parameter tree: leaves outside any stack by their path,
+    and stacks by the path they sit under (a stacked leaf's path is the
+    stack's, a slash, then its name inside the layer)."""
+    globals: Dict[str, tuple]
+    stacks: Dict[str, Stack]
 
 
 def base_key(seed: int):
@@ -38,27 +58,9 @@ def base_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def global_shapes(sizes: dict) -> dict:
-    D, V = sizes["d_model"], sizes["vocab_size"]
-    shapes = {"embed": (V, D), "final_norm/scale": (D,)}
-    if not sizes["tie_embeddings"]:
-        shapes["lm_head"] = (D, V)
-    return shapes
-
-
-def layer_shapes(sizes: dict) -> dict:
-    D, F = sizes["d_model"], sizes["d_ff"]
-    H, KVH = sizes["num_heads"], sizes["num_kv_heads"]
-    hd = sizes.get("head_dim") or D // H
-    return {"ln1/scale": (D,), "attn/wq": (D, H * hd),
-            "attn/wk": (D, KVH * hd), "attn/wv": (D, KVH * hd),
-            "attn/wo": (H * hd, D), "ln2/scale": (D,),
-            "ffn/wi": (D, F), "ffn/wg": (D, F), "ffn/wo": (F, D)}
-
-
 def scale_exponent(name: str, shape: tuple) -> int:
     """k such that ints * 2**-k spread like the usual initialisation."""
-    target = 0.02 if name == "embed" else 1.0 / math.sqrt(shape[0])
+    target = 0.02 if name == "embed" else 1.0 / math.sqrt(shape[-2])
     return round(math.log2(INT8_STD / target))
 
 
@@ -67,12 +69,13 @@ def _leaf_key(key, name: str):
 
 
 def _matrix(key, name: str, shape: tuple, dtype):
-    rows, cols = shape
+    *rows, cols = shape
     words = -(-cols // 4)
-    bits = jax.random.bits(key, (rows, words), jnp.uint32)
-    ints = jax.lax.bitcast_convert_type(bits, jnp.int8).reshape(rows, 4 * words)
+    bits = jax.random.bits(key, (*rows, words), jnp.uint32)
+    ints = jax.lax.bitcast_convert_type(bits, jnp.int8).reshape(
+        *rows, 4 * words)
     scale = 2.0 ** -scale_exponent(name, shape)
-    return ints[:, :cols].astype(dtype) * jnp.asarray(scale, dtype)
+    return ints[..., :cols].astype(dtype) * jnp.asarray(scale, dtype)
 
 
 def make_leaf(key, name: str, shape: tuple, dtype, layer=None):
@@ -85,54 +88,67 @@ def make_leaf(key, name: str, shape: tuple, dtype, layer=None):
     return _matrix(k, name, shape, dtype)
 
 
-def make_layer(key, sizes: dict, layer, dtype) -> dict:
+def make_leaves(key, shapes: dict, dtype, layer=None) -> dict:
+    """Each leaf of ``shapes`` (name -> shape); ``layer`` as for
+    ``make_leaf``.  The reference makes one layer of a stack with it."""
     return {name: make_leaf(key, name, shape, dtype, layer)
-            for name, shape in layer_shapes(sizes).items()}
-
-
-def make_globals(key, sizes: dict, dtype) -> dict:
-    return {name: make_leaf(key, name, shape, dtype)
-            for name, shape in global_shapes(sizes).items()}
+            for name, shape in shapes.items()}
 
 
 def _path_name(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
-def make_params(template, sizes: dict, seed: int):
+def _place(tree: Tree, name: str):
+    """(stack path or None, name inside the stack, shape it must have)."""
+    if name in tree.globals:
+        return None, name, tuple(tree.globals[name])
+    for at, stack in tree.stacks.items():
+        inner = name[len(at) + 1:]
+        if name.startswith(at + "/") and inner in stack.leaves:
+            return at, inner, (np.shape(stack.layers)
+                               + tuple(stack.leaves[inner]))
+    return None, None, None
+
+
+def make_params(template, module, sizes: dict, seed: int):
     """The whole parameter tree, shaped like ``template`` (a tree of
     ``ShapeDtypeStruct``, as ``jax.eval_shape`` gives for the model's own
-    initialiser), built on the default device by one compiled call.  Every
-    leaf of the template has to be one this module knows by name and
-    shape: anything else means the model is not the one the configuration
-    describes."""
-    L = sizes["num_layers"]
-    gshapes, lshapes = global_shapes(sizes), layer_shapes(sizes)
+    initialiser), built on the default device by one compiled call.
+    ``module`` is the architecture's, and ``module.params(sizes)`` its
+    ``Tree``.  Every leaf of the template has to be one the tree names,
+    at the shape it gives: anything else means the model is not the one
+    the configuration describes."""
+    tree = module.params(sizes)
     flat, treedef = jax.tree_util.tree_flatten_with_path(template)
-    names = [_path_name(p) for p, _ in flat]
-    for name, (_, leaf) in zip(names, flat):
-        if name in gshapes:
-            want = gshapes[name]
-        elif name.startswith("layers/") and name[7:] in lshapes:
-            want = (L,) + lshapes[name[7:]]
-        else:
+    places = []
+    for path, leaf in flat:
+        name = _path_name(path)
+        at, inner, want = _place(tree, name)
+        if inner is None:
             raise ValueError(f"parameter {name!r} {leaf.shape} is not part of "
-                             "a dense GQA decoder")
+                             f"the architecture {module.__name__}")
         if tuple(leaf.shape) != want:
             raise ValueError(f"parameter {name!r} has shape {leaf.shape}, the "
                              f"configuration gives {want}")
-    dtypes = [leaf.dtype for _, leaf in flat]
+        places.append((at, inner, leaf.dtype))
 
     def build(key):
-        stacked = jax.lax.map(
-            lambda l: make_layer(key, sizes, l, dtypes[0]), jnp.arange(L))
-        glob = make_globals(key, sizes, dtypes[0])
-        leaves = []
-        for name, dt in zip(names, dtypes):
-            arr = stacked[name[7:]] if name.startswith("layers/") else glob[name]
-            leaves.append(arr.astype(dt))
+        made = {}
+        for at, stack in tree.stacks.items():
+            wanted = {inner: (tuple(stack.leaves[inner]), dt)
+                      for a, inner, dt in places if a == at}
+            if not wanted:
+                continue
+            layers = np.asarray(stack.layers, np.int32)
+            flat_made = jax.lax.map(
+                lambda l: {n: make_leaf(key, n, s, dt, l)
+                           for n, (s, dt) in wanted.items()},
+                jnp.asarray(layers.reshape(-1)))
+            made[at] = {n: a.reshape(layers.shape + a.shape[1:])
+                        for n, a in flat_made.items()}
+        leaves = [make_leaf(key, inner, tree.globals[inner], dt) if at is None
+                  else made[at][inner] for at, inner, dt in places]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    if len(set(np.dtype(d) for d in dtypes)) != 1:
-        raise ValueError(f"mixed parameter dtypes {set(dtypes)}")
     return jax.jit(build)(base_key(seed))

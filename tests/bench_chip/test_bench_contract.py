@@ -86,3 +86,22 @@ def test_rooflines_and_utilisations_are_shares():
     for m in BENCH["per_layer"]:
         if "roofline" in m["name"] or "mfu" in m["name"]:
             assert m["unit"] == "%" and m["source"] == "device_trace"
+
+
+def test_steady_tpot_reads_what_tpot_p50_ms_reads():
+    from types import SimpleNamespace
+
+    from benchmarks.chip import harness
+    rec = SimpleNamespace(measured=[
+        SimpleNamespace(ok=True, first=1.0, finish=1.0 + 0.021 * k * (n - 1),
+                        tokens=[0] * n)
+        for k, n in ((1, 40), (2, 7), (3, 300), (1.5, 1))])
+    want = harness.reader("tpot_p50_ms")(rec)
+    assert want == pytest.approx(42.0)
+    assert harness.reader("tpot_p50_ms.steady")(rec) == want
+    steady = next(m for m in BENCH["end_to_end"]
+                  if m["name"] == "tpot_p50_ms.steady")
+    shared = next(m for m in BENCH["end_to_end"]
+                  if m["name"] == "tpot_p50_ms")
+    assert set(_cells_of(steady)) < set(_cells_of(shared))
+    assert steady["bound"] < shared["bound"]

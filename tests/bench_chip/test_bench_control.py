@@ -4,10 +4,13 @@ a run served by it comes out not correct, while the float32 reference's
 own greedy tokens read no gap at all."""
 import numpy as np
 
-from bench_chip_tiny import TINY_ARCH, TINY_GAP_LIMIT, tiny_cell
+from bench_chip_tiny import tiny_cell
 from benchmarks.chip import harness, reference, traffic
 
-SIZES = dict(tiny_cell("granite-3-8b", "sharegpt-1.28rps").config["arch"])
+CELL = tiny_cell("granite-3-8b", "sharegpt-1.28rps")
+ARCH = CELL.architecture
+SIZES = dict(CELL.config["arch"])
+TINY_GAP_LIMIT = CELL.config["check"]["logit_gap_limit"]
 SEQ, ROWS, SEED = 128, 4, 2 ** 34 + 5
 
 
@@ -15,14 +18,14 @@ def greedy_samples(n_new=24):
     """Prompts from the traffic generator, continued greedily by the
     float32 reference itself."""
     rng = traffic.token_rng(SEED)
-    prompts = [[2 + int(t) % (TINY_ARCH["vocab_size"] - 2)
+    prompts = [[2 + int(t) % (SIZES["vocab_size"] - 2)
                 for t in rng.integers(0, 8192, n)] for n in (9, 30, 17)]
     served = [[] for _ in prompts]
     for _ in range(n_new):
         samples = [(p, s + [0]) for p, s in zip(prompts, served)]
         tokens, lengths, spans = reference.pack(samples, SEQ, ROWS)
         zero = np.zeros(tokens.shape + (1,), np.int32)
-        _, _, first = reference.summarize(SIZES, SEED, tokens, lengths, zero)
+        _, _, first = ARCH.summarize(SIZES, SEED, tokens, lengths, zero)
         for i, (a, b) in enumerate(spans):
             served[i].append(int(first[i, b - 1]))
     return list(zip(prompts, served))
@@ -30,8 +33,8 @@ def greedy_samples(n_new=24):
 
 def test_fp8_control_fails_the_check_and_float32_passes():
     samples = greedy_samples()
-    served, control = reference.control_gaps(SIZES, SEED, samples, SEQ,
-                                             ROWS, "fp8")
+    served, control = reference.control_gaps(ARCH.summarize, SIZES, SEED,
+                                             samples, SEQ, ROWS, "fp8")
     assert max(served) == 0.0
     assert max(control) > 2 * TINY_GAP_LIMIT
     conf = {"check": {"logit_gap_limit": TINY_GAP_LIMIT}}

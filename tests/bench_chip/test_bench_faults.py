@@ -66,8 +66,8 @@ def compiles_in_window(engine):
     (token_altered, "logit_gap"), (state_unchanged, "logit_gap"),
     (rows_misaligned, "logit_gap"), (compiles_in_window, "compiles_serving")])
 def test_fault_turns_correct_false(monkeypatch, fault, fails):
-    def broken(conf, seed, device):
-        gw, engine, params = BUILD(conf, seed, device)
+    def broken(cell, seed, device):
+        gw, engine, params = BUILD(cell, seed, device)
         fault(engine)
         return gw, engine, params
     monkeypatch.setattr(harness, "build", broken)

@@ -7,7 +7,7 @@ from pathlib import Path
 import pytest
 
 import bench_chip_tiny  # noqa: F401  (puts the repository on sys.path)
-from benchmarks.chip import flops, peaks, readers
+from benchmarks.chip import harness, peaks, readers
 from benchmarks.chip import trace_reduce as TR
 
 FIXTURE = Path(__file__).with_name("fixture_trace.xplane.pb")
@@ -69,18 +69,34 @@ def test_top_ops_name_program_role_and_op(tl):
     assert sum(v for _, v in top) <= TR.busy_s(tl) + 1e-9
 
 
-def test_decode_readers_over_the_fixture(tl):
+def fixture_record(tl):
     class Rec:
         sizes = dict(num_layers=1, d_model=64, num_heads=4, num_kv_heads=2,
                      head_dim=16, d_ff=128, vocab_size=256,
                      tie_embeddings=True)
         peak = peaks.peak_of("TPU v5 lite")
         timeline = tl
-    work = flops.decode_step(Rec.sizes, 4, 1000)
+        architecture = harness.architecture("dense_gqa")
+    return Rec
+
+
+def test_decode_readers_over_the_fixture(tl):
+    Rec = fixture_record(tl)
+    work = Rec.architecture.decode_step(Rec.sizes, 4, 1000)
     want = 100 * 6 * work.least_seconds(Rec.peak) / TR.module_seconds(
         tl, "decode")
     assert readers.decode_roofline_pct(Rec) == pytest.approx(want)
     assert readers.idle_pct(Rec) == pytest.approx(100 * TR.idle_share(tl))
+
+
+def test_device_readers_match_the_recorded_readings(tl):
+    # read before the work counts moved into archs/dense_gqa.py: the
+    # move changed no reading
+    Rec = fixture_record(tl)
+    assert harness.reader("step.decode_roofline")(Rec) == 2.226303969068327
+    assert harness.reader("step.decode_mfu_pct")(Rec) == 0.026873874441288435
+    assert [(w.flops, w.bytes) for w in readers.prefill_work(Rec)] == \
+        [(5283840, 115072)] * 3
 
 
 def test_union_and_gaps_by_hand():

@@ -1,11 +1,16 @@
-"""The benchmark's yardstick on the CPU: the operation and byte counts
-against hand-worked granite-3-8b numbers, the table of peaks, the seeded
-weights, the traffic generator and the latency readers."""
+"""The benchmark's yardstick on the CPU: the dense architecture's
+operation and byte counts against hand-worked granite-3-8b numbers, the
+table of peaks, the seeded weights, the traffic generator and the latency
+readers."""
+import zlib
+
 import numpy as np
 import pytest
 
 import bench_chip_tiny  # noqa: F401  (puts the repository on sys.path)
-from benchmarks.chip import flops, harness, peaks, traffic, weights
+from benchmarks.chip import harness, peaks, traffic, weights
+
+DENSE = harness.architecture("dense_gqa")
 
 # granite-3-8b as served on one chip: 24 of 40 layers, published widths
 GRANITE = dict(num_layers=24, d_model=4096, num_heads=32, num_kv_heads=8,
@@ -17,17 +22,17 @@ def test_granite_weights_and_kv_by_hand():
     # per layer: q 4096x4096, k and v 4096x1024, o 4096x4096, three FFN
     # matrices 4096x12800
     per_layer = 4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 12800
-    assert flops.layer_matmul_params(GRANITE) == per_layer == 199_229_440
+    assert DENSE.layer_matmul_params(GRANITE) == per_layer == 199_229_440
     # 24 layers with their two norms, the final norm, the tied embedding
-    assert flops.weight_bytes(GRANITE) == 2 * (
+    assert DENSE.weight_bytes(GRANITE) == 2 * (
         24 * (per_layer + 2 * 4096) + 4096 + 49155 * 4096) == 9_966_092_288
     # K and V, 24 layers, 8 heads of 128, bf16
-    assert flops.kv_bytes_per_token(GRANITE) == 98_304
+    assert DENSE.kv_bytes_per_token(GRANITE) == 98_304
 
 
 def test_granite_decode_step_by_hand():
     # 16 lanes whose contexts sum to 16 000 tokens
-    w = flops.decode_step(GRANITE, 16, 16_000)
+    w = DENSE.decode_step(GRANITE, 16, 16_000)
     matmul = 16 * 2 * (24 * 199_229_440 + 4096 * 49155)
     attn = 16_000 * 4 * 24 * 32 * 128
     assert w.flops == matmul + attn
@@ -41,7 +46,7 @@ def test_granite_decode_step_by_hand():
 def test_granite_prefill_chunk_by_hand():
     # the second 256-token chunk of a prompt: attends to 256 cached
     # tokens and causally to itself
-    w = flops.prefill_chunk(GRANITE, 256, 256)
+    w = DENSE.prefill_chunk(GRANITE, 256, 256)
     pairs = 256 * 256 + 256 * 257 // 2
     assert w.flops == (256 * 2 * 24 * 199_229_440 + 2 * 4096 * 49155
                        + pairs * 4 * 24 * 32 * 128)
@@ -49,8 +54,8 @@ def test_granite_prefill_chunk_by_hand():
 
 
 def test_prefill_of_one_token_matches_a_decode_lane():
-    one = flops.prefill_chunk(GRANITE, 99, 1)
-    step = flops.decode_step(GRANITE, 1, 100)
+    one = DENSE.prefill_chunk(GRANITE, 99, 1)
+    step = DENSE.decode_step(GRANITE, 1, 100)
     assert one.flops == step.flops and one.bytes == step.bytes
 
 
@@ -64,27 +69,33 @@ SMALL = dict(num_layers=3, d_model=64, num_heads=4, num_kv_heads=2,
              head_dim=16, d_ff=96, vocab_size=130, tie_embeddings=False)
 
 
-def test_weights_layer_alone_equals_stacked_and_bf16_is_exact():
+def small_params(seed=2 ** 40 + 3):
     import jax
     import jax.numpy as jnp
-    key = weights.base_key(2 ** 40 + 3)
     template = {"embed": jax.ShapeDtypeStruct((130, 64), jnp.bfloat16),
                 "lm_head": jax.ShapeDtypeStruct((64, 130), jnp.bfloat16),
                 "final_norm": {"scale": jax.ShapeDtypeStruct((64,),
                                                              jnp.bfloat16)},
                 "layers": {k.split("/")[0]: {} for k in
-                           weights.layer_shapes(SMALL)}}
-    for name, shape in weights.layer_shapes(SMALL).items():
+                           DENSE.layer_shapes(SMALL)}}
+    for name, shape in DENSE.layer_shapes(SMALL).items():
         a, b = name.split("/")
         template["layers"][a][b] = jax.ShapeDtypeStruct((3,) + shape,
                                                         jnp.bfloat16)
-    params = weights.make_params(template, SMALL, 2 ** 40 + 3)
-    alone = weights.make_layer(key, SMALL, 2, jnp.float32)
+    return weights.make_params(template, DENSE, SMALL, seed)
+
+
+def test_weights_layer_alone_equals_stacked_and_bf16_is_exact():
+    import jax.numpy as jnp
+    key = weights.base_key(2 ** 40 + 3)
+    params = small_params()
+    alone = weights.make_leaves(key, DENSE.layer_shapes(SMALL), jnp.float32,
+                                2)
     for name, arr in alone.items():
         a, b = name.split("/")
         served = np.asarray(params["layers"][a][b][2].astype(jnp.float32))
         np.testing.assert_array_equal(served, np.asarray(arr))
-    glob = weights.make_globals(key, SMALL, jnp.float32)
+    glob = weights.make_leaves(key, DENSE.global_shapes(SMALL), jnp.float32)
     np.testing.assert_array_equal(
         np.asarray(params["lm_head"].astype(jnp.float32)),
         np.asarray(glob["lm_head"]))
@@ -101,7 +112,59 @@ def test_weights_refuse_an_unknown_parameter():
     tpl = {"embed": jax.ShapeDtypeStruct((130, 64), jnp.bfloat16),
            "mystery": jax.ShapeDtypeStruct((4, 4), jnp.bfloat16)}
     with pytest.raises(ValueError, match="mystery"):
-        weights.make_params(tpl, dict(SMALL, tie_embeddings=True), 1)
+        weights.make_params(tpl, DENSE, dict(SMALL, tie_embeddings=True), 1)
+
+
+# crc32 of each leaf's bfloat16 bytes, recorded before the dense decoder's
+# parameter tree moved into archs/dense_gqa.py: the weights' bits did not
+# change with the move
+SMALL_CRC32 = {"embed": 2511938635, "lm_head": 109725983,
+               "layers/attn/wq": 3727025050, "layers/attn/wk": 1661665088,
+               "layers/ffn/wo": 1160807935, "final_norm/scale": 1028775320}
+
+
+def test_weights_match_the_recorded_bits():
+    import jax
+    params = small_params()
+    got = {"/".join(str(k.key) for k in path):
+           zlib.crc32(np.asarray(leaf).tobytes())
+           for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert {k: got[k] for k in SMALL_CRC32} == SMALL_CRC32
+
+
+def test_weights_several_stacks_each_slice_its_own_layer():
+    """Two stacks, as a model with two kinds of layer has: the slice at
+    each index is the layer the stack names there, made alone equal to
+    made stacked, a three-axis leaf included, mixed dtypes kept."""
+    import jax
+    import jax.numpy as jnp
+    tree = weights.Tree(
+        {"embed": (130, 64)},
+        {"mix": weights.Stack(((0, 1), (3, 4)), {"in": (64, 32),
+                                                 "norm": (64,)}),
+         "moe": weights.Stack((2, 5), {"experts": (4, 64, 16),
+                                       "decay": (8,)})})
+    arch = type("Arch", (), {"params": staticmethod(lambda sizes: tree)})
+    sd = jax.ShapeDtypeStruct
+    template = {"embed": sd((130, 64), jnp.bfloat16),
+                "mix": {"in": sd((2, 2, 64, 32), jnp.bfloat16),
+                        "norm": sd((2, 2, 64), jnp.bfloat16)},
+                "moe": {"experts": sd((2, 4, 64, 16), jnp.bfloat16),
+                        "decay": sd((2, 8), jnp.float32)}}
+    params = weights.make_params(template, arch, {}, 2 ** 36 + 1)
+    key = weights.base_key(2 ** 36 + 1)
+    for at, idx, layer in (("mix", (1, 0), 3), ("mix", (0, 1), 1),
+                           ("moe", (1,), 5)):
+        alone = weights.make_leaves(key, tree.stacks[at].leaves,
+                                    jnp.float32, layer)
+        for name, arr in alone.items():
+            stacked = params[at][name][idx]
+            assert stacked.dtype == template[at][name].dtype
+            np.testing.assert_array_equal(
+                np.asarray(stacked.astype(jnp.float32)), np.asarray(arr))
+    e = np.asarray(params["moe"]["experts"][0].astype(jnp.float32))
+    assert not np.array_equal(e[0], e[1])             # experts differ
+    assert 0.5 / 8 < e.std() < 2 / 8                  # fan-in 64
 
 
 MIX = {"rate": 5.0, "ramp_s": 2.0, "drain_cap_s": 10.0,
