@@ -34,6 +34,13 @@ of the finished requests.  ``--trace 1`` also records a profiler trace of
 the middle of the window, with the program's layer entry points annotated
 from here, and reports the per-layer metrics instead of the end-to-end
 ones.
+
+What a reader finds beside the client's view: the program's own
+``alise.*`` spans with their stats (``rec.timeline.program``, traced runs
+only; ``trace_reduce.program_spans`` and ``within``) and the engine's
+counters and gauges (``rec.gauges``: ``engine.gauges()`` once warm-up is
+done and again once serving has stopped, so that a cumulative counter
+reads as end - start over ramp, window and drain).
 """
 from __future__ import annotations
 
@@ -171,6 +178,7 @@ class Record:
     timeline: object = None         # trace_reduce.Timeline (traced runs)
     clock_offset: float = 0.0       # perf_counter - gateway clock
     architecture: object = None     # the cell's architecture module
+    gauges: dict = field(default_factory=dict)  # start, end -> gauges()
 
     @property
     def measured(self) -> List[Req]:
@@ -490,6 +498,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     setup = Compiles()
     gw, engine, params = build(cell, seed, dev)
+    gauges = {"start": dict(engine.gauges())}
     if trace:
         annotate(gw, engine)
     setup_s = time.perf_counter() - t_start
@@ -500,6 +509,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     recs, window, tracer, n_compiles, off = asyncio.run(
         serve(gw, engine, cell, seconds, seed, trace, keep_trace))
+    gauges["end"] = dict(engine.gauges())
     ws, we = window
     iters = [(t - off, c, b, dt) for t, c, b, dt in engine.iter_times
              if ws <= t - off < we]
@@ -545,7 +555,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     record = Record(conf["arch"], conf["slo"], seconds, window, recs,
                     iters, pool_tokens, setup_s, peak, timeline, off,
-                    cell.architecture)
+                    cell.architecture, gauges)
     if record_hook is not None:
         record_hook(record)
     wanted = cell.per_layer if trace else cell.end_to_end

@@ -8,8 +8,12 @@ holds one event per op execution (busy time is their union; a control-flow
 op such as a layer loop's ``while`` contains the ops of its body) and its
 ``XLA Modules`` line one event per program execution.  Host spans are the
 ``bench:*`` annotations on the ``/host:CPU`` plane, each with the stats it
-was annotated with.  All events share the trace's clock (nanoseconds).
-The traced window is the span of the ``bench:window`` annotation.
+was annotated with.  The program's own spans (``alise.*``, written by
+``serving/observability/span.py``) are kept apart, in ``program``, each
+with its stats and its host thread: ``program_spans`` and ``within`` read
+them, and nothing that gives a program its role or an idle gap its span
+sees them.  All events share the trace's clock (nanoseconds).  The
+traced window is the span of the ``bench:window`` annotation.
 
 Programs are told apart by what the host was doing when it dispatched
 them, not by name: a jitted ``functools.partial`` runs as
@@ -34,6 +38,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 PREFIX = "bench:"
+PROGRAM = "alise."              # the program's own spans
 WINDOW = PREFIX + "window"
 NO_SPAN = "no host span"
 _MODULE_ID = re.compile(r"\(\d+\)$")
@@ -45,6 +50,7 @@ class Event:
     start: float                # seconds on the trace's clock
     dur: float
     stats: Dict[str, object] = field(default_factory=dict)
+    thread: int = 0             # the host line (thread) it was traced on
 
     @property
     def end(self) -> float:
@@ -59,6 +65,7 @@ class Timeline:
     modules: Dict[str, List[Event]]     # chip -> program executions
     host: List[Event]                   # bench:* spans, prefix dropped
     enqueued: Dict[str, float] = field(default_factory=dict)  # run_id -> t
+    program: List[Event] = field(default_factory=list)  # alise.*, ditto
 
     def __post_init__(self):
         spans = sorted((e for e in self.host if e.name != WINDOW[len(PREFIX):]),
@@ -70,6 +77,11 @@ class Timeline:
             reach = max(reach, e.end)
             self._reach.append(reach)
         self._roles: Optional[Dict[str, str]] = None
+        self._named: Dict[str, List[Event]] = defaultdict(list)
+        for e in sorted(self.program, key=lambda e: e.start):
+            self._named[e.name].append(e)
+        self._named_starts = {name: [e.start for e in evs]
+                              for name, evs in self._named.items()}
 
     @property
     def window_s(self) -> float:
@@ -153,6 +165,27 @@ def host_spans(tl: Timeline, name: str) -> List[Event]:
             if e.name == name and tl.window[0] <= e.end <= tl.window[1]]
 
 
+def program_spans(tl: Timeline, name: str) -> List[Event]:
+    """The program's spans called ``name`` (``alise.`` dropped) that ended
+    inside the window."""
+    return [e for e in tl._named.get(name, ())
+            if tl.window[0] <= e.end <= tl.window[1]]
+
+
+def within(tl: Timeline, outer: Event, name: str) -> List[Event]:
+    """The program's spans called ``name`` that lie inside the span
+    ``outer``, on its thread."""
+    evs = tl._named.get(name, [])
+    i = bisect.bisect_left(tl._named_starts.get(name, []), outer.start)
+    out = []
+    for e in evs[i:]:
+        if e.start > outer.end:
+            break
+        if e.end <= outer.end and e.thread == outer.thread:
+            out.append(e)
+    return out
+
+
 def op_name(hlo: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...)`` -> ``fusion.12``."""
     return hlo.split(" = ", 1)[0].lstrip("%")
@@ -219,13 +252,13 @@ def idle_by_host_span(tl: Timeline, n: int = 10) -> List[list]:
 
 
 # ------------------------------------------------------------------ loading
-def _events(line) -> List[Event]:
+def _events(line, thread: int = 0) -> List[Event]:
     out = []
     with warnings.catch_warnings():     # the stats type lacks __module__
         warnings.simplefilter("ignore", DeprecationWarning)
         for e in line.events:
             out.append(Event(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9,
-                             {k: v for k, v in e.stats}))
+                             {k: v for k, v in e.stats}, thread))
     return out
 
 
@@ -236,6 +269,7 @@ def load(path: str) -> Timeline:
     ops: Dict[str, List[Event]] = {}
     modules: Dict[str, List[Event]] = {}
     host: List[Event] = []
+    program: List[Event] = []
     enqueued: Dict[str, float] = {}
     dispatched: Dict[str, float] = {}       # flow id -> dispatch time
     for plane in data.planes:
@@ -246,12 +280,15 @@ def load(path: str) -> Timeline:
                 elif line.name == "XLA Modules":
                     modules[plane.name] = _events(line)
         elif plane.name == "/host:CPU":
-            for line in plane.lines:
+            for thread, line in enumerate(plane.lines):
                 issue: List[Event] = []
-                for e in _events(line):
+                for e in _events(line, thread):
                     if e.name.startswith(PREFIX):
                         e.name = e.name[len(PREFIX):]
                         host.append(e)
+                    elif e.name.startswith(PROGRAM):
+                        e.name = e.name[len(PROGRAM):]
+                        program.append(e)
                     elif e.name == "tpu::System::Execute" and "_p" in e.stats:
                         dispatched[str(e.stats["_p"])] = e.start
                     elif e.name.endswith("=>IssueSequencedEvent"):
@@ -267,4 +304,5 @@ def load(path: str) -> Timeline:
         raise ValueError(f"{path}: no {WINDOW!r} span; the traced window "
                          "is unknown")
     w = max(windows, key=lambda e: e.dur)
-    return Timeline((w.start, w.end), ops, modules, host, enqueued)
+    return Timeline((w.start, w.end), ops, modules, host, enqueued,
+                    program)
