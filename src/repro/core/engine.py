@@ -406,6 +406,7 @@ class ServingEngine:
             g[f"mlfq_q{i}_depth"] = float(d)
         for w, n in self.kv.decode_width_steps.items():
             g[f"decode_width_{w}_steps"] = float(n)
+        g["decode_h2d_transfers"] = float(self.kv.decode_h2d_transfers)
         pool = getattr(self.kv, "pool", None)
         if pool is not None:
             g["pool_free_pages"] = float(len(pool.free_pages))

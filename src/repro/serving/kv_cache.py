@@ -24,8 +24,10 @@ fp tensors and quantizing in host numpy.
 Every program is jitted from a named function (:func:`named_program`), so
 the profiler's trace names its executions ``jit_alise_<what>``; a decode
 dispatch runs in the host spans ``alise.decode.stage`` (page tables and
-lane arrays to the device), ``alise.decode.dispatch`` and
-``alise.decode.sync``.
+lane arrays packed into one int32 buffer, sent to the device in one
+transfer: :func:`lane_buffer`), ``alise.decode.dispatch`` and
+``alise.decode.sync``; the program unpacks the buffer
+(:func:`decode_program`).
 
 The paged decode step gathers only as many pages per lane as its page table
 is wide, and that width follows the live context: the smallest rung of
@@ -65,8 +67,55 @@ def named_program(name: str, fn, **static):
     return program
 
 
-def _to_device(*arrays) -> list:
-    return [jnp.asarray(a) for a in arrays]
+# A decode dispatch's host inputs travel as ONE (B, W) int32 buffer: one
+# host-to-device copy a step instead of one per array.  Its layout gives,
+# per column group in argument order, the array's shape past the lane axis
+# (() or (n,)) and whether it is bool (stored as 0/1).
+LaneLayout = Tuple[Tuple[Tuple[int, ...], bool], ...]
+
+
+def lane_buffer(B: int, *cols) -> Tuple[np.ndarray, LaneLayout, list]:
+    """One decode step's host inputs packed column by column, in order,
+    into one ``(B, W)`` int32 buffer.  A column is a host array, ``(B,)``
+    or ``(B, n)``, int32 or bool, copied in; or a shape tail, ``()`` or
+    ``(n,)``, for int32 columns the caller fills in place.  Returns the
+    buffer, its layout and a writable view of each column."""
+    layout = tuple((c, False) if isinstance(c, tuple)
+                   else (np.shape(c)[1:], np.asarray(c).dtype == bool)
+                   for c in cols)
+    buf = np.zeros((B, sum(t[0] if t else 1 for t, _ in layout)), np.int32)
+    views, at = [], 0
+    for (tail, _), c in zip(layout, cols):
+        w = tail[0] if tail else 1
+        view = buf[:, at:at + w] if tail else buf[:, at]
+        if not isinstance(c, tuple):
+            view[...] = c
+        views.append(view)
+        at += w
+    return buf, layout, views
+
+
+def unpack_lanes(packed, layout: LaneLayout) -> list:
+    """Inside a program: the arrays :func:`lane_buffer` packed, split back
+    by static column slices, bool columns as ``!= 0``."""
+    out, at = [], 0
+    for tail, is_bool in layout:
+        w = tail[0] if tail else 1
+        col = packed[:, at:at + w] if tail else packed[:, at]
+        out.append(col != 0 if is_bool else col)
+        at += w
+    return out
+
+
+def decode_program(name: str, fn, **static):
+    """``fn(params, kv, *lane arrays, base_key, **static)`` jitted as the
+    program ``name`` that takes ``(params, kv, packed, base_key, layout)``:
+    it unpacks the lane arrays from ``packed`` (``layout`` is static, so a
+    program compiles once per buffer shape) and donates ``kv``."""
+    def unpacked(params, kv, packed, base_key, layout, **kw):
+        return fn(params, kv, *unpack_lanes(packed, layout), base_key, **kw)
+    return jax.jit(named_program(name, unpacked, **static),
+                   static_argnums=(4,), donate_argnums=(1,))
 
 
 def decode_width_ladder(max_seq_len: int, page_size: int) -> Tuple[int, ...]:
@@ -368,6 +417,8 @@ class KVBackend:
         # decode steps served per page-table width, keyed by the positions
         # gathered per lane (the paged backend's ladder; empty on dense)
         self.decode_width_steps: Dict[int, int] = {}
+        # host-to-device copies made staging decode steps: one a step
+        self.decode_h2d_transfers = 0
 
     # --------------------------------------------------------------- lanes
     def slot_of(self, rid: int) -> Optional[int]:
@@ -411,11 +462,19 @@ class KVBackend:
                 f"{menu[-1]}; the scheduler must clamp chunk spans")
         return self._pow2_bucket(n)
 
-    def _dispatch_sync(self, program, params, kv, args):
+    def _stage(self, buf: np.ndarray):
+        """A :func:`lane_buffer` on the device, in one copy.  Uncommitted,
+        as every array this backend stages: the engine runs its backend
+        under its device as the default (``ServingEngine._on_device``),
+        and a committed input would key the programs apart from warm-up."""
+        self.decode_h2d_transfers += 1
+        return jax.device_put(buf)
+
+    def _dispatch_sync(self, program, params, kv, packed, layout):
         """One decode dispatch and the wait for its sampled outputs: returns
         (the outputs before the KV, as numpy arrays; the new KV)."""
         with span("decode.dispatch"):
-            *out, kv = program(params, kv, *args, self._base_key)
+            *out, kv = program(params, kv, packed, self._base_key, layout)
         with span("decode.sync"):
             out = jax.device_get(out)
         return [np.asarray(o) for o in out], kv
@@ -562,15 +621,14 @@ class DenseKVBackend(KVBackend):
         self._axes = self._cache_batch_axes()
         # the cache pytree (arg 1) is consumed and fully re-emitted by the
         # fused step: donate it so it updates in place
-        self._fused = jax.jit(named_program(
+        self._fused = decode_program(
             "alise_decode", model.decode_step_sampled,
-            **self._sample_kwargs()), donate_argnums=(1,))
+            **self._sample_kwargs())
         self._fused_verify = None
         if cfg.spec_k > 0 and model.supports_spec_decode():
-            self._fused_verify = jax.jit(named_program(
+            self._fused_verify = decode_program(
                 "alise_decode_verify", model.decode_verify_sampled,
-                interpret=self.interpret, **self._sample_kwargs()),
-                donate_argnums=(1,))
+                interpret=self.interpret, **self._sample_kwargs())
         self._decode = jax.jit(named_program("alise_decode_logits",
                                              model.decode_step),
                                donate_argnums=(1,))
@@ -863,10 +921,11 @@ class DenseKVBackend(KVBackend):
     def decode(self, params, tokens, active, new_gen, new_ctx, true_len,
                rids):
         with span("decode.stage"):
-            args = _to_device(tokens, active, new_gen, new_ctx, true_len,
-                              rids)
+            buf, layout, _ = lane_buffer(len(tokens), tokens, active,
+                                         new_gen, new_ctx, true_len, rids)
+            packed = self._stage(buf)
         (tok, reason), self.cache = self._dispatch_sync(
-            self._fused, params, self.cache, args)
+            self._fused, params, self.cache, packed, layout)
         return tok, reason
 
     def supports_spec_decode(self) -> bool:
@@ -879,10 +938,12 @@ class DenseKVBackend(KVBackend):
         # overwritten by the next dispatch before they could matter —
         # rollback costs nothing on the dense stripes
         with span("decode.stage"):
-            args = _to_device(tokens, n_drafts, active, base_gen, base_ctx,
-                              true_len, rids)
+            buf, layout, _ = lane_buffer(len(tokens), tokens, n_drafts,
+                                         active, base_gen, base_ctx,
+                                         true_len, rids)
+            packed = self._stage(buf)
         (s, n_emit, reason), self.cache = self._dispatch_sync(
-            self._fused_verify, params, self.cache, args)
+            self._fused_verify, params, self.cache, packed, layout)
         return s, n_emit, reason
 
     def decode_logits(self, params, tokens, active):
@@ -941,16 +1002,15 @@ class PagedKVBackend(KVBackend):
             if spec_on else [])
         # kv (arg 1) is the whole page pool, consumed and re-emitted: donate
         # so pages are written in place
-        self._fused = jax.jit(named_program(
+        self._fused = decode_program(
             "alise_decode", model.paged_decode_step_sampled,
             attn_impl=cfg.attn_impl, interpret=self.interpret,
-            **self._sample_kwargs()), donate_argnums=(1,))
+            **self._sample_kwargs())
         self._fused_verify = None
         if spec_on:
-            self._fused_verify = jax.jit(named_program(
+            self._fused_verify = decode_program(
                 "alise_decode_verify", model.paged_decode_verify_sampled,
-                interpret=self.interpret, **self._sample_kwargs()),
-                donate_argnums=(1,))
+                interpret=self.interpret, **self._sample_kwargs())
         # chunked prefill always attends via the logical-order page gather
         # (bit-exact vs the dense stripe path); attn_impl only selects the
         # decode-step kernel
@@ -1251,10 +1311,13 @@ class PagedKVBackend(KVBackend):
             if pages is None:
                 pages = self._decode_pages([rid for _, rid in lanes])
                 self.decode_width_steps[pages * pg] += 1
-            tables = np.full((B, pages), self.scratch_page, np.int32)
-            lens = np.zeros((B,), np.int32)
-            wp = np.full((B,), self.scratch_page, np.int32)
-            wo = np.zeros((B,), np.int32)
+            # the page table and write targets are built in their columns
+            # of the packed buffer: inactive lanes read and write scratch
+            buf, layout, (_, tables, lens, wp, wo, *_) = lane_buffer(
+                B, tokens, (pages,), (), (), (), active, new_gen, new_ctx,
+                true_len, rids)
+            tables[...] = self.scratch_page
+            wp[...] = self.scratch_page
             for slot, rid in lanes:
                 # the fed token's KV lands at logical position `pos`: grow
                 # the page table first (caller guarantees a free page via
@@ -1266,10 +1329,10 @@ class PagedKVBackend(KVBackend):
                 lens[slot] = pos
                 wp[slot] = pt[pos // pg]
                 wo[slot] = pos % pg
-            args = _to_device(tokens, tables, lens, wp, wo, active, new_gen,
-                              new_ctx, true_len, rids)
+            packed = self._stage(buf)
         (tok, reason), kv = self._dispatch_sync(
-            self._fused, params, {"k": self.pool.k, "v": self.pool.v}, args)
+            self._fused, params, {"k": self.pool.k, "v": self.pool.v},
+            packed, layout)
         self.pool.k, self.pool.v = kv["k"], kv["v"]
         return tok, reason
 
@@ -1285,11 +1348,12 @@ class PagedKVBackend(KVBackend):
             # one extra table column holds the lane's scratch page right
             # after its real pages: a scratch-resident write at logical
             # position p (p // pg == len(table)) gathers back at exactly p
-            tables = np.full((B, maxp + 1), self.scratch_page, np.int32)
-            lens = np.zeros((B,), np.int32)
-            wp = np.full((B, K1), self.scratch_page, np.int32)
-            wo = np.broadcast_to(np.arange(K1, dtype=np.int32) % pg,
-                                 (B, K1)).copy()
+            buf, layout, (_, tables, lens, wp, wo, *_) = lane_buffer(
+                B, tokens, (maxp + 1,), (), (K1,), (K1,), n_drafts, active,
+                base_gen, base_ctx, true_len, rids)
+            tables[...] = self.scratch_page
+            wp[...] = self.scratch_page
+            wo[...] = np.arange(K1, dtype=np.int32) % pg
             for slot, rid in enumerate(self.slot_req):
                 if rid is None or not active[slot]:
                     continue
@@ -1305,11 +1369,10 @@ class PagedKVBackend(KVBackend):
                     wp[slot, i] = (pt[p // pg] if p // pg < len(pt)
                                    else self.lane_scratch[slot])
                     wo[slot, i] = p % pg
-            args = _to_device(tokens, tables, lens, wp, wo, n_drafts, active,
-                              base_gen, base_ctx, true_len, rids)
+            packed = self._stage(buf)
         (s, n_emit, reason), kv = self._dispatch_sync(
             self._fused_verify, params, {"k": self.pool.k, "v": self.pool.v},
-            args)
+            packed, layout)
         self.pool.k, self.pool.v = kv["k"], kv["v"]
         # commit after the sync: lanes whose accepted span crossed into
         # scratch promote it into the page table (pointer move, no copy)

@@ -23,9 +23,9 @@ Spans of the serving path (nesting as shown):
   context tokens they held before the step, and the positions per lane its
   attention gathers) and ``alise.predictor.feedback``;
 - under ``alise.engine.decode``: ``alise.decode.stage`` (page tables, lane
-  arrays, host to device), ``alise.decode.dispatch``, ``alise.decode.sync``
-  (the wait for the sampled tokens) and ``alise.decode.accept`` (token
-  acceptance and stream events);
+  arrays, one packed host-to-device copy), ``alise.decode.dispatch``,
+  ``alise.decode.sync`` (the wait for the sampled tokens) and
+  ``alise.decode.accept`` (token acceptance and stream events);
 - ``alise.gc`` (``generation``): one garbage collection, from
   ``gc.callbacks`` (installed by :func:`trace_gc`), while a profiler records.
 """

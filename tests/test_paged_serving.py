@@ -8,7 +8,8 @@ plus the serving-level invariant: a ServingEngine on the paged backend
 produces greedy outputs bit-identical to the dense slotted backend, with and
 without forced preemption/swapping — and the decode page-table width that
 follows the live context: its ladder, the step at a trimmed width against
-the full one, and warm-up compiling every rung.
+the full one, and warm-up compiling every rung — and the decode step's
+inputs staged as one packed buffer in one host-to-device copy.
 """
 import jax
 import jax.numpy as jnp
@@ -23,9 +24,11 @@ from repro.core.request import Request, reset_request_counter
 from repro.kernels.paged_attention import (paged_attention_ref,
                                            paged_decode_attention)
 from repro.models.model import Model
+from repro.serving import kv_cache
 from repro.serving.kv_cache import (KVBackendConfig, PagedKVBackend,
                                     PagedKVConfig, PagedKVPool,
-                                    decode_table_width, decode_width_ladder)
+                                    decode_table_width, decode_width_ladder,
+                                    lane_buffer, unpack_lanes)
 from repro.serving.observability import EventBus, render_prometheus
 from repro.utils.compile_counter import CompileCounter
 
@@ -460,3 +463,104 @@ def test_warmup_compiles_every_decode_width(model_and_params):
     text = render_prometheus(bus)
     assert f'alise_decode_width_64_steps{{replica="e0"}} {float(steps[64])}' \
         in text
+
+
+# ---------------------------------------- decode inputs in one packed copy
+
+_IMAX = np.iinfo(np.int32).max
+
+
+def _lane_columns(case: str, rng, B: int = 5):
+    """The arrays one decode step stages, in its argument order: the fed
+    token(s), an optional page table (filled in place through its view),
+    then the per-lane arrays, with a bool ``active`` and ``true_len`` at
+    the int32 maximum on most lanes."""
+    def ints(*tail):
+        return rng.integers(-(1 << 30), 1 << 30, (B,) + tail).astype(
+            np.int32)
+    active = rng.random(B) < 0.5
+    active[:2] = True, False
+    true_len = np.full((B,), _IMAX, np.int32)
+    true_len[1] = 7
+    lanes = [active, ints(), ints(), true_len, ints()]
+    if case == "lanes":
+        return [ints(1)] + lanes, {}
+    if case == "token_block":
+        return [ints(4), ints()] + lanes, {}
+    rung = {"table_rung_2": 2, "table_rung_16": 16}[case]
+    return [ints(1), ints(rung), ints(), ints(), ints()] + lanes, {1: rung}
+
+
+@pytest.mark.parametrize("case", ["lanes", "token_block", "table_rung_2",
+                                  "table_rung_16"])
+def test_lane_buffer_round_trip(case):
+    """Packing a step's host arrays into one int32 buffer and unpacking it
+    inside a program returns each array exactly, in its own columns: bool
+    columns as bool, ``true_len`` at the int32 maximum unchanged, and a page
+    table built in place through its view."""
+    cols, filled = _lane_columns(case, np.random.default_rng(11))
+    B = len(cols[0])
+    buf, layout, views = lane_buffer(
+        B, *((c.shape[1:] if i in filled else c) for i, c in enumerate(cols)))
+    for i in filled:
+        views[i][...] = cols[i]
+    assert buf.dtype == np.int32
+    assert [t for t, _ in layout] == [c.shape[1:] for c in cols]
+    assert [b for _, b in layout] == [c.dtype == bool for c in cols]
+    np.testing.assert_array_equal(buf, np.concatenate(
+        [c.reshape(B, -1).astype(np.int32) for c in cols], axis=1))
+    out = jax.jit(unpack_lanes, static_argnums=(1,))(jnp.asarray(buf),
+                                                     layout)
+    assert len(out) == len(cols)
+    for got, want in zip(out, cols):
+        assert got.shape == want.shape
+        assert got.dtype == (jnp.bool_ if want.dtype == bool else jnp.int32)
+        np.testing.assert_array_equal(np.asarray(got), want)
+    assert int(out[-2][0]) == _IMAX          # true_len, lane 0
+
+
+@pytest.mark.parametrize("backend,prompt,rung", [
+    ("dense", 3, None), ("dense", 41, None),
+    ("paged", 3, 8), ("paged", 41, 48)])
+def test_decode_step_stages_in_one_copy(model_and_params, monkeypatch,
+                                        backend, prompt, rung):
+    """Every decode step of an engine makes exactly one host-to-device copy
+    while staging (counted at ``jax.device_put`` and ``jnp.asarray``), on
+    either backend and at two page-table rungs, and after N steps the
+    ``decode_h2d_transfers`` gauge has grown by N."""
+    cfg, model, params = model_and_params
+    reqs = _mk_requests(cfg, (6,), (prompt,))
+    kw = dict(kv_backend="paged", page_size=8) if backend == "paged" else {}
+    eng = ServingEngine(model, params, EngineConfig(
+        max_slots=2, max_seq_len=64, max_new_tokens=8, strategy="vllm",
+        quantize_offload=False, warmup_compile=True, **kw),
+        predictor=OraclePredictor())
+    start = eng.gauges()["decode_h2d_transfers"]
+    copies, staging = [], [False]
+
+    def counting(real):
+        def call(*a, **k):
+            copies.append(staging[0])
+            return real(*a, **k)
+        return call
+    monkeypatch.setattr(kv_cache.jax, "device_put",
+                        counting(jax.device_put))
+    monkeypatch.setattr(kv_cache.jnp, "asarray", counting(jnp.asarray))
+    decode, per_step = eng.kv.decode, []
+
+    def counted_decode(*a, **k):
+        before = sum(copies)
+        staging[0] = True
+        try:
+            return decode(*a, **k)
+        finally:
+            staging[0] = False
+            per_step.append(sum(copies) - before)
+    monkeypatch.setattr(eng.kv, "decode", counted_decode)
+    eng.serve(reqs)
+    assert len(reqs[0].output_tokens) == 6
+    assert per_step and per_step == [1] * len(eng.iter_times)
+    assert eng.gauges()["decode_h2d_transfers"] - start == len(per_step)
+    if rung is not None:
+        assert {w for w, n in eng.kv.decode_width_steps.items() if n} \
+            == {rung}
