@@ -3,11 +3,10 @@
 Also covers Fig. 2 (FCFS vs ALISE on ShareGPT) as the orca-vs-alise columns.
 ``derived`` = normalized latency in ms/token at each (system, dataset, rate).
 
-Plus the real-engine decode-dispatch comparison (``e2e/engine_decode/*``):
-decode tokens/s of the legacy per-slot path (one ``int(jnp.argmax(...))``
-host sync per slot per iteration) vs the fused in-JIT step (sampling +
-termination on device, one sync per iteration) on the dense and paged KV
-backends at ``max_slots >= 8``.
+Plus the real-engine decode rows (``e2e/engine_decode/*``): decode
+tokens/s of the fused in-JIT step (sampling + termination on device, one
+sync per iteration) on the dense and paged KV backends at
+``max_slots >= 8``.
 """
 from __future__ import annotations
 
@@ -32,7 +31,7 @@ def _run_traced_decode(model, params, cfg, max_slots, out_len, n_reqs,
 
     eng = ServingEngine(model, params, EngineConfig(
         max_slots=max_slots, max_seq_len=64, max_new_tokens=out_len,
-        strategy="alise", quantize_offload=False, fused_decode=True),
+        strategy="alise", quantize_offload=False),
         predictor=OraclePredictor())
     eng.attach_bus(EventBus(clock="wall"), "engine0")
     eng.serve(mk_reqs(max_slots, 4))             # warm the jit caches
@@ -56,7 +55,7 @@ def _run_traced_decode(model, params, cfg, max_slots, out_len, n_reqs,
 
 
 def run_engine_decode(arch: str = "granite-3-8b") -> dict:
-    """Fused in-JIT decode vs per-slot dispatch, decode tokens/s."""
+    """Fused in-JIT decode on both KV backends, decode tokens/s."""
     import jax
     import numpy as np
 
@@ -83,10 +82,8 @@ def run_engine_decode(arch: str = "granite-3-8b") -> dict:
                 for _ in range(n)]
 
     modes = {
-        "per_slot": dict(fused_decode=False),
-        "fused_dense": dict(fused_decode=True),
-        "fused_paged": dict(fused_decode=True, kv_backend="paged",
-                            page_size=16),
+        "fused_dense": dict(),
+        "fused_paged": dict(kv_backend="paged", page_size=16),
     }
     results = {}
     fused_tokens = None
@@ -100,8 +97,8 @@ def run_engine_decode(arch: str = "granite-3-8b") -> dict:
         eng.iter_times.clear()
         reqs = mk_reqs(n_reqs, out_len)
         t0 = time.perf_counter()
-        eng.serve(reqs)                          # wall covers the full loop,
-        wall = time.perf_counter() - t0          # incl. per-slot host syncs
+        eng.serve(reqs)                          # wall covers the full loop
+        wall = time.perf_counter() - t0
         toks = sum(r.generated for r in reqs)
         tok_s = toks / max(wall, 1e-9)
         results[name] = tok_s
@@ -111,8 +108,6 @@ def run_engine_decode(arch: str = "granite-3-8b") -> dict:
         emit(f"e2e/engine_decode/{name}", wall / max(len(eng.iter_times), 1)
              * 1e6, f"tok_per_s={tok_s:.1f};slots={max_slots};"
              f"iters={len(eng.iter_times)}")
-    sp = results["fused_dense"] / max(results["per_slot"], 1e-9)
-    emit("e2e/engine_decode/fused_speedup", 0.0, f"{sp:.2f}x")
 
     # --- speculative verify-k on the same workload: bit-identical greedy
     # outputs on both backends, decode tok/s vs the non-speculative fused
@@ -130,8 +125,8 @@ def run_engine_decode(arch: str = "granite-3-8b") -> dict:
     f32_model = Model(f32_cfg, attn_chunk=32, remat=False)
     f32_params = f32_model.init(jax.random.PRNGKey(0))
     spec_modes = {
-        "dense": dict(fused_decode=True),
-        "paged": dict(fused_decode=True, kv_backend="paged", page_size=16),
+        "dense": dict(),
+        "paged": dict(kv_backend="paged", page_size=16),
     }
     for name, kw in spec_modes.items():
         runs = {}
@@ -172,9 +167,8 @@ def run_engine_decode(arch: str = "granite-3-8b") -> dict:
                                     n_reqs, mk_reqs, fused_tokens,
                                     results["fused_dense"])
     results["trace_overhead"] = emit_ratio
-    note(f"[engine_decode] slots={max_slots}: per-slot "
-         f"{results['per_slot']:.1f} tok/s -> fused dense "
-         f"{results['fused_dense']:.1f} tok/s ({sp:.2f}x), fused paged "
+    note(f"[engine_decode] slots={max_slots}: fused dense "
+         f"{results['fused_dense']:.1f} tok/s, fused paged "
          f"{results['fused_paged']:.1f} tok/s")
     return results
 

@@ -85,6 +85,7 @@ def run() -> None:
 
     from repro.configs import get_smoke_config
     from repro.models.model import Model
+    from repro.serving.kv_cache import dense_decode
 
     mcfg = get_smoke_config("granite-3-8b")
     model = Model(mcfg, attn_chunk=32, remat=False)
@@ -97,8 +98,8 @@ def run() -> None:
     active = jnp.ones((Bs,), bool)
     step_key = jax.random.PRNGKey(1)
     rids = jnp.arange(Bs, dtype=jnp.int32)
-    fused = jax.jit(lambda p, c, t: model.decode_step_sampled(
-        p, c, t, active, ones, ones, ones * Smax, rids, step_key,
+    fused = jax.jit(lambda p, c, t: dense_decode(
+        model, p, c, t, active, ones, ones, ones * Smax, rids, step_key,
         max_seq_len=Smax))
     plain = jax.jit(model.decode_step)
 

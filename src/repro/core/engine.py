@@ -7,10 +7,10 @@ token-budgeted list of typed work items — over a pluggable
 :class:`~repro.serving.kv_cache.KVBackend`:
 
   * **chunked, resumable prefill**: each :class:`PrefillChunk` item runs
-    ``prefill_chunk``-sized pieces of a prompt through
-    ``Model.prefill_chunk`` (dense) / ``Model.paged_prefill_chunk`` (paged,
-    KV written device-side through the page pool, mid-page chunk boundaries
-    included), resuming from the partially-filled cache — so one long
+    ``prefill_chunk``-sized pieces of a prompt through the one paged forward
+    ``Model.forward_paged`` (KV written device-side into the page pool,
+    mid-page chunk boundaries included, or into the dense slot stripe as a
+    one-page table), resuming from the partially-filled cache — so one long
     prompt no longer stalls every resident decode lane for a whole-prompt
     dispatch.  Families without chunk support (SSM / hybrid / enc-dec)
     fall back to the monolithic ``Model.prefill`` path;
@@ -21,9 +21,8 @@ token-budgeted list of typed work items — over a pluggable
     embedding, layer stack, KV writes, attention, sampling (greedy or
     temperature/top-k) and EOS/length termination all run on device — the
     host syncs a single ``(tokens, reasons)`` pair instead of one
-    ``int(jnp.argmax(...))`` per slot (``fused_decode=False`` keeps the
-    legacy per-slot dispatch for comparison); prefill first tokens and the
-    legacy path sample through the same ``sampler.sample_and_reason``;
+    ``int(jnp.argmax(...))`` per slot; prefill first tokens sample on the
+    host through the same ``sampler.sample_and_reason``;
   * request-level KV swapping between the device cache ("HBM") and a host
     pool ("DRAM"), quantized INT8 *on device* via the Pallas kv_quant
     kernels per the paper's Eq. 8 — the host link carries the INT8 payload;
@@ -120,8 +119,6 @@ class EngineConfig:
     page_size: int = 16                    # paged backend page granularity
     paged_attn_impl: str = "gather"        # gather (bit-exact vs dense) |
                                            # kernel (Pallas paged attention)
-    fused_decode: bool = True              # one in-jit dispatch per iter
-                                           # (False: legacy per-slot sampling)
     prefill_chunk: Optional[int] = None    # max prompt tokens per prefill
                                            # chunk (None = monolithic);
                                            # ignored for families without
@@ -158,8 +155,8 @@ class EngineConfig:
                                            # in one fused dispatch; greedy
                                            # AND temperature outputs stay
                                            # bit-identical spec on vs off
-                                           # (needs fused_decode + a
-                                           # chunk-capable model family)
+                                           # (needs a chunk-capable model
+                                           # family)
     spec_k: int = 3                        # draft tokens per lane (paged
                                            # backend: must be < page_size)
     profile_window: int = 4096             # iter/prefill ring-buffer size
@@ -199,7 +196,7 @@ class ServingEngine:
         # so enabling the cache doesn't silently shrink the slot cache).
         dense_store_bytes = 0.0
         if (cfg.prefix_cache and cfg.kv_backend == "dense"
-                and model.supports_chunked_prefill()):
+                and model.supports_paged()):
             capacity = cfg.prefix_cache_pages or (
                 cfg.max_slots * cfg.max_seq_len // cfg.page_size)
             dense_store_bytes = capacity * cfg.page_size * bpt
@@ -216,7 +213,7 @@ class ServingEngine:
         self.latency = latency or LatencyModel(t0=1e-4, alpha=1e-6, beta=1e-2)
         # chunked prefill needs backend support (attention-family
         # decoder-only); other families keep monolithic whole-prompt spans
-        self._chunked_ok = model.supports_chunked_prefill()
+        self._chunked_ok = model.supports_paged()
         # fixed chunk-shape menu: explicit flag wins; packing without a
         # menu derives the pow2 menu (packs group by bucket, so every
         # packable chunk needs one)
@@ -242,8 +239,7 @@ class ServingEngine:
             prefill_buckets=buckets, prefill_pack=self._pack_ok,
             prefill_pack_width=cfg.prefill_pack_width,
             decode_width=(cfg.spec_k + 1
-                          if (cfg.spec_decode and cfg.fused_decode
-                              and model.supports_spec_decode())
+                          if cfg.spec_decode and model.supports_paged()
                           else 1))
         self.sched = Scheduler(sched_cfg, self.predictor, self.latency, self.mem)
 
@@ -258,13 +254,9 @@ class ServingEngine:
             prefix_cache_pages=cfg.prefix_cache_pages, seed=cfg.seed,
             prefill_buckets=buckets,
             prefill_pack_width=cfg.prefill_pack_width,
-            spec_k=(cfg.spec_k
-                    if cfg.spec_decode and cfg.fused_decode else 0))
+            spec_k=cfg.spec_k if cfg.spec_decode else 0)
         with self._on_device():
             if cfg.kv_backend == "paged":
-                if not cfg.fused_decode:
-                    raise ValueError("the paged backend only implements the "
-                                     "fused in-JIT decode step")
                 num_pages = max(1, int(hbm // (cfg.page_size * bpt)))
                 self.kv = PagedKVBackend(model, bcfg, num_pages, device)
             elif cfg.kv_backend == "dense":
@@ -308,8 +300,8 @@ class ServingEngine:
         self._generated_of: Dict[int, List[int]] = {}
         # host-side sampling uses the same per-(rid, token-index) key
         # derivation as the fused device dispatch, so every code path —
-        # prefill first token, legacy per-slot decode, fused decode,
-        # verify-k — draws the identical key stream for a given token
+        # prefill first token, fused decode, verify-k — draws the
+        # identical key stream for a given token
         self._sample_base_key = jax.random.PRNGKey(cfg.seed)
         # streaming events: recorded only when a front-end opts in (the
         # gateway sets this), so plain step() drivers that never poll don't
@@ -453,7 +445,7 @@ class ServingEngine:
     def _sample_host(self, logits_row, rid: int, new_gen: int, new_ctx: int,
                      true_len: int):
         """One-row host-side sampling + termination for prefill first
-        tokens and the legacy per-slot decode path — the same
+        tokens — the same
         ``sample_and_reason`` chain the fused decode step runs on device,
         with the same per-(request, token-index) key derivation, so every
         code path draws the identical stream for a given token.  Returns
@@ -686,7 +678,7 @@ class ServingEngine:
         per bucket (when packing is on; a single member already compiles
         the full ``(width, bucket)`` shape — dummy rows pad the rest), the
         fused decode step at every page-table width the paged backend
-        dispatches (or the legacy per-slot step), and the host-side
+        dispatches, and the host-side
         first-token sampling chain.  Each bucket runs twice — the first
         rep compiles, the second measures — and the measured seconds land
         in ``self.latency.bucket_costs`` so EWT prices a bucketed chunk at
@@ -757,13 +749,9 @@ class ServingEngine:
         active = np.zeros((B,), bool)
         zeros = np.zeros((B,), np.int32)
         tl = np.full((B,), np.iinfo(np.int32).max, np.int32)
-        if self.cfg.fused_decode:
-            # every page-table width of the paged ladder
-            self.kv.warm_decode(self.params, tokens, active, zeros, zeros,
-                                tl, zeros)
-        else:
-            jax.block_until_ready(
-                self.kv.decode_logits(self.params, tokens, active))
+        # every page-table width of the paged ladder
+        self.kv.warm_decode(self.params, tokens, active, zeros, zeros, tl,
+                            zeros)
         if self._spec_ok:
             # warm the verify-k shape (the serve path's only other decode
             # dispatch: one fixed (B, spec_k+1) shape, variable draft
@@ -1238,13 +1226,11 @@ class ServingEngine:
                 s, n_emit, reasons = self.kv.decode_verify(
                     self.params, tokens, n_drafts, active, base_gen,
                     base_ctx, true_len, rids)
-            elif self.cfg.fused_decode:
+            else:
                 # one dispatch: decode + sample + terminate on device
                 toks, reasons = self.kv.decode(
                     self.params, tokens, active, base_gen + 1,
                     base_ctx + 1, true_len, rids)
-            else:
-                logits = self.kv.decode_logits(self.params, tokens, active)
             self.iter_times.append((sp.t0, ctx_tokens, len(runnable),
                                     time.perf_counter() - sp.t0))
             if self.bus is not None:
@@ -1277,16 +1263,10 @@ class ServingEngine:
                                 r, int(s[slot, i]), generated_of, t,
                                 reason=(REASONS[int(reasons[slot])]
                                         if last else ""))
-                    elif self.cfg.fused_decode:
+                    else:
                         self._accept_token(
                             r, int(toks[slot]), generated_of, t,
                             reason=REASONS[int(reasons[slot])])
-                    else:
-                        tok, reason = self._sample_host(
-                            logits[slot], r.req_id, r.generated + 1,
-                            r.context_len + 1, self._true_len_of(r))
-                        self._accept_token(r, tok, generated_of, t,
-                                           reason=reason)
 
     def step_and_poll(self, t: float) -> Tuple[bool, List[EngineEvent]]:
         """One iteration plus its events, as a single executor-friendly call
@@ -1300,8 +1280,7 @@ class ServingEngine:
                       reason: str = ""):
         """Record a sampled token.  ``reason`` carries the termination
         verdict from ``sample_and_reason`` — computed on device by the
-        fused step, host-side (same function) for prefill first tokens and
-        the legacy per-slot path."""
+        fused step, host-side (same function) for prefill first tokens."""
         req.generated += 1
         # the fed/just-sampled token's predecessors are all materialized:
         # context minus the one token whose KV the next decode step writes

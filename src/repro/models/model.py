@@ -1,5 +1,9 @@
 """Model assembly: decoder-only / MoE / SSM / hybrid / encoder-decoder stacks
 with scan-over-layers, remat, and train / prefill / decode entry points.
+Serving an attention-family decoder-only stack runs one forward,
+:meth:`Model.forward_paged`, at every width (decode, verify-k, a prefill
+chunk, a pack of chunks) over a KV page pool; a dense stripe cache is a
+pool of one page per slot (:func:`stripe_view`).
 
 The serving steps name their parts with ``jax.named_scope`` (``attention``:
 projections, KV writes and reads, attention and output projection; ``ffn``;
@@ -123,6 +127,20 @@ def _apply_sublayer_decode(cfg: ArchConfig, p, x, lengths, kind: str,
     x = x + out
     x, _ = _apply_ffn_part(cfg, p, x, ffn_kind, moe_groups)
     return x, new_cache
+
+
+def stripe_view(slots, start, width: int):
+    """A dense ``(L, B, Smax, KVH, hd)`` cache as the page pool of
+    :meth:`Model.forward_paged`: B pages of Smax, slot ``b`` is page ``b``
+    and position ``p`` of its stripe offset ``p``.  For rows on ``slots``
+    (N,) whose queries start at ``start`` (N,), returns the page tables
+    (N, 1) and the write targets ``(write_page, write_off)`` (N, width).
+    An out-of-range slot (a pack's dummy row) reads a clamped stripe and
+    its writes are dropped, as are writes past Smax."""
+    slots = jnp.asarray(slots, jnp.int32)
+    start = jnp.asarray(start, jnp.int32)
+    write_page = jnp.broadcast_to(slots[:, None], (slots.shape[0], width))
+    return slots[:, None], write_page, start[:, None] + jnp.arange(width)
 
 
 # ------------------------------------------------------------------- hybrid
@@ -398,9 +416,8 @@ class Model:
                                          .repeat(x.shape[-1], -1), axis=1)[:, 0]
         else:
             x_last = x[:, -1, :]
-        logits = self._logits(params, x_last)
         cache = self._pack_cache(caches, enc_ctx, batch_size=B, cur_len=S)
-        return logits.astype(jnp.float32), cache
+        return self.logits(params, x_last), cache
 
     def _pack_cache(self, caches, enc_ctx, batch_size: int, cur_len: int):
         kvd = dtype_of(self.kv_dtype)
@@ -426,10 +443,20 @@ class Model:
         """One decode iteration.  tokens: (B,1) int32.  Returns (logits, cache).
 
         ``cache["lengths"]`` (B,) counts valid tokens; new KV is written at
-        index lengths (caches must be allocated with Smax > lengths).
+        index lengths (caches must be allocated with Smax > lengths).  An
+        attention-family decoder-only stack runs :meth:`forward_paged` over
+        its ``(L, B, Smax, ...)`` cache seen as B pages of Smax
+        (:func:`stripe_view`).
         """
         cfg = self.cfg
         lengths = cache["lengths"]
+        if self.supports_paged():
+            slots = jnp.arange(tokens.shape[0])
+            h, kv = self.forward_paged(
+                params, {"k": cache["k"], "v": cache["v"]}, tokens,
+                *stripe_view(slots, lengths, 1), lengths)
+            return (self.logits(params, h[:, -1]),
+                    {**cache, **kv, "lengths": lengths + 1})
         x = self._embed_in(params, tokens)
         x = shard_hint(x, "batch", None, None)
 
@@ -446,8 +473,8 @@ class Model:
                          "lengths": lengths + 1}
         elif cfg.family == "hybrid":
             x, new_cache = self._decode_hybrid(params, cache, x, lengths)
-        elif cfg.is_encoder_decoder:
-            kind, ffn_kind = "attn", cfg.ffn_kind(0)
+        else:                                       # encoder-decoder
+            ffn_kind = cfg.ffn_kind(0)
 
             def body(h, inp):
                 p_l, p_x, k_c, v_c, xk, xv = inp
@@ -461,172 +488,54 @@ class Model:
                                            cache["k"], cache["v"],
                                            cache["xk"], cache["xv"]))
             new_cache = {**cache, "k": k, "v": v, "lengths": lengths + 1}
-        else:
-            kind, ffn_kind = cfg.layer_kind(0), cfg.ffn_kind(0)
-
-            def body(h, inp):
-                p_l, k_c, v_c = inp
-                h, nc = _apply_sublayer_decode(cfg, p_l, h, lengths, kind,
-                                               ffn_kind, {"k": k_c, "v": v_c})
-                return h, (nc["k"], nc["v"])
-            x, (k, v) = lax.scan(body, x, (params["layers"],
-                                           cache["k"], cache["v"]))
-            new_cache = {**cache, "k": k, "v": v, "lengths": lengths + 1}
 
         x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._logits(params, x[:, -1, :])
-        return logits.astype(jnp.float32), new_cache
+        return self.logits(params, x[:, -1, :]), new_cache
 
-    def decode_step_sampled(self, params, cache, tokens, active, new_gen,
-                            new_ctx, true_len, rids, base_key, *,
-                            greedy_sampling=True,
-                            temp: float = 1.0, top_k: int = 0,
-                            eos_token: int = 1, max_new_tokens: int = 128,
-                            max_seq_len: int = 256):
-        """Fused decode iteration: decode + sample + terminate, one dispatch.
+    def logits(self, params, h):
+        """The lm head, in float32, over final-normed hidden states."""
+        return self._logits(params, h).astype(jnp.float32)
 
-        Wraps :meth:`decode_step` and moves sampling (greedy or
-        temperature/top-k) and EOS/length termination *inside* the jitted
-        step, so the engine syncs one small ``(tokens, reasons)`` pair per
-        iteration instead of one ``int(jnp.argmax(...))`` per slot.
+    def chunk_logits(self, params, h, chunk_len):
+        """Logits (N, V) at each row's last real column ``chunk_len - 1``
+        of hidden states ``h`` (N, C, D): the next-token logits of a
+        prompt whose final chunk this is."""
+        last = jnp.clip(chunk_len - 1, 0, h.shape[1] - 1)
+        return self.logits(params, jnp.take_along_axis(
+            h, last[:, None, None], axis=1)[:, 0])
 
-        ``rids`` (B,) int32 + ``base_key`` derive each lane's sampling key
-        via :func:`sampler.token_keys` — the token being sampled has
-        generation index ``new_gen - 1``, so the stream per (request,
-        index) is batch-composition- and speculation-independent.
-
-        ``active`` (B,) bool masks slots with no live request: their cache
-        ``lengths`` do not advance and their reason is forced to 0.
-        Returns ``(sampled (B,) int32, reason (B,) int32, new_cache)``.
-        """
-        from repro.serving.sampler import sample_and_reason, token_keys
-        logits, cache = self.decode_step(params, cache, tokens)
-        lengths = cache["lengths"]
-        cache = {**cache, "lengths": jnp.where(active, lengths, lengths - 1)}
-        keys = None if greedy_sampling else token_keys(
-            base_key, rids, new_gen - 1)
-        tok, reason = sample_and_reason(
-            logits, keys, greedy_sampling=greedy_sampling, temp=temp,
-            top_k=top_k, eos_token=eos_token, max_new_tokens=max_new_tokens,
-            max_seq_len=max_seq_len, new_gen=new_gen, new_ctx=new_ctx,
-            true_len=true_len)
-        reason = jnp.where(active, reason, 0)
-        return tok, reason, cache
-
-    # -------------------------------------------------- speculative decode
-    def supports_spec_decode(self) -> bool:
-        """Verify-k decode shares chunked prefill's requirements: an
-        attention-family decoder-only stack, where scoring k+1 positions
-        against cached KV is exactly a (tiny) prefill chunk."""
-        return self.supports_chunked_prefill()
-
-    def decode_verify(self, params, cache, tokens, *,
-                      interpret: bool = False):
-        """Score K1 = k+1 decode positions per lane in one dispatch.
-
-        ``tokens``: (B, K1) int32 — column 0 is the lane's previous sampled
-        token, columns 1..k its draft tokens.  KV for ``tokens[:, i]`` is
-        written at index ``lengths + i``; attention is the same masked
-        chunk attention as resumable prefill (causal over ``q_pos =
-        lengths + i``), so position i sees exactly the context the
-        sequential path would have.  Returns ``(logits (B, K1, V) f32,
-        new_cache)`` with ``lengths`` unchanged — the caller commits
-        accepted positions by advancing ``lengths``; rejected positions'
-        KV stays past the watermark where nothing ever attends to it (and
-        the next dispatch overwrites it).
-        """
-        cfg = self.cfg
-        if not self.supports_spec_decode():
-            raise ValueError(f"verify-k decode unsupported for family="
-                             f"{cfg.family} enc_dec={cfg.is_encoder_decoder}")
-        lengths = cache["lengths"]
-        B, K1 = tokens.shape
-        Smax = cache["k"].shape[2]
-        x = self._embed_in(params, tokens)
-        x = shard_hint(x, "batch", None, None)
-        q_pos = lengths[:, None] + jnp.arange(K1)[None, :]    # (B, K1)
-        kv_pos = jnp.broadcast_to(jnp.arange(Smax)[None, :], (B, Smax))
-        rows = jnp.arange(B)[:, None]
-        ffn_kind = cfg.ffn_kind(0)
-
-        def body(h, inp):
-            p_l, k_l, v_l = inp                   # (B, Smax, KVH, hd)
-            h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            with jax.named_scope("attention"):
-                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-                k_l = k_l.at[rows, q_pos].set(k.astype(k_l.dtype))
-                v_l = v_l.at[rows, q_pos].set(v.astype(v_l.dtype))
-                attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, lengths,
-                                        interpret)
-                h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
-            h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, (k_l, v_l)
-
-        x, (k_new, v_new) = lax.scan(body, x, (params["layers"],
-                                               cache["k"], cache["v"]))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._logits(params, x)                      # (B, K1, V)
-        return logits.astype(jnp.float32), {**cache, "k": k_new, "v": v_new}
-
-    def decode_verify_sampled(self, params, cache, tokens, n_drafts, active,
-                              base_gen, base_ctx, true_len, rids, base_key,
-                              *, interpret: bool = False,
-                              greedy_sampling=True, temp: float = 1.0,
-                              top_k: int = 0, eos_token: int = 1,
-                              max_new_tokens: int = 128,
-                              max_seq_len: int = 256):
-        """Fused verify-k iteration on the dense backend: score k+1
-        positions, sample each with its own per-token key, accept the
-        longest exact-match draft prefix, and resolve termination — one
-        dispatch, one host sync.
-
-        ``base_gen``/``base_ctx``: (B,) generated count / context length
-        *before* this dispatch.  Accepted lanes advance ``lengths`` by
-        ``n_emit`` (the last emitted token's KV stays unwritten — the next
-        dispatch feeds it, same invariant as plain decode); inactive or
-        fully-rejected garbage stays past the watermark.  Returns
-        ``(samples (B, K1), n_emit (B,), reason (B,), new_cache)``.
-        """
-        from repro.serving.sampler import token_keys, verify_and_reason
-        logits, cache = self.decode_verify(params, cache, tokens,
-                                           interpret=interpret)
-        B, K1 = tokens.shape
-        keys = None
-        if not greedy_sampling:
-            rr = jnp.repeat(jnp.asarray(rids, jnp.int32), K1)
-            ii = (jnp.asarray(base_gen, jnp.int32)[:, None]
-                  + jnp.arange(K1, dtype=jnp.int32)[None, :]).reshape(-1)
-            keys = token_keys(base_key, rr, ii).reshape(B, K1, -1)
-        s, n_emit, reason = verify_and_reason(
-            logits, tokens, jnp.asarray(n_drafts, jnp.int32), keys, active,
-            greedy_sampling=greedy_sampling, temp=temp, top_k=top_k,
-            eos_token=eos_token, max_new_tokens=max_new_tokens,
-            max_seq_len=max_seq_len, base_gen=base_gen, base_ctx=base_ctx,
-            true_len=true_len)
-        cache = {**cache, "lengths": cache["lengths"] + n_emit}
-        return s, n_emit, reason, cache
-
-    # ------------------------------------------------------ chunked prefill
-    def supports_chunked_prefill(self) -> bool:
-        """Chunked (resumable) prefill covers attention-family decoder-only
-        stacks: an attention chunk resumes from cached prefix KV exactly,
-        while SSM/hybrid recurrent state would need a cross-chunk state
-        handoff and enc-dec a static cross cache — both fall back to
-        monolithic prefill."""
+    # ------------------------------------- the paged attention-family forward
+    def supports_paged(self) -> bool:
+        """:meth:`forward_paged` (paged KV, chunked prefill, verify-k)
+        covers attention-family decoder-only stacks: SSM/hybrid state is
+        constant-size and would need a cross-chunk state handoff, and
+        enc-dec carries a static cross cache — those families decode
+        through :meth:`decode_step` and prefill monolithically."""
         cfg = self.cfg
         return (cfg.family not in ("ssm", "hybrid")
                 and not cfg.is_encoder_decoder)
 
+    def supports_prefill_pack(self) -> bool:
+        """Packed prefill batches several requests' chunks through one
+        dispatch.  Attention/norm/dense-FFN treat batch rows independently,
+        so packing preserves per-segment outputs bit-for-bit — but MoE
+        expert capacity is a function of the *total* token count
+        (``capacity_factor * T * K / E``), so co-batched segments would
+        change each other's drop behavior.  Packing therefore covers
+        dense-FFN attention stacks only.
+        """
+        return self.supports_paged() and self.cfg.ffn_kind(0) != "moe"
+
     def _chunk_attn(self, q, k_all, v_all, q_pos, kv_pos, start,
                     interpret: bool):
-        """Attention for one (possibly packed) prefill chunk.
+        """Attention of C > 1 queries per row over the row's gathered KV.
 
-        ``q``: (B, C, H, hd); ``k_all``/``v_all``: (B, Smax, KVH, hd);
-        ``q_pos``/``kv_pos``: (B, C)/(B, Smax); ``start``: (B,) int32.
-        ``masked`` materializes the C x Smax score matrix with the causal
+        ``q``: (N, C, H, hd); ``k_all``/``v_all``: (N, S, KVH, hd);
+        ``q_pos``/``kv_pos``: (N, C)/(N, S); ``start``: (N,) int32.
+        ``masked`` materializes the C x S score matrix with the causal
         position mask (the bit-identity reference); ``flash`` routes
         through the Pallas ``flash_prefill_prefix`` kernel, which reads
-        the stripe blockwise with online softmax — same math, no dense
+        the keys blockwise with online softmax — same math, no dense
         score matrix, so it survives long contexts.  ``interpret`` runs
         the kernel in Pallas interpret mode (the caller decides it from
         its device's platform).
@@ -636,67 +545,13 @@ class Model:
             from repro.kernels.flash_prefill.flash_prefill import (
                 flash_prefill_prefix)
             out = flash_prefill_prefix(
-                q.transpose(0, 2, 1, 3),            # (B, H, C, hd)
+                q.transpose(0, 2, 1, 3),            # (N, H, C, hd)
                 k_all.astype(q.dtype).transpose(0, 2, 1, 3),
                 v_all.astype(q.dtype).transpose(0, 2, 1, 3),
                 start, interpret=interpret)
-            return out.transpose(0, 2, 1, 3)        # (B, C, H, hd)
+            return out.transpose(0, 2, 1, 3)        # (N, C, H, hd)
         return L.full_attention(cfg, q, k_all, v_all, causal=True,
                                 q_positions=q_pos, kv_positions=kv_pos)
-
-    def prefill_chunk(self, params, k_stripe, v_stripe, tokens, start,
-                      chunk_len, *, interpret: bool = False):
-        """One resumable prefill chunk over a dense per-request KV stripe.
-
-        ``k_stripe``/``v_stripe``: (L, Smax, KVH, hd) — the request's slot
-        stripes with tokens ``[0, start)`` already materialized by earlier
-        chunks; ``tokens``: (1, C) int32, right-padded past ``chunk_len``;
-        ``start``/``chunk_len`` are dynamic scalars.  Writes the chunk's KV
-        at absolute positions ``[start, start+C)`` (out-of-range padded
-        rows are dropped by JAX's scatter OOB semantics) and attends each
-        chunk query at absolute position ``start+i`` over stripe keys
-        ``j <= start+i`` — unwritten stripe positions are masked, so stale
-        lane contents never leak into the output.
-
-        Returns ``(last_logits (1, V) f32, new_k, new_v)`` where
-        ``last_logits`` is taken at local index ``chunk_len - 1`` (the
-        prompt's next-token logits when this is the final chunk).
-        """
-        cfg = self.cfg
-        if not self.supports_chunked_prefill():
-            raise ValueError(f"chunked prefill unsupported for family="
-                             f"{cfg.family} enc_dec={cfg.is_encoder_decoder}")
-        C = tokens.shape[1]
-        Smax = k_stripe.shape[1]
-        x = self._embed_in(params, tokens)                    # (1, C, D)
-        x = shard_hint(x, "batch", None, None)
-        q_pos = (start + jnp.arange(C))[None, :]              # (1, C)
-        kv_pos = jnp.arange(Smax)[None, :]                    # (1, Smax)
-        write_idx = start + jnp.arange(C)                     # (C,)
-        start_vec = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
-        ffn_kind = cfg.ffn_kind(0)
-
-        def body(h, inp):
-            p_l, k_l, v_l = inp                               # (Smax, KVH, hd)
-            h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            with jax.named_scope("attention"):
-                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-                k_l = k_l.at[write_idx].set(k[0].astype(k_l.dtype))
-                v_l = v_l.at[write_idx].set(v[0].astype(v_l.dtype))
-                attn = self._chunk_attn(q, k_l[None], v_l[None], q_pos,
-                                        kv_pos, start_vec, interpret)
-                h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
-            h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, (k_l, v_l)
-
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["layers"], k_stripe, v_stripe))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        last = jnp.clip(chunk_len - 1, 0, C - 1)
-        x_last = jax.lax.dynamic_index_in_dim(x, last, axis=1,
-                                              keepdims=False)
-        logits = self._logits(params, x_last)
-        return logits.astype(jnp.float32), k_new, v_new
 
     def _scan_paged(self, params, kv, x, layer_fn):
         """Layer scan over a paged pool carried in the scan *carry*:
@@ -715,303 +570,43 @@ class Model:
             (params["layers"], jnp.arange(self.cfg.num_layers)))
         return x, {"k": k_new, "v": v_new}
 
-    def paged_prefill_chunk(self, params, kv, tokens, block_tables,
-                            write_page, write_off, start, chunk_len, *,
-                            interpret: bool = False):
-        """Paged twin of :meth:`prefill_chunk`: the chunk's KV lands
-        directly in the page pool (device-side, mid-page chunk boundaries
-        included) and attention gathers the request's pages in logical
-        order — the same masked ops as the dense stripe path, so greedy
-        outputs stay bit-identical across backends.
+    def forward_paged(self, params, kv, tokens, block_tables, write_page,
+                      write_off, start, *, attn_impl: str = "gather",
+                      interpret: bool = False):
+        """The attention-family forward at every serving width: decode
+        (C = 1), verify-k (C = k+1), one prefill chunk (N = 1) and a pack
+        of N chunks, over a KV page pool.
 
-        ``kv``: {"k","v"} (L, num_pages, page, KVH, hd); ``block_tables``:
-        (1, max_pages) int32 with unused entries pointing at the scratch
-        page; ``write_page``/``write_off``: (C,) physical destination of
-        each chunk token (scratch for padded rows).
-        """
-        cfg = self.cfg
-        if not self.supports_chunked_prefill():
-            raise ValueError(f"chunked prefill unsupported for family="
-                             f"{cfg.family} enc_dec={cfg.is_encoder_decoder}")
-        C = tokens.shape[1]
-        page = kv["k"].shape[2]
-        n_pages = block_tables.shape[1]
-        Smax = n_pages * page
-        x = self._embed_in(params, tokens)
-        x = shard_hint(x, "batch", None, None)
-        q_pos = (start + jnp.arange(C))[None, :]
-        kv_pos = jnp.arange(Smax)[None, :]
-        start_vec = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
-        ffn_kind = cfg.ffn_kind(0)
+        ``kv``: {"k","v"} (L, pages, page, KVH, hd); ``tokens``: (N, C)
+        int32; ``block_tables``: (N, W) int32, row n's pages in logical
+        order (unused entries at a scratch page); ``write_page``/
+        ``write_off``: (N, C) physical destination of each token's KV
+        (pad columns at a scratch page; out-of-range targets are dropped
+        by JAX's scatter); ``start``: (N,) int32.  Row n's queries sit at
+        positions ``start[n] + arange(C)`` and see keys up to their own
+        position, so unwritten and stale positions never reach an output.
+        A dense stripe cache is a pool of pages Smax long
+        (:func:`stripe_view`).
 
-        def body(h, p_l, k_all, v_all, i):
-            h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            with jax.named_scope("attention"):
-                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-                k_all = k_all.at[i, write_page, write_off].set(
-                    k[0].astype(k_all.dtype))
-                v_all = v_all.at[i, write_page, write_off].set(
-                    v[0].astype(v_all.dtype))
-                kg = k_all[i, block_tables[0]].reshape(1, Smax,
-                                                       *k_all.shape[3:])
-                vg = v_all[i, block_tables[0]].reshape(1, Smax,
-                                                       *v_all.shape[3:])
-                attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start_vec,
-                                        interpret)
-                h = h + attn.reshape(1, C, -1) @ p_l["attn"]["wo"]
-            h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, k_all, v_all
+        Attention follows the static width: at C = 1 the gathered pages go
+        through :func:`layers.decode_attention` (with ``attn_impl=
+        "kernel"`` the Pallas paged-attention kernel reads the pool by the
+        page table instead); at C > 1 through :meth:`_chunk_attn`.
 
-        x, kv = self._scan_paged(params, kv, x, body)
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        last = jnp.clip(chunk_len - 1, 0, C - 1)
-        x_last = jax.lax.dynamic_index_in_dim(x, last, axis=1,
-                                              keepdims=False)
-        logits = self._logits(params, x_last)
-        return logits.astype(jnp.float32), kv
-
-    # ------------------------------------------------------- packed prefill
-    def supports_prefill_pack(self) -> bool:
-        """Packed prefill batches several requests' chunks through one
-        dispatch.  Attention/norm/dense-FFN treat batch rows independently,
-        so packing preserves per-segment outputs bit-for-bit — but MoE
-        expert capacity is a function of the *total* token count
-        (``capacity_factor * T * K / E``), so co-batched segments would
-        change each other's drop behavior.  Packing therefore covers
-        dense-FFN attention stacks only.
-        """
-        return self.supports_chunked_prefill() and self.cfg.ffn_kind(0) != "moe"
-
-    def prefill_pack(self, params, k_stripes, v_stripes, tokens, start,
-                     chunk_len, *, interpret: bool = False):
-        """N prefill chunks from distinct requests in one dispatch.
-
-        Batched twin of :meth:`prefill_chunk` — segment ``i`` occupies
-        batch row ``i``, so its computation is the same masked attention
-        over its own (Smax) stripe as the unpacked path and greedy outputs
-        stay bit-identical packed-vs-unpacked.
-
-        ``k_stripes``/``v_stripes``: (L, N, Smax, KVH, hd); ``tokens``:
-        (N, C) int32 right-padded; ``start``/``chunk_len``: (N,) int32.
-        Dummy rows (pack padding) carry ``chunk_len = 0`` and whatever
-        stripe the caller gathered; their outputs are garbage the caller
-        discards.  Returns ``(last_logits (N, V) f32, new_k, new_v)``.
-        """
-        cfg = self.cfg
-        if not self.supports_prefill_pack():
-            raise ValueError(
-                f"packed prefill unsupported for family={cfg.family} "
-                f"ffn={cfg.ffn_kind(0)} enc_dec={cfg.is_encoder_decoder}")
-        N, C = tokens.shape
-        Smax = k_stripes.shape[2]
-        x = self._embed_in(params, tokens)                    # (N, C, D)
-        x = shard_hint(x, "batch", None, None)
-        start = jnp.asarray(start, jnp.int32)
-        q_pos = start[:, None] + jnp.arange(C)[None, :]       # (N, C)
-        kv_pos = jnp.broadcast_to(jnp.arange(Smax)[None, :], (N, Smax))
-        rows = jnp.arange(N)[:, None]                         # (N, 1)
-        write_idx = q_pos                                     # (N, C)
-        ffn_kind = cfg.ffn_kind(0)
-
-        def body(h, inp):
-            p_l, k_l, v_l = inp                               # (N, Smax, KVH, hd)
-            h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            with jax.named_scope("attention"):
-                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-                k_l = k_l.at[rows, write_idx].set(k.astype(k_l.dtype))
-                v_l = v_l.at[rows, write_idx].set(v.astype(v_l.dtype))
-                attn = self._chunk_attn(q, k_l, v_l, q_pos, kv_pos, start,
-                                        interpret)
-                h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
-            h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, (k_l, v_l)
-
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["layers"], k_stripes, v_stripes))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        last = jnp.clip(chunk_len - 1, 0, C - 1)              # (N,)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        logits = self._logits(params, x_last)                 # (N, V)
-        return logits.astype(jnp.float32), k_new, v_new
-
-    def paged_prefill_pack(self, params, kv, tokens, block_tables,
-                           write_page, write_off, start, chunk_len, *,
-                           interpret: bool = False):
-        """Paged twin of :meth:`prefill_pack`: N chunks' KV lands in the
-        shared page pool in one dispatch.
-
-        ``kv``: {"k","v"} (L, num_pages, page, KVH, hd); ``tokens``:
-        (N, C); ``block_tables``: (N, max_pages) with unused entries at
-        the scratch page; ``write_page``/``write_off``: (N, C) physical
-        destination per token (scratch for padded rows and dummy
-        segments); ``start``/``chunk_len``: (N,) int32.
-        """
-        cfg = self.cfg
-        if not self.supports_prefill_pack():
-            raise ValueError(
-                f"packed prefill unsupported for family={cfg.family} "
-                f"ffn={cfg.ffn_kind(0)} enc_dec={cfg.is_encoder_decoder}")
-        N, C = tokens.shape
-        page = kv["k"].shape[2]
-        n_pages = block_tables.shape[1]
-        Smax = n_pages * page
-        x = self._embed_in(params, tokens)
-        x = shard_hint(x, "batch", None, None)
-        start = jnp.asarray(start, jnp.int32)
-        q_pos = start[:, None] + jnp.arange(C)[None, :]
-        kv_pos = jnp.broadcast_to(jnp.arange(Smax)[None, :], (N, Smax))
-        ffn_kind = cfg.ffn_kind(0)
-
-        def body(h, p_l, k_all, v_all, i):
-            h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            with jax.named_scope("attention"):
-                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, q_pos)
-                k_all = k_all.at[i, write_page, write_off].set(
-                    k.astype(k_all.dtype))
-                v_all = v_all.at[i, write_page, write_off].set(
-                    v.astype(v_all.dtype))
-                kg = k_all[i, block_tables].reshape(N, Smax, *k_all.shape[3:])
-                vg = v_all[i, block_tables].reshape(N, Smax, *v_all.shape[3:])
-                attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, start,
-                                        interpret)
-                h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
-            h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
-            return h, k_all, v_all
-
-        x, kv = self._scan_paged(params, kv, x, body)
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        last = jnp.clip(chunk_len - 1, 0, C - 1)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        logits = self._logits(params, x_last)
-        return logits.astype(jnp.float32), kv
-
-    # ------------------------------------------------------- paged decode
-    def supports_paged(self) -> bool:
-        """Paged KV decode covers attention-family decoder-only stacks
-        (SSM/hybrid state is constant-size — paging buys nothing — and
-        enc-dec carries a static cross cache)."""
-        cfg = self.cfg
-        return (cfg.family not in ("ssm", "hybrid")
-                and not cfg.is_encoder_decoder)
-
-    def paged_decode_step(self, params, kv, tokens, block_tables, lengths,
-                          write_page, write_off, *, attn_impl: str = "gather",
-                          interpret: bool = False):
-        """One decode iteration over a paged KV pool (vLLM-style block KV).
-
-        ``kv``: {"k","v"} of shape (L, num_pages, page, KVH, hd);
-        ``tokens`` (B, 1) int32; ``block_tables`` (B, max_pages) int32 with
-        unused entries pointing at a sacrificial page; ``lengths`` (B,) =
-        tokens already written, so the fed token's KV lands at logical
-        position ``lengths`` = physical ``(write_page, write_off)``.
-
-        ``attn_impl="gather"`` materializes the pages in logical order and
-        reuses :func:`layers.decode_attention` — bit-identical to the dense
-        slotted path (same ops on the same values), which is what the
-        dense-vs-paged greedy invariant tests pin.  ``"kernel"`` routes
-        through the Pallas paged-attention kernel (no gather — the block
-        table drives scalar-prefetch DMA), numerically equal within
-        online-softmax reassociation.
-
-        Returns ``(logits (B, V) f32, new_kv)``.
+        Returns the final-normed hidden states (N, C, D) and the new
+        ``kv``; the caller applies the lm head only where it needs logits.
         """
         cfg = self.cfg
         if not self.supports_paged():
-            raise ValueError(f"paged decode unsupported for family="
+            raise ValueError(f"the paged forward is unsupported for family="
                              f"{cfg.family} enc_dec={cfg.is_encoder_decoder}")
-        B = tokens.shape[0]
-        page = kv["k"].shape[2]
+        N, C = tokens.shape
+        S = block_tables.shape[1] * kv["k"].shape[2]
         x = self._embed_in(params, tokens)
         x = shard_hint(x, "batch", None, None)
-        positions = lengths[:, None]
-        ffn_kind = cfg.ffn_kind(0)
-
-        def body(h, p_l, k_all, v_all, i):
-            h1 = L.apply_norm(cfg, p_l["ln1"], h)
-            with jax.named_scope("attention"):
-                q, k, v = L._project_qkv(cfg, p_l["attn"], h1, positions)
-                k_all = k_all.at[i, write_page, write_off].set(
-                    k[:, 0].astype(k_all.dtype))
-                v_all = v_all.at[i, write_page, write_off].set(
-                    v[:, 0].astype(v_all.dtype))
-                if attn_impl == "kernel":
-                    from repro.kernels.paged_attention.paged_attention \
-                        import paged_attention
-                    attn = paged_attention(q[:, 0], k_all, v_all,
-                                           block_tables, lengths + 1,
-                                           layer=i, interpret=interpret)
-                else:
-                    n_pages = block_tables.shape[1]
-                    kg = k_all[i, block_tables].reshape(
-                        B, n_pages * page, *k_all.shape[3:])
-                    vg = v_all[i, block_tables].reshape(
-                        B, n_pages * page, *v_all.shape[3:])
-                    attn = L.decode_attention(cfg, q[:, 0], kg, vg,
-                                              lengths + 1)
-                h = h + (attn.reshape(B, -1) @ p_l["attn"]["wo"])[:, None, :]
-            h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind)
-            return h, k_all, v_all
-
-        x, kv = self._scan_paged(params, kv, x, body)
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._logits(params, x[:, -1, :])
-        return logits.astype(jnp.float32), kv
-
-    def paged_decode_step_sampled(self, params, kv, tokens, block_tables,
-                                  lengths, write_page, write_off, active,
-                                  new_gen, new_ctx, true_len, rids, base_key,
-                                  *, attn_impl: str = "gather",
-                                  interpret: bool = False,
-                                  greedy_sampling=True, temp: float = 1.0,
-                                  top_k: int = 0, eos_token: int = 1,
-                                  max_new_tokens: int = 128,
-                                  max_seq_len: int = 256):
-        """Paged twin of :meth:`decode_step_sampled`: one fused dispatch
-        returning ``(sampled, reason, new_kv)``.  Per-lane sampling keys
-        derive from ``(rids, new_gen - 1)`` exactly as on the dense path."""
-        from repro.serving.sampler import sample_and_reason, token_keys
-        logits, kv = self.paged_decode_step(
-            params, kv, tokens, block_tables, lengths, write_page, write_off,
-            attn_impl=attn_impl, interpret=interpret)
-        keys = None if greedy_sampling else token_keys(
-            base_key, rids, new_gen - 1)
-        tok, reason = sample_and_reason(
-            logits, keys, greedy_sampling=greedy_sampling, temp=temp,
-            top_k=top_k, eos_token=eos_token, max_new_tokens=max_new_tokens,
-            max_seq_len=max_seq_len, new_gen=new_gen, new_ctx=new_ctx,
-            true_len=true_len)
-        reason = jnp.where(active, reason, 0)
-        return tok, reason, kv
-
-    def paged_decode_verify(self, params, kv, tokens, block_tables, lengths,
-                            write_page, write_off, *,
-                            interpret: bool = False):
-        """Paged twin of :meth:`decode_verify`: K1 positions per lane land
-        in the page pool at host-computed ``(write_page, write_off)``
-        destinations (real tail pages in place; positions past the last
-        allocated page on the lane's private scratch page, which the
-        backend promotes into the page table only on accept).
-
-        ``block_tables``: (B, max_pages + 1) with the lane scratch page
-        appended right after the lane's real pages, so a scratch-resident
-        position's gather index equals its logical position; stale scratch
-        offsets sit past every query position and are causally masked.
-        Attention is always the gather form — the Pallas paged kernel is
-        single-query — which keeps the verify math bit-identical to the
-        dense chunk attention.  Returns ``(logits (B, K1, V) f32, new_kv)``.
-        """
-        cfg = self.cfg
-        if not (self.supports_spec_decode() and self.supports_paged()):
-            raise ValueError(f"paged verify-k unsupported for family="
-                             f"{cfg.family} enc_dec={cfg.is_encoder_decoder}")
-        B, K1 = tokens.shape
-        page = kv["k"].shape[2]
-        n_pages = block_tables.shape[1]
-        Smax = n_pages * page
-        x = self._embed_in(params, tokens)
-        x = shard_hint(x, "batch", None, None)
-        q_pos = lengths[:, None] + jnp.arange(K1)[None, :]
-        kv_pos = jnp.broadcast_to(jnp.arange(Smax)[None, :], (B, Smax))
+        start = jnp.asarray(start, jnp.int32)
+        q_pos = start[:, None] + jnp.arange(C)[None, :]       # (N, C)
+        kv_pos = jnp.broadcast_to(jnp.arange(S)[None, :], (N, S))
         ffn_kind = cfg.ffn_kind(0)
 
         def body(h, p_l, k_all, v_all, i):
@@ -1022,52 +617,29 @@ class Model:
                     k.astype(k_all.dtype))
                 v_all = v_all.at[i, write_page, write_off].set(
                     v.astype(v_all.dtype))
-                kg = k_all[i, block_tables].reshape(B, Smax, *k_all.shape[3:])
-                vg = v_all[i, block_tables].reshape(B, Smax, *v_all.shape[3:])
-                attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos, lengths,
-                                        interpret)
-                h = h + attn.reshape(B, K1, -1) @ p_l["attn"]["wo"]
+                if C == 1 and attn_impl == "kernel":
+                    from repro.kernels.paged_attention.paged_attention \
+                        import paged_attention
+                    attn = paged_attention(q[:, 0], k_all, v_all,
+                                           block_tables, start + 1,
+                                           layer=i, interpret=interpret)
+                else:
+                    kg = k_all[i, block_tables].reshape(N, S,
+                                                        *k_all.shape[3:])
+                    vg = v_all[i, block_tables].reshape(N, S,
+                                                        *v_all.shape[3:])
+                    if C == 1:
+                        attn = L.decode_attention(cfg, q[:, 0], kg, vg,
+                                                  start + 1)
+                    else:
+                        attn = self._chunk_attn(q, kg, vg, q_pos, kv_pos,
+                                                start, interpret)
+                h = h + attn.reshape(N, C, -1) @ p_l["attn"]["wo"]
             h, _ = _apply_ffn_part(cfg, p_l, h, ffn_kind, self.moe_groups)
             return h, k_all, v_all
 
         x, kv = self._scan_paged(params, kv, x, body)
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._logits(params, x)
-        return logits.astype(jnp.float32), kv
-
-    def paged_decode_verify_sampled(self, params, kv, tokens, block_tables,
-                                    lengths, write_page, write_off, n_drafts,
-                                    active, base_gen, base_ctx, true_len,
-                                    rids, base_key, *,
-                                    interpret: bool = False,
-                                    greedy_sampling=True,
-                                    temp: float = 1.0, top_k: int = 0,
-                                    eos_token: int = 1,
-                                    max_new_tokens: int = 128,
-                                    max_seq_len: int = 256):
-        """Fused paged verify-k iteration: score + sample + accept +
-        terminate, one dispatch.  Length bookkeeping is host-side on the
-        paged backend, so this returns ``(samples, n_emit, reason,
-        new_kv)`` and the backend commits page-table state after the sync
-        (rollback = simply not advancing the pool length)."""
-        from repro.serving.sampler import token_keys, verify_and_reason
-        logits, kv = self.paged_decode_verify(
-            params, kv, tokens, block_tables, lengths, write_page, write_off,
-            interpret=interpret)
-        B, K1 = tokens.shape
-        keys = None
-        if not greedy_sampling:
-            rr = jnp.repeat(jnp.asarray(rids, jnp.int32), K1)
-            ii = (jnp.asarray(base_gen, jnp.int32)[:, None]
-                  + jnp.arange(K1, dtype=jnp.int32)[None, :]).reshape(-1)
-            keys = token_keys(base_key, rr, ii).reshape(B, K1, -1)
-        s, n_emit, reason = verify_and_reason(
-            logits, tokens, jnp.asarray(n_drafts, jnp.int32), keys, active,
-            greedy_sampling=greedy_sampling, temp=temp, top_k=top_k,
-            eos_token=eos_token, max_new_tokens=max_new_tokens,
-            max_seq_len=max_seq_len, base_gen=base_gen, base_ctx=base_ctx,
-            true_len=true_len)
-        return s, n_emit, reason, kv
+        return L.apply_norm(cfg, params["final_norm"], x), kv
 
     def _decode_hybrid(self, params, cache, x, lengths):
         cfg = self.cfg

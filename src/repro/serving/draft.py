@@ -1,7 +1,8 @@
 """Model-free draft-token sources for speculative (verify-k) decoding.
 
-The fused verify-k dispatch (``Model.decode_verify_sampled`` /
-``paged_decode_verify_sampled``) scores k draft tokens plus the fed token
+The fused verify-k dispatch (``kv_cache.paged_verify`` /
+``kv_cache.dense_verify``: ``Model.forward_paged`` at width k+1, then
+``sampler.verify_epilogue``) scores k draft tokens plus the fed token
 in one jitted call and accepts the longest prefix that exact-matches what
 sequential sampling would have produced — so *any* draft source is safe:
 a bad draft costs nothing but the wasted lane width, never a changed

@@ -15,6 +15,10 @@ Two layers live here:
     KV placement, request-granular offload/upload blobs, and ``decode()`` —
     **one fused jitted dispatch per iteration** that samples tokens and
     computes termination flags on device (no per-slot host sync).
+    Both run one forward, ``Model.forward_paged``, for decode, verify-k,
+    chunks and packs: the dense cache is a pool of one ``Smax`` page per
+    slot (``models.model.stripe_view``), so greedy outputs agree across
+    the backends by construction.
 
 Offload/upload runs through the Pallas ``kv_quant`` kernels when
 ``quantize_offload`` is set: KV is quantized **on device** and the host link
@@ -37,6 +41,7 @@ the softmax, so a narrower table changes no result, only the copy.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -46,7 +51,9 @@ import numpy as np
 
 from repro.kernels import interpret_mode
 from repro.kernels.kv_quant import kv_dequantize_op, kv_quantize_op
+from repro.models.model import stripe_view
 from repro.serving.observability.span import span
+from repro.serving.sampler import decode_epilogue, verify_epilogue
 
 _QBLK = 128                                   # kv_quant row-tile
 
@@ -116,6 +123,103 @@ def decode_program(name: str, fn, **static):
         return fn(params, kv, *unpack_lanes(packed, layout), base_key, **kw)
     return jax.jit(named_program(name, unpacked, **static),
                    static_argnums=(4,), donate_argnums=(1,))
+
+
+# ------------------------------------------------ programs over one forward
+# Each program composes ``Model.forward_paged`` (the dense cache of the
+# other families: ``Model.decode_step``) with the lm head, only where its
+# logits are needed, and a sampler epilogue.  ``model`` is bound first.
+
+def paged_decode(model, params, kv, tokens, tables, lens, write_page,
+                 write_off, active, new_gen, new_ctx, true_len, rids,
+                 base_key, *, attn_impl: str = "gather",
+                 interpret: bool = False, **sample):
+    """The fused paged decode step: ``tokens`` (B, 1) at positions ``lens``
+    (B,), each token's KV written at ``(write_page, write_off)`` (B,).
+    Returns ``(sampled (B,), reason (B,), new_kv)``."""
+    h, kv = model.forward_paged(params, kv, tokens, tables,
+                                write_page[:, None], write_off[:, None],
+                                lens, attn_impl=attn_impl,
+                                interpret=interpret)
+    tok, reason = decode_epilogue(model.logits(params, h[:, -1]), active,
+                                  new_gen, new_ctx, true_len, rids,
+                                  base_key, **sample)
+    return tok, reason, kv
+
+
+def paged_verify(model, params, kv, tokens, tables, lens, write_page,
+                 write_off, n_drafts, active, base_gen, base_ctx, true_len,
+                 rids, base_key, *, interpret: bool = False, **sample):
+    """The fused paged verify-k step: ``tokens`` (B, K1) at ``lens + i``,
+    written at ``(write_page, write_off)`` (B, K1).  Returns ``(samples
+    (B, K1), n_emit (B,), reason (B,), new_kv)``; the backend commits
+    page-table state after the sync."""
+    h, kv = model.forward_paged(params, kv, tokens, tables, write_page,
+                                write_off, lens, interpret=interpret)
+    s, n_emit, reason = verify_epilogue(
+        model.logits(params, h), tokens, n_drafts, active, base_gen,
+        base_ctx, true_len, rids, base_key, **sample)
+    return s, n_emit, reason, kv
+
+
+def paged_prefill(model, params, kv, tokens, tables, write_page, write_off,
+                  start, chunk_len, *, interpret: bool = False):
+    """Prefill chunks into the page pool: ``tokens`` (N, C) right-padded,
+    ``start``/``chunk_len`` (N,) and write targets (N, C) — or, for one
+    chunk, scalars and (C,).  Returns ``(last_logits (N, V), new_kv)``."""
+    N, C = tokens.shape
+    h, kv = model.forward_paged(params, kv, tokens, tables,
+                                write_page.reshape(N, C),
+                                write_off.reshape(N, C), start.reshape(N),
+                                interpret=interpret)
+    return model.chunk_logits(params, h, chunk_len.reshape(N)), kv
+
+
+def dense_decode(model, params, cache, tokens, active, new_gen, new_ctx,
+                 true_len, rids, base_key, **sample):
+    """The fused decode step over the dense cache of any family: lanes
+    not ``active`` keep their ``lengths``.  Returns ``(sampled, reason,
+    new_cache)``."""
+    logits, cache = model.decode_step(params, cache, tokens)
+    lengths = cache["lengths"]
+    cache = {**cache, "lengths": jnp.where(active, lengths, lengths - 1)}
+    tok, reason = decode_epilogue(logits, active, new_gen, new_ctx,
+                                  true_len, rids, base_key, **sample)
+    return tok, reason, cache
+
+
+def dense_verify(model, params, cache, tokens, n_drafts, active, base_gen,
+                 base_ctx, true_len, rids, base_key, *,
+                 interpret: bool = False, **sample):
+    """The fused verify-k step over the dense stripes: accepted lanes
+    advance ``lengths`` by ``n_emit``; rejected positions' KV stays past
+    the watermark, never attended and overwritten by the next dispatch.
+    Returns ``(samples, n_emit, reason, new_cache)``."""
+    B, K1 = tokens.shape
+    lengths = cache["lengths"]
+    h, kv = model.forward_paged(
+        params, {"k": cache["k"], "v": cache["v"]}, tokens,
+        *stripe_view(jnp.arange(B), lengths, K1), lengths,
+        interpret=interpret)
+    s, n_emit, reason = verify_epilogue(
+        model.logits(params, h), tokens, n_drafts, active, base_gen,
+        base_ctx, true_len, rids, base_key, **sample)
+    return s, n_emit, reason, {**cache, **kv, "lengths": lengths + n_emit}
+
+
+def dense_prefill(model, params, k_cache, v_cache, tokens, slots, start,
+                  chunk_len, *, interpret: bool = False):
+    """Prefill chunks into the dense stripes of ``slots``: ``tokens``
+    (N, C), ``slots``/``start``/``chunk_len`` (N,) (scalars for one
+    chunk).  A dummy row's out-of-range slot reads a clamped stripe and
+    writes nothing.  Returns ``(last_logits (N, V), new_k, new_v)``."""
+    N, C = tokens.shape
+    start = start.reshape(N)
+    h, kv = model.forward_paged(
+        params, {"k": k_cache, "v": v_cache}, tokens,
+        *stripe_view(slots.reshape(N), start, C), start, interpret=interpret)
+    return (model.chunk_logits(params, h, chunk_len.reshape(N)),
+            kv["k"], kv["v"])
 
 
 def decode_width_ladder(max_seq_len: int, page_size: int) -> Tuple[int, ...]:
@@ -562,10 +666,6 @@ class KVBackend:
         (speculative writes past that are rolled back)."""
         raise NotImplementedError
 
-    def decode_logits(self, params, tokens, active):
-        """Legacy per-slot dispatch path (host-side sampling baseline)."""
-        raise NotImplementedError
-
     def pages_shortfall(self, rids: List[int]) -> int:
         """Physical pages missing to decode one token for each of ``rids``
         (always 0 for the dense backend)."""
@@ -622,48 +722,21 @@ class DenseKVBackend(KVBackend):
         # the cache pytree (arg 1) is consumed and fully re-emitted by the
         # fused step: donate it so it updates in place
         self._fused = decode_program(
-            "alise_decode", model.decode_step_sampled,
+            "alise_decode", functools.partial(dense_decode, model),
             **self._sample_kwargs())
         self._fused_verify = None
-        if cfg.spec_k > 0 and model.supports_spec_decode():
+        if cfg.spec_k > 0 and model.supports_paged():
             self._fused_verify = decode_program(
-                "alise_decode_verify", model.decode_verify_sampled,
+                "alise_decode_verify", functools.partial(dense_verify, model),
                 interpret=self.interpret, **self._sample_kwargs())
-        self._decode = jax.jit(named_program("alise_decode_logits",
-                                             model.decode_step),
-                               donate_argnums=(1,))
-        self._chunk = None
-        if model.supports_chunked_prefill():
-            # one jitted dispatch per chunk over the *full* cache: the slot
-            # gather, chunk compute, and slot scatter all fuse — no eager
-            # whole-cache copies on the host side per chunk (the batch axis
-            # of k/v is 1 for every chunk-capable family)
-            def alise_prefill_chunk(params, k_cache, v_cache, toks, slot,
-                                    start, chunk_len):
-                logits, k_new, v_new = model.prefill_chunk(
-                    params, k_cache[:, slot], v_cache[:, slot], toks,
-                    start, chunk_len, interpret=self.interpret)
-                return (logits,
-                        k_cache.at[:, slot].set(k_new.astype(k_cache.dtype)),
-                        v_cache.at[:, slot].set(v_new.astype(v_cache.dtype)))
-            self._chunk = jax.jit(alise_prefill_chunk, donate_argnums=(1, 2))
-        self._pack = None
+        # chunk and pack programs run the one forward over the whole cache
+        # (donated, written in place), each row's stripe a one-page table
+        self._chunk = self._pack = None
+        if model.supports_paged():
+            self._chunk = self._prefill_program("alise_prefill_chunk")
         if model.supports_prefill_pack():
-            # packed twin: N segment rows gather N slot stripes, run the
-            # batched chunk compute, scatter back.  Dummy rows carry an
-            # out-of-range slot index: JAX clamps the gather (harmless
-            # read of the last stripe) and DROPS the scatter, so pack
-            # padding never touches live cache state.
-            def alise_prefill_pack(params, k_cache, v_cache, toks, slots,
-                                   start, chunk_len):
-                logits, k_new, v_new = model.prefill_pack(
-                    params, k_cache[:, slots], v_cache[:, slots], toks,
-                    start, chunk_len, interpret=self.interpret)
-                return (logits,
-                        k_cache.at[:, slots].set(k_new.astype(k_cache.dtype)),
-                        v_cache.at[:, slots].set(v_new.astype(v_cache.dtype)))
-            self._pack = jax.jit(alise_prefill_pack, donate_argnums=(1, 2))
-        if cfg.prefix_cache and model.supports_chunked_prefill():
+            self._pack = self._prefill_program("alise_prefill_pack")
+        if cfg.prefix_cache and model.supports_paged():
             from repro.serving.prefix_cache import DensePrefixCache
             acfg = model.cfg
             capacity = cfg.prefix_cache_pages or (
@@ -680,6 +753,11 @@ class DenseKVBackend(KVBackend):
                 vc = vc.at[:, slot, :span].set(v.astype(vc.dtype))
                 return kc, vc, lengths.at[slot].set(hit)
             self._place = jax.jit(alise_kv_place, donate_argnums=(0, 1, 2))
+
+    def _prefill_program(self, name: str):
+        return jax.jit(named_program(
+            name, functools.partial(dense_prefill, self.model),
+            interpret=self.interpret), donate_argnums=(1, 2))
 
     def _cache_batch_axes(self) -> Dict[str, int]:
         fam = self.model.cfg.family
@@ -766,7 +844,7 @@ class DenseKVBackend(KVBackend):
         starts = np.zeros((N,), np.int32)
         lens = np.zeros((N,), np.int32)
         # dummy rows target slot == max_slots: out of range by one, so the
-        # jitted gather clamps and the scatter back is dropped
+        # forward's gather clamps and its KV writes are dropped
         slots = np.full((N,), self.cfg.max_slots, np.int32)
         for i, (rid, tokens, start) in enumerate(items):
             slot = self.slot_of(rid)
@@ -933,10 +1011,6 @@ class DenseKVBackend(KVBackend):
 
     def decode_verify(self, params, tokens, n_drafts, active, base_gen,
                       base_ctx, true_len, rids):
-        # rejected positions' KV writes land past each lane's post-accept
-        # watermark: never attended (causal masks + ``lengths``) and
-        # overwritten by the next dispatch before they could matter —
-        # rollback costs nothing on the dense stripes
         with span("decode.stage"):
             buf, layout, _ = lane_buffer(len(tokens), tokens, n_drafts,
                                          active, base_gen, base_ctx,
@@ -945,13 +1019,6 @@ class DenseKVBackend(KVBackend):
         (s, n_emit, reason), self.cache = self._dispatch_sync(
             self._fused_verify, params, self.cache, packed, layout)
         return s, n_emit, reason
-
-    def decode_logits(self, params, tokens, active):
-        logits, cache = self._decode(params, self.cache, jnp.asarray(tokens))
-        lengths = np.array(cache["lengths"])
-        lengths[~np.asarray(active)] -= 1
-        self.cache = {**cache, "lengths": jnp.asarray(lengths)}
-        return logits
 
 
 class PagedKVBackend(KVBackend):
@@ -980,7 +1047,7 @@ class PagedKVBackend(KVBackend):
                 f"({cfg.page_size}) so a lane's speculative span never "
                 "needs more than its one scratch page")
         acfg = model.cfg
-        spec_on = cfg.spec_k > 0 and model.supports_spec_decode()
+        spec_on = cfg.spec_k > 0 and model.supports_paged()
         self.max_pages_per_seq = cfg.max_seq_len // cfg.page_size
         self.decode_widths = decode_width_ladder(cfg.max_seq_len,
                                                  cfg.page_size)
@@ -1003,23 +1070,24 @@ class PagedKVBackend(KVBackend):
         # kv (arg 1) is the whole page pool, consumed and re-emitted: donate
         # so pages are written in place
         self._fused = decode_program(
-            "alise_decode", model.paged_decode_step_sampled,
+            "alise_decode", functools.partial(paged_decode, model),
             attn_impl=cfg.attn_impl, interpret=self.interpret,
             **self._sample_kwargs())
         self._fused_verify = None
         if spec_on:
             self._fused_verify = decode_program(
-                "alise_decode_verify", model.paged_decode_verify_sampled,
+                "alise_decode_verify", functools.partial(paged_verify, model),
                 interpret=self.interpret, **self._sample_kwargs())
-        # chunked prefill always attends via the logical-order page gather
-        # (bit-exact vs the dense stripe path); attn_impl only selects the
-        # decode-step kernel
+        # chunks, packs and verify attend through the logical-order page
+        # gather, as the dense backend does over its one-page tables;
+        # attn_impl only selects the width-1 decode attention
+        prefill = functools.partial(paged_prefill, model)
         self._chunk = jax.jit(named_program(
-            "alise_prefill_chunk", model.paged_prefill_chunk,
-            interpret=self.interpret), donate_argnums=(1,))
+            "alise_prefill_chunk", prefill, interpret=self.interpret),
+            donate_argnums=(1,))
         self._pack = (jax.jit(named_program(
-            "alise_prefill_pack", model.paged_prefill_pack,
-            interpret=self.interpret), donate_argnums=(1,))
+            "alise_prefill_pack", prefill, interpret=self.interpret),
+            donate_argnums=(1,))
             if model.supports_prefill_pack() else None)
         if cfg.prefix_cache:
             from repro.serving.prefix_cache import PagedPrefixCache

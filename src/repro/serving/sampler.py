@@ -151,3 +151,45 @@ def verify_and_reason(logits, drafts, n_drafts, keys, active, *,
         last = jnp.take_along_axis(reason, (m - 1)[:, None], axis=1)[:, 0]
         reason_out = jnp.where(active, last, 0).astype(jnp.int32)
         return s, n_emit, reason_out
+
+
+def decode_epilogue(logits, active, new_gen, new_ctx, true_len, rids,
+                    base_key, *, greedy_sampling: bool = True,
+                    temp: float = 1.0, top_k: int = 0, eos_token: int = 1,
+                    max_new_tokens: int = 128, max_seq_len: int = 256):
+    """The fused decode step's tail over its last-position logits (B, V):
+    each lane's key from ``(rids, new_gen - 1)`` (the generation index of
+    the token being sampled), then :func:`sample_and_reason`; lanes not
+    ``active`` get reason 0.  Returns ``(tokens (B,), reason (B,))``."""
+    keys = None if greedy_sampling else token_keys(base_key, rids,
+                                                   new_gen - 1)
+    tok, reason = sample_and_reason(
+        logits, keys, greedy_sampling=greedy_sampling, temp=temp,
+        top_k=top_k, eos_token=eos_token, max_new_tokens=max_new_tokens,
+        max_seq_len=max_seq_len, new_gen=new_gen, new_ctx=new_ctx,
+        true_len=true_len)
+    return tok, jnp.where(active, reason, 0)
+
+
+def verify_epilogue(logits, tokens, n_drafts, active, base_gen, base_ctx,
+                    true_len, rids, base_key, *,
+                    greedy_sampling: bool = True, temp: float = 1.0,
+                    top_k: int = 0, eos_token: int = 1,
+                    max_new_tokens: int = 128, max_seq_len: int = 256):
+    """The fused verify-k step's tail over its logits (B, K1, V): a key
+    per draft position ``(rids, base_gen + i)``, then
+    :func:`verify_and_reason`.  Returns ``(samples (B, K1), n_emit (B,),
+    reason (B,))``."""
+    B, K1 = tokens.shape
+    keys = None
+    if not greedy_sampling:
+        rr = jnp.repeat(jnp.asarray(rids, jnp.int32), K1)
+        ii = (jnp.asarray(base_gen, jnp.int32)[:, None]
+              + jnp.arange(K1, dtype=jnp.int32)[None, :]).reshape(-1)
+        keys = token_keys(base_key, rr, ii).reshape(B, K1, -1)
+    return verify_and_reason(
+        logits, tokens, jnp.asarray(n_drafts, jnp.int32), keys, active,
+        greedy_sampling=greedy_sampling, temp=temp, top_k=top_k,
+        eos_token=eos_token, max_new_tokens=max_new_tokens,
+        max_seq_len=max_seq_len, base_gen=base_gen, base_ctx=base_ctx,
+        true_len=true_len)

@@ -23,6 +23,7 @@ from repro.kernels.kv_quant.kv_quant import (kv_dequantize, kv_quantize,
 from repro.kernels.paged_attention.paged_attention import paged_attention
 from repro.launch.serve import SIZES
 from repro.models.model import Model
+from repro.serving.kv_cache import paged_decode, paged_prefill
 
 ARCH = "granite-3-8b"
 SIZE = SIZES["chip"]
@@ -160,7 +161,7 @@ def test_fused_paged_decode_layer_compiles(cfg, one_chip, attn_impl):
     KV write, attention, logits, sampling) with kernels compiled."""
     model = Model(cfg.scaled(num_layers=1), attn_chunk=32, remat=False)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    _compile(model.paged_decode_step_sampled, params,
+    _compile(functools.partial(paged_decode, model), params,
              *_decode_args(model.cfg, 1), one_chip=one_chip,
              **_decode_kw(attn_impl))
 
@@ -193,7 +194,7 @@ def test_chip_config_fits_one_v5e(cfg, one_chip, program):
     model = Model(cfg, attn_chunk=32, remat=False)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     if program.startswith("decode"):
-        compiled = _compile(model.paged_decode_step_sampled, params,
+        compiled = _compile(functools.partial(paged_decode, model), params,
                             *_decode_args(cfg, cfg.num_layers),
                             one_chip=one_chip, donate=(1,),
                             **_decode_kw(program.split("-")[1]))
@@ -201,7 +202,7 @@ def test_chip_config_fits_one_v5e(cfg, one_chip, program):
         C = int(program.split("-")[1])
         pool = _pool(cfg, cfg.num_layers)
         i32 = functools.partial(_s, dtype=jnp.int32)
-        compiled = _compile(model.paged_prefill_chunk, params,
+        compiled = _compile(functools.partial(paged_prefill, model), params,
                             {"k": pool, "v": pool}, i32((1, C)),
                             i32((1, SIZE.max_seq_len // PAGE)), i32((C,)),
                             i32((C,)), i32(()), i32(()), one_chip=one_chip,

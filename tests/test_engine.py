@@ -120,21 +120,6 @@ def test_fitted_latency_model_sane(model_and_params):
     assert lm.t0 >= 0 and lm.beta > 0
 
 
-def test_fused_matches_per_slot_dispatch(model_and_params):
-    """The fused in-JIT step (sampling + termination on device) is
-    bit-identical to the legacy per-slot host-argmax path."""
-    cfg, model, params = model_and_params
-    ref = _reference_outputs(cfg, model, params)      # fused (default)
-    reqs = _requests(cfg)
-    eng = ServingEngine(model, params, EngineConfig(
-        max_slots=8, max_seq_len=64, max_new_tokens=48, strategy="vllm",
-        quantize_offload=False, fused_decode=False),
-        predictor=OraclePredictor())
-    eng.serve(reqs)
-    for r in reqs:
-        assert ref[r.req_id] == list(r.output_tokens)
-
-
 def test_profiling_rings_bounded(model_and_params):
     """iter_times / prefill_times are ring buffers: long-running gateway
     serves must not grow them without bound."""

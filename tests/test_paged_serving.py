@@ -11,6 +11,8 @@ follows the live context: its ladder, the step at a trimmed width against
 the full one, and warm-up compiling every rung — and the decode step's
 inputs staged as one packed buffer in one host-to-device copy.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -396,11 +398,13 @@ def test_trimmed_decode_step_matches_full_width(model_and_params, attn_impl):
                 jnp.arange(B, dtype=jnp.int32), jax.random.PRNGKey(0))
         out = {}
         for name, table in (("full", full), ("trim", full[:, :width])):
-            logits, _ = model.paged_decode_step(
-                params, kv, tokens, jnp.asarray(table), *args,
-                attn_impl=attn_impl, interpret=True)
-            tok, reason, new_kv = model.paged_decode_step_sampled(
-                params, kv, tokens, jnp.asarray(table), *args, *lane,
+            h, _ = model.forward_paged(
+                params, kv, tokens, jnp.asarray(table), wp[:, None],
+                wo[:, None], jnp.asarray(lens), attn_impl=attn_impl,
+                interpret=True)
+            logits = model.logits(params, h[:, -1])
+            tok, reason, new_kv = kv_cache.paged_decode(
+                model, params, kv, tokens, jnp.asarray(table), *args, *lane,
                 attn_impl=attn_impl, interpret=True,
                 max_seq_len=maxp * page)
             out[name] = (np.asarray(logits), np.asarray(tok),
@@ -564,3 +568,104 @@ def test_decode_step_stages_in_one_copy(model_and_params, monkeypatch,
     if rung is not None:
         assert {w for w, n in eng.kv.decode_width_steps.items() if n} \
             == {rung}
+
+
+@pytest.mark.parametrize("layout", ["one-page-dense-view", "scattered-pages"])
+def test_one_forward_widths(model_and_params, layout):
+    """``Model.forward_paged`` agrees with itself across widths and row
+    counts, over a dense stripe cache seen as one page per slot and over a
+    pool whose pages are scattered: decoding a run one token at a time
+    gives the logits (and the KV) of one width-C call over it, and the
+    chunk, pack-of-one and verify programs built on it agree with that
+    call."""
+    cfg, _, _ = model_and_params
+    cfg = dataclasses.replace(cfg, param_dtype="float32",
+                              compute_dtype="float32")
+    model = Model(cfg, attn_chunk=32, remat=False, kv_dtype="float32")
+    params = model.init(jax.random.PRNGKey(0))
+    P, C, Smax = 5, 6, 32
+    rng = np.random.default_rng(11)
+    prefix = jnp.asarray(rng.integers(2, cfg.vocab_size, (1, P)), jnp.int32)
+    run = jnp.asarray(rng.integers(2, cfg.vocab_size, (1, C)), jnp.int32)
+    if layout == "one-page-dense-view":
+        B, slot, page, n_pages = 3, 1, Smax, 3
+        table = np.array([[slot]], np.int32)
+
+        def targets(pos):
+            return np.full((1, len(pos)), slot, np.int32), np.array([pos])
+    else:
+        page, n_pages, scratch = 4, 12, 5
+        pages = [7, 2, 9, 4, 11, 0, 8, 3]
+        table = np.array([pages], np.int32)
+
+        def targets(pos):
+            return (np.array([[pages[p // page] for p in pos]], np.int32),
+                    np.array([[p % page for p in pos]], np.int32))
+    shape = (cfg.num_layers, n_pages, page, cfg.num_kv_heads, cfg.hd)
+    kv0 = {"k": jnp.zeros(shape), "v": jnp.zeros(shape)}
+
+    def forward(kv, tokens, pos0):
+        wp, wo = targets(range(pos0, pos0 + tokens.shape[1]))
+        return model.forward_paged(params, kv, tokens, jnp.asarray(table),
+                                   jnp.asarray(wp), jnp.asarray(wo),
+                                   jnp.asarray([pos0]), interpret=True)
+
+    _, kv1 = forward(kv0, prefix, 0)
+    h, kv_wide = forward(kv1, run, P)
+    wide = model.logits(params, h)[0]                         # (C, V)
+    kv = kv1
+    for i in range(C):
+        h1, kv = forward(kv, run[:, i:i + 1], P + i)
+        np.testing.assert_allclose(model.logits(params, h1[:, -1])[0],
+                                   wide[i], rtol=1e-5, atol=1e-5)
+    for key in "kv":
+        np.testing.assert_allclose(kv[key], kv_wide[key], rtol=1e-5,
+                                   atol=1e-5)
+
+    # chunk, pack of one (a dummy row and two pad columns) and verify
+    wp, wo = targets(range(P, P + C))
+    if layout == "one-page-dense-view":
+        # the dummy row's slot B is out of range: its writes are dropped
+        chunk, _, _ = kv_cache.dense_prefill(
+            model, params, kv1["k"], kv1["v"], run, jnp.asarray(slot),
+            jnp.asarray(P), jnp.asarray(C), interpret=True)
+        packed, _, _ = kv_cache.dense_prefill(
+            model, params, kv1["k"], kv1["v"],
+            jnp.pad(run, ((0, 1), (0, 2))), jnp.asarray([slot, B]),
+            jnp.asarray([P, 0]), jnp.asarray([C, 0]), interpret=True)
+        tokens = jnp.zeros((B, C), jnp.int32).at[slot].set(run[0])
+        lanes = jnp.arange(B) == slot
+        cache = {**kv1, "lengths": jnp.where(lanes, P, 0)}
+        s, n_emit, _, _ = kv_cache.dense_verify(
+            model, params, cache, tokens, jnp.where(lanes, C - 1, 0), lanes,
+            jnp.zeros((B,), jnp.int32), cache["lengths"],
+            jnp.full((B,), 1 << 30, jnp.int32), jnp.arange(B),
+            jax.random.PRNGKey(0), interpret=True, max_seq_len=Smax)
+        s, n_emit = s[slot], n_emit[slot]
+    else:
+        chunk, _ = kv_cache.paged_prefill(
+            model, params, kv1, run, jnp.asarray(table), jnp.asarray(wp[0]),
+            jnp.asarray(wo[0]), jnp.asarray(P), jnp.asarray(C),
+            interpret=True)
+        _, pad_wo = targets(range(P + C, P + C + 2))
+
+        def rows(a, fill):              # a dummy row after row 0
+            return np.concatenate([a, np.full_like(a, fill)])
+        packed, _ = kv_cache.paged_prefill(
+            model, params, kv1, jnp.pad(run, ((0, 1), (0, 2))),
+            jnp.asarray(rows(table, scratch)),
+            jnp.asarray(rows(np.concatenate(
+                [wp, np.full_like(pad_wo, scratch)], 1), scratch)),
+            jnp.asarray(rows(np.concatenate([wo, pad_wo], 1), 0)),
+            jnp.asarray([P, 0]), jnp.asarray([C, 0]), interpret=True)
+        s, n_emit, _, _ = kv_cache.paged_verify(
+            model, params, kv1, run, jnp.asarray(table), jnp.asarray([P]),
+            jnp.asarray(wp), jnp.asarray(wo), jnp.asarray([C - 1]),
+            jnp.asarray([True]), jnp.asarray([0]), jnp.asarray([P]),
+            jnp.asarray([1 << 30]), jnp.asarray([0]), jax.random.PRNGKey(0),
+            interpret=True, max_seq_len=Smax)
+        s, n_emit = s[0], n_emit[0]
+    np.testing.assert_allclose(chunk[0], wide[-1], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(packed[0], wide[-1], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(s, np.argmax(wide, -1))
+    assert 1 <= int(n_emit) <= C

@@ -5,6 +5,7 @@ named ``jit_alise_*``, named scopes change op metadata only, and the
 first-token stamps of a gateway run are ordered on one clock."""
 import asyncio
 import contextlib
+import functools
 import gc
 import re
 import tempfile
@@ -23,7 +24,7 @@ from repro.core.predictor import OraclePredictor
 from repro.core.request import Request
 from repro.models.model import Model
 from repro.serving.gateway import Gateway, GatewayConfig
-from repro.serving.kv_cache import named_program
+from repro.serving.kv_cache import named_program, paged_decode, paged_prefill
 from repro.serving.observability import EventBus, span
 
 
@@ -192,13 +193,14 @@ def test_named_scopes_change_metadata_only(model_and_params, monkeypatch,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
     if step == "decode":
-        program = ("alise_decode", model.paged_decode_step_sampled,
+        program = ("alise_decode", functools.partial(paged_decode, model),
                    dict(interpret=True, max_new_tokens=8, max_seq_len=64))
         args = (params, kv, i32(B, 1), i32(B, maxp), i32(B), i32(B), i32(B),
                 jax.ShapeDtypeStruct((B,), bool), i32(B), i32(B), i32(B),
                 i32(B), jax.ShapeDtypeStruct((2,), jnp.uint32))
     else:
-        program = ("alise_prefill_chunk", model.paged_prefill_chunk,
+        program = ("alise_prefill_chunk",
+                   functools.partial(paged_prefill, model),
                    dict(interpret=True))
         args = (params, kv, i32(1, C), i32(1, maxp), i32(C), i32(C), i32(),
                 i32())
